@@ -180,6 +180,10 @@ def main() -> None:
     parser.add_argument("--gb", type=float, default=1.0)
     parser.add_argument("--work-dir", default=None)
     args = parser.parse_args()
+
+    from torchsnapshot_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     result = run(args.gb, args.work_dir)
     print(json.dumps(result, indent=2))
 

@@ -28,6 +28,10 @@ def main() -> None:
     parser.add_argument("--work-dir", default=None)
     args = parser.parse_args()
 
+    from torchsnapshot_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     import jax
     import jax.numpy as jnp
     import optax

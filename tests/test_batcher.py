@@ -260,7 +260,8 @@ def test_device_unpack_dtype_cast(tmp_path):
 
 def test_unpack_slab_primitives():
     """unpack_slab_to_device inverts pack_arrays_to_host for every
-    supported dtype class (float, int, bool, complex, bf16)."""
+    supported dtype class (float, int, bool, complex, bf16), one slab per
+    element width — the only kind either program takes."""
     import jax
     import jax.numpy as jnp
 
@@ -269,29 +270,31 @@ def test_unpack_slab_primitives():
         unpack_slab_to_device,
     )
 
-    arrays = [
-        jnp.arange(64, dtype=jnp.float32).reshape(8, 8),
-        jnp.arange(32, dtype=jnp.int8),
-        jnp.array([True, False, True, True]),
-        (jnp.arange(16, dtype=jnp.float32) * 0.25).astype(jnp.bfloat16),
-        jnp.arange(8, dtype=jnp.float32).astype(jnp.complex64) * (1 + 2j),
+    slabs = [
+        [
+            jnp.arange(64, dtype=jnp.float32).reshape(8, 8),
+            jnp.arange(8, dtype=jnp.float32).astype(jnp.complex64) * (1 + 2j),
+        ],
+        [jnp.arange(32, dtype=jnp.int8), jnp.array([True, False, True, True])],
+        [(jnp.arange(16, dtype=jnp.float32) * 0.25).astype(jnp.bfloat16)],
     ]
-    slab = pack_arrays_to_host(arrays)
-    members = []
-    off = 0
-    for a in arrays:
-        dt = np.asarray(a).dtype
-        members.append((off, str(dt), tuple(a.shape)))
-        off += np.asarray(a).nbytes
-    out = unpack_slab_to_device(
-        memoryview(slab),
-        tuple(members),
-        tuple(np.asarray(a).dtype for a in arrays),
-        jax.devices()[0],
-    )
-    for a, b in zip(arrays, out):
-        assert np.asarray(a).dtype == np.asarray(b).dtype
-        assert np.array_equal(np.asarray(a), np.asarray(b)), a
+    for arrays in slabs:
+        slab = pack_arrays_to_host(arrays)
+        members = []
+        off = 0
+        for a in arrays:
+            dt = np.asarray(a).dtype
+            members.append((off, str(dt), tuple(a.shape)))
+            off += np.asarray(a).nbytes
+        out = unpack_slab_to_device(
+            memoryview(slab),
+            tuple(members),
+            tuple(np.asarray(a).dtype for a in arrays),
+            jax.devices()[0],
+        )
+        for a, b in zip(arrays, out):
+            assert np.asarray(a).dtype == np.asarray(b).dtype
+            assert np.array_equal(np.asarray(a), np.asarray(b)), a
 
 
 def test_big_host_members_bypass_slab():
@@ -426,3 +429,188 @@ def test_device_and_host_members_slab_separately():
     assert len(slab_stagers) == 2
     kinds = sorted(s._all_jax for s in slab_stagers)
     assert kinds == [False, True], "device and host members interleaved"
+
+
+def test_failed_device_pack_is_counted_not_silent(tmp_path, monkeypatch):
+    """A device slab pack that fails still lands on the host pack — but
+    through exceptions.swallowed and a WARNING, never quietly."""
+    import jax.numpy as jnp
+
+    from torchsnapshot_tpu import obs
+    from torchsnapshot_tpu.ops import device_pack
+
+    def oom(arrays):
+        raise RuntimeError("RESOURCE_EXHAUSTED: injected")
+
+    monkeypatch.setattr(device_pack, "pack_arrays_to_host", oom)
+    state = {
+        "app": StateDict(
+            a=jnp.arange(16, dtype=jnp.float32),
+            c=jnp.arange(8, dtype=jnp.int32),
+        )
+    }
+    counter = obs.counter(obs.EXCEPTIONS_SWALLOWED)
+    before = counter.value
+    with knobs.override_disable_batching(False), knobs.override_slab_size_threshold_bytes(4096):
+        snap = Snapshot.take(str(tmp_path / "s"), state)
+    assert counter.value == before + 1
+    assert snap.verify(deep=True).ok  # the host pack wrote the same bytes
+    dest = {"app": StateDict(a=jnp.zeros(16), c=jnp.zeros(8, jnp.int32))}
+    snap.restore(dest)
+    np.testing.assert_array_equal(np.asarray(dest["app"]["a"]), np.arange(16))
+
+
+def test_device_slabs_group_by_element_width():
+    """Every member of a device slab joins it by a same-width bitcast, so
+    slabs never mix element widths (ops/device_pack.py)."""
+    import jax.numpy as jnp
+
+    from torchsnapshot_tpu.batcher import (
+        BatchedBufferStager,
+        batch_write_requests,
+    )
+    from torchsnapshot_tpu.io_types import WriteReq
+    from torchsnapshot_tpu.manifest import ArrayEntry
+    from torchsnapshot_tpu.preparers.array import JaxArrayBufferStager
+
+    entries, reqs = {}, []
+    for i, dt in enumerate(
+        [jnp.float32, jnp.bfloat16, jnp.int32, jnp.bfloat16, jnp.float32]
+    ):
+        name = f"w{i}"
+        entries[name] = ArrayEntry(
+            name, "buffer_protocol", str(jnp.dtype(dt)), [64], False
+        )
+        reqs.append(WriteReq(
+            path=name,
+            buffer_stager=JaxArrayBufferStager(jnp.ones(64, dt)),
+        ))
+    _, out = batch_write_requests(entries, reqs, rank=0)
+    slabs = [
+        r.buffer_stager for r in out
+        if isinstance(r.buffer_stager, BatchedBufferStager)
+    ]
+    widths = sorted(
+        tuple(sorted({s.arr.dtype.itemsize for s, _ in slab.stagers}))
+        for slab in slabs
+    )
+    assert widths == [(2,), (4,)]
+    assert all(slab._device_packable for slab in slabs)
+
+
+def test_failed_device_unpack_is_counted_not_silent(tmp_path, monkeypatch):
+    import jax.numpy as jnp
+
+    from torchsnapshot_tpu import PyTreeState, obs
+    from torchsnapshot_tpu.ops import device_pack
+
+    tree = {
+        "a": jnp.arange(512, dtype=jnp.float32),
+        "b": jnp.arange(128, dtype=jnp.int32),
+    }
+    Snapshot.take(str(tmp_path / "s"), {"m": PyTreeState(dict(tree))})
+
+    def oom(*a, **k):
+        raise RuntimeError("RESOURCE_EXHAUSTED: injected")
+
+    monkeypatch.setattr(device_pack, "unpack_slab_to_device", oom)
+    dest = PyTreeState(
+        {"a": jnp.zeros(512, jnp.float32), "b": jnp.zeros(128, jnp.int32)}
+    )
+    counter = obs.counter(obs.EXCEPTIONS_SWALLOWED)
+    before = counter.value
+    with knobs.override_device_unpack("1"):
+        Snapshot(str(tmp_path / "s")).restore({"m": dest})
+    assert counter.value == before + 1
+    for k in tree:  # the host path restored the same values
+        assert np.array_equal(np.asarray(dest.tree[k]), np.asarray(tree[k]))
+
+
+def test_mixed_width_slab_takes_host_unpack_by_choice(tmp_path, monkeypatch):
+    """A slab laid out with mixed element widths (an older plan) is
+    INELIGIBLE for the device unpack — no attempt, no counted failure."""
+    import jax.numpy as jnp
+
+    from torchsnapshot_tpu import PyTreeState, batcher, obs
+    from torchsnapshot_tpu.ops import device_pack
+
+    # lay the slab out the old way: all device members in one group
+    monkeypatch.setattr(device_pack, "packed_width", lambda dt: 0)
+    tree = {
+        "a": jnp.arange(512, dtype=jnp.float32),
+        "b": jnp.ones(256, jnp.bfloat16),
+    }
+    snap = Snapshot.take(str(tmp_path / "s"), {"m": PyTreeState(dict(tree))})
+    monkeypatch.undo()
+    locations = {e.location for e in snap.get_manifest().values()
+                 if hasattr(e, "location")}
+    assert any("batched" in loc for loc in locations)
+
+    calls = []
+    real = device_pack.unpack_slab_to_device
+    monkeypatch.setattr(
+        device_pack, "unpack_slab_to_device",
+        lambda *a, **k: (calls.append(1), real(*a, **k))[1],
+    )
+    dest = PyTreeState(
+        {"a": jnp.zeros(512, jnp.float32), "b": jnp.zeros(256, jnp.bfloat16)}
+    )
+    counter = obs.counter(obs.EXCEPTIONS_SWALLOWED)
+    before = counter.value
+    with knobs.override_device_unpack("1"):
+        Snapshot(str(tmp_path / "s")).restore({"m": dest})
+    assert calls == [] and counter.value == before
+    for k in tree:
+        assert np.array_equal(np.asarray(dest.tree[k]), np.asarray(tree[k]))
+
+
+def test_slab_unpack_donates_member_templates(tmp_path):
+    """Members restored through the device unpack free their templates
+    like every other restore path (1x-restore; donation strictly after
+    the replacement is reachable)."""
+    import jax.numpy as jnp
+
+    from torchsnapshot_tpu import PyTreeState
+    from torchsnapshot_tpu.preparers.array import DONATION_STATS
+
+    tree = {
+        "a": jnp.arange(512, dtype=jnp.float32),
+        "b": jnp.arange(128, dtype=jnp.int32),
+    }
+    Snapshot.take(str(tmp_path / "s"), {"m": PyTreeState(dict(tree))})
+    templates = {
+        "a": jnp.zeros(512, jnp.float32), "b": jnp.zeros(128, jnp.int32),
+    }
+    dest = PyTreeState(dict(templates))
+    before = DONATION_STATS["donated_templates"]
+    with knobs.override_device_unpack("1"), knobs.override_restore_donate("1"):
+        Snapshot(str(tmp_path / "s")).restore({"m": dest})
+    assert DONATION_STATS["donated_templates"] == before + 2
+    assert all(t.is_deleted() for t in templates.values())
+    for k in tree:
+        assert np.array_equal(np.asarray(dest.tree[k]), np.asarray(tree[k]))
+
+
+def test_slab_of_shards_from_several_devices_packs_on_host_by_choice(tmp_path):
+    """One jit cannot take operands committed to different devices: such
+    a slab is never offered to the device pack, so a sharded blocking
+    take counts no swallowed exception."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from torchsnapshot_tpu import PyTreeState, obs
+    from torchsnapshot_tpu.ops import device_pack
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ("x",))
+    w = jax.device_put(
+        jnp.arange(4 * 64, dtype=jnp.float32).reshape(4, 64),
+        NamedSharding(mesh, P("x", None)),
+    )
+    counter = obs.counter(obs.EXCEPTIONS_SWALLOWED)
+    before = counter.value
+    packs = device_pack.CALL_COUNTS["pack"]
+    snap = Snapshot.take(str(tmp_path / "s"), {"m": PyTreeState({"w": w})})
+    assert counter.value == before
+    assert device_pack.CALL_COUNTS["pack"] == packs
+    assert snap.verify(deep=True).ok

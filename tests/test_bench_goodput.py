@@ -53,38 +53,21 @@ def test_goodput_rollup_shape_and_json_safety(tmp_path):
 
 
 def test_every_bench_record_site_embeds_goodput():
-    """Static contract over bench.py: the quick-phase record literal
-    and the main ``result`` record both embed the goodput block (the
-    main record accumulates, so one assignment before the first
-    full-record print covers every later print of it)."""
+    """Static contract over bench.py: the ``result`` record embeds the
+    goodput block (the record accumulates, so one assignment before
+    the first full-record print covers every later print of it)."""
     with open(_BENCH_PATH) as f:
         src = f.read()
     tree = ast.parse(src)
 
-    # quick-phase: the record dict literal printed by _quick_number
-    # carries a "goodput" key
-    quick = next(
+    # result["goodput"] is assigned in main
+    main = next(
         n for n in ast.walk(tree)
-        if isinstance(n, ast.FunctionDef) and n.name == "_quick_number"
-    )
-    quick_keys = {
-        k.value
-        for n in ast.walk(quick)
-        if isinstance(n, ast.Dict)
-        for k in n.keys
-        if isinstance(k, ast.Constant)
-    }
-    assert "goodput" in quick_keys
-    assert "metrics" in quick_keys  # same record literal
-
-    # main path: result["goodput"] is assigned in run_child
-    child = next(
-        n for n in ast.walk(tree)
-        if isinstance(n, ast.FunctionDef) and n.name == "run_child"
+        if isinstance(n, ast.FunctionDef) and n.name == "main"
     )
     assigned = {
         t.slice.value
-        for n in ast.walk(child)
+        for n in ast.walk(main)
         if isinstance(n, ast.Assign)
         for t in n.targets
         if isinstance(t, ast.Subscript)
@@ -94,3 +77,51 @@ def test_every_bench_record_site_embeds_goodput():
     }
     assert "goodput" in assigned
     assert "metrics" in assigned  # the record-assembly site it rides
+
+
+def test_bench_on_cpu_exits_nonzero_without_a_metric_line():
+    """bench.py measures the chip or nothing: on the CPU it exits nonzero
+    before printing any line under a device metric's name."""
+    import subprocess
+    import sys
+
+    proc = subprocess.run(
+        [sys.executable, _BENCH_PATH],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        capture_output=True, text=True, timeout=120,
+    )
+    assert proc.returncode != 0
+    assert "JAX_PLATFORMS='cpu'" in proc.stderr
+    assert '"metric"' not in proc.stdout
+
+
+def test_bench_is_one_process():
+    """No supervisor, no ``--child`` re-exec, no module-level subprocess
+    import: the only children left are the takeover probe's, and they
+    are pinned to the CPU so they can never ask for the chip."""
+    with open(_BENCH_PATH) as f:
+        src = f.read()
+    tree = ast.parse(src)
+    top_imports = {
+        a.name
+        for n in tree.body
+        if isinstance(n, ast.Import)
+        for a in n.names
+    }
+    assert "subprocess" not in top_imports
+    assert "--child" not in src
+    spawners = {
+        fn.name
+        for fn in tree.body  # top level: nested helpers count as theirs
+        if isinstance(fn, ast.FunctionDef)
+        and any(
+            isinstance(n, ast.Attribute) and n.attr == "Popen"
+            for n in ast.walk(fn)
+        )
+    }
+    assert spawners <= {"_takeover_probe"}
+    probe = next(
+        fn for fn in ast.walk(tree)
+        if isinstance(fn, ast.FunctionDef) and fn.name == "_takeover_probe"
+    )
+    assert '"JAX_PLATFORMS": "cpu"' in ast.get_source_segment(src, probe)

@@ -301,3 +301,42 @@ def test_pinned_offload_copies_released_after_commit(tmp_path):
         assert live_pinned_bytes() <= baseline, (
             f"pinned copies leaked at take {it}"
         )
+
+
+def test_offload_dispatch_failure_is_counted_not_silent(monkeypatch):
+    """When the batched device->pinned_host dispatch fails the leaves
+    stage lazily (safe by immutability) — logged and counted through
+    exceptions.swallowed, so a chip run cannot quietly lose the
+    unblock-early design."""
+    import jax
+    import jax.numpy as jnp
+
+    from torchsnapshot_tpu import host_offload, obs
+
+    src = jnp.arange(256, dtype=jnp.float32)
+    _, reqs = _prepare(src)
+
+    def refuse(*a, **k):
+        raise RuntimeError("pinned_host allocation refused (injected)")
+
+    monkeypatch.setattr(jax, "device_put", refuse)
+    counter = obs.counter(obs.EXCEPTIONS_SWALLOWED)
+    before = counter.value
+    assert eager_offload_write_reqs(reqs) == 0
+    assert counter.value == before + 1
+    assert host_offload.LAST_OFFLOAD_STATS["device_offload_bytes"] == 0
+    assert reqs[0].buffer_stager.arr is src  # still stages from the device
+
+
+def test_offload_budget_skip_is_counted_not_silent():
+    import jax.numpy as jnp
+
+    from torchsnapshot_tpu import host_offload, obs
+
+    src = jnp.arange(256, dtype=jnp.float32)
+    _, reqs = _prepare(src)
+    counter = obs.counter(obs.EXCEPTIONS_SWALLOWED)
+    before = counter.value
+    assert eager_offload_write_reqs(reqs, budget_bytes=16) == 0
+    assert counter.value == before + 1
+    assert host_offload.LAST_OFFLOAD_STATS["device_offload_bytes"] == 0

@@ -204,8 +204,10 @@ def test_probe_direct_readonly_rung(tmp_path, monkeypatch):
 
 @needs_engine
 def test_fastio_zero_knob_and_probe_failure_keep_pre_engine_paths(tmp_path):
-    """FASTIO=0 (and a lib without the engine symbols) must yield the
-    pre-engine native path — same bytes, plugin still functional."""
+    """FASTIO=0 (and no native lib at all) must yield the pre-engine
+    path — same bytes, plugin still functional.  (A lib WITHOUT the
+    engine symbols cannot be loaded any more: cached libraries are named
+    by source hash — tests/test_native_ext.py.)"""
     data = np.random.default_rng(5).integers(0, 256, size=70001, dtype=np.uint8)
     with knobs.override_fastio(False):
         plugin = FSStoragePlugin(root=str(tmp_path / "off"))
@@ -214,11 +216,6 @@ def test_fastio_zero_knob_and_probe_failure_keep_pre_engine_paths(tmp_path):
     rio = ReadIO(path="x")
     plugin.sync_read(rio)
     assert bytes(memoryview(rio.buf)) == data.tobytes()
-    # a lib that predates the engine symbols degrades the same way
-    class _Stale:
-        pass
-
-    assert fastio_mod.create_engine(_Stale(), str(tmp_path)) is None
     assert fastio_mod.create_engine(None, str(tmp_path)) is None
 
 

@@ -3,7 +3,8 @@
 Runs in interpret mode on CPU (flash_attention is called directly here,
 bypassing the knob — which resolves "auto" to OFF on CPU so production
 CPU runs never pay interpret-mode cost); the same kernel compiles for
-TPU via Mosaic, where "auto" probe-compiles once and caches the verdict.
+TPU via Mosaic, where "auto" is on (chip_smoke.py compiles and checks it
+on the chip).
 Oracle: dense_attention / _block_attend in parallel/ring_attention.py.
 """
 
@@ -14,7 +15,6 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp
 
 from torchsnapshot_tpu.ops.flash_attention import (
-    PALLAS_AVAILABLE,
     flash_attention,
     flash_attention_partials,
 )
@@ -22,11 +22,6 @@ from torchsnapshot_tpu.parallel.ring_attention import (
     _block_attend,
     dense_attention,
 )
-
-pytestmark = pytest.mark.skipif(
-    not PALLAS_AVAILABLE, reason="pallas unavailable"
-)
-
 
 def _qkv(b, s, h, d, seed=0, dtype=jnp.float32, sk=None):
     rng = np.random.default_rng(seed)

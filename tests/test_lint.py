@@ -690,7 +690,7 @@ def test_tool_tsnp_env_read_clean():
 
         STATE = os.environ.get("TSNP_BENCH_STATE_DIR", ".")
         """,
-        filename="tools/bench_watch.py",
+        filename="tools/soak.py",
     )
     assert findings == []
 
@@ -788,7 +788,7 @@ def test_retry_discipline_exempts_resilience_module_and_non_package():
         filename="torchsnapshot_tpu/resilience/retry.py",
     ) == []
     assert _run(
-        "retry-discipline", src, filename="tools/bench_watch.py"
+        "retry-discipline", src, filename="tools/soak.py"
     ) == []
     assert len(_run("retry-discipline", src)) == 1  # package default
 
@@ -2077,7 +2077,7 @@ def test_kv_hygiene_scoped_to_package():
         def commit(coord):
             coord.kv_set("done", "1")
         """,
-        filename="tools/bench_watch.py",
+        filename="tools/soak.py",
     )
     assert findings == []
 

@@ -242,3 +242,65 @@ def test_fused_digest_checksums_match_pre_write_path(tmp_path):
     assert fs_d and set(fs_d) == set(mem_d)
     assert fs_d == mem_d
     assert s_fs.verify(deep=True).ok
+
+
+def test_stale_library_from_other_source_is_not_loaded(tmp_path, monkeypatch):
+    """Cached libraries are named by a hash of fastio.cpp's content and
+    the flag sets: a .so built from OTHER source — however fresh its
+    mtime after a copy or an artifact restore — is never a candidate,
+    and a successful build deletes it."""
+    import shutil
+
+    if _csrc.load() is None:
+        pytest.skip("no C++ toolchain")
+    here = tmp_path / "csrc"
+    here.mkdir()
+    shutil.copy(_csrc._SRC, here / "fastio.cpp")
+    with open(here / "fastio.cpp", "a") as f:
+        f.write("\n// a later edit of the source\n")
+    fp = _csrc._cpu_fingerprint()
+    old_key = _csrc._build_key()  # the key of the UNEDITED source
+    stale = here / f"fastio.{fp or 'portable'}.{old_key}.so"
+    stale.write_bytes(b"not a library: loading this would fail loudly")
+    os.utime(stale, (2**31, 2**31))  # far newer than the source
+    monkeypatch.setattr(_csrc, "_HERE", str(here))
+    monkeypatch.setattr(_csrc, "_SRC", str(here / "fastio.cpp"))
+    monkeypatch.setattr(_csrc, "_lib", None)
+    monkeypatch.setattr(_csrc, "_load_attempted", False)
+    new_key = _csrc._build_key()
+    assert new_key != old_key
+    lib = _csrc.load()
+    assert lib is not None and new_key in lib._name
+    assert not stale.exists()
+    assert lib.tsnp_crc32c is not None
+
+
+def test_no_toolchain_and_no_cache_warns_once_and_degrades(
+    tmp_path, monkeypatch, caplog
+):
+    """The pure-Python fallback stays for toolchain-less installs, but it
+    is announced at WARNING, not taken silently."""
+    import logging
+    import shutil
+
+    here = tmp_path / "csrc"
+    here.mkdir()
+    shutil.copy(_csrc._SRC, here / "fastio.cpp")
+    monkeypatch.setattr(_csrc, "_HERE", str(here))
+    monkeypatch.setattr(_csrc, "_SRC", str(here / "fastio.cpp"))
+    monkeypatch.setattr(_csrc, "_lib", None)
+    monkeypatch.setattr(_csrc, "_load_attempted", False)
+    monkeypatch.setattr(_csrc, "_build", lambda fp, key: None)  # no g++
+    with caplog.at_level(logging.WARNING, logger=_csrc.logger.name):
+        assert _csrc.load() is None
+        assert _csrc.load() is None  # memoized: no second warning
+    warnings = [r for r in caplog.records if "fastio library unavailable" in r.message]
+    assert len(warnings) == 1
+    assert _csrc.crc32c(b"abc") is None  # callers take their python paths
+
+
+def test_build_key_covers_source_and_flags(monkeypatch):
+    key = _csrc._build_key()
+    assert key == _csrc._build_key()  # stable across calls and processes
+    monkeypatch.setattr(_csrc, "_BASE_FLAGS", ("-O2", "-shared", "-fPIC"))
+    assert _csrc._build_key() != key

@@ -298,7 +298,82 @@ def test_donate_helper_modes():
         donate_template(arr)
         assert arr.is_deleted()
         donate_template(arr)  # idempotent on a deleted array
+    # the default mode on an aliased template's SECOND path: the array is
+    # already deleted (its .devices() raises) and donation must no-op
+    with knobs.override_restore_donate("auto"):
+        donate_template(arr)
     # unrecognized values degrade to auto (a typo'd env var must not
     # abort a half-applied restore), with a warning
     with knobs.override_restore_donate("bogus"):
         assert knobs.restore_donation() == "auto"
+
+
+def test_donation_ignores_host_templates_and_counts_failures(monkeypatch):
+    """donate_template only ever frees jax arrays; a delete() that raises
+    is an optimization lost — counted, never fatal, never silent."""
+    import jax.numpy as jnp
+
+    from torchsnapshot_tpu import knobs, obs
+    from torchsnapshot_tpu.preparers import array as array_mod
+
+    counter = obs.counter(obs.EXCEPTIONS_SWALLOWED)
+    before = counter.value
+    with knobs.override_restore_donate("1"):
+        array_mod.donate_template(None)
+        array_mod.donate_template(np.zeros(4))
+        assert counter.value == before  # nothing to free, nothing failed
+
+        class _Stuck:
+            def is_deleted(self):
+                return False
+
+            def delete(self):
+                raise RuntimeError("buffer has an external reference")
+
+        monkeypatch.setattr(array_mod, "_is_jax_array", lambda a: True)
+        array_mod.donate_template(_Stuck())
+        assert counter.value == before + 1
+        monkeypatch.undo()
+        t = jnp.zeros(4)
+        array_mod.donate_template(t)
+        assert t.is_deleted()
+
+
+def test_auto_donation_on_an_accelerator_handles_aliased_templates(monkeypatch):
+    """RESTORE_DONATE=auto on a non-cpu device: the first path of an
+    aliased template donates it, the second finds it deleted — where
+    ``jax.Array.devices()`` raises — and no-ops without a counted
+    failure."""
+    from types import SimpleNamespace
+
+    from torchsnapshot_tpu import knobs, obs
+    from torchsnapshot_tpu.preparers import array as array_mod
+
+    class _OnTpu:
+        sharding = SimpleNamespace(
+            device_set=[SimpleNamespace(platform="tpu")]
+        )
+        deleted = False
+
+        def devices(self):
+            if self.deleted:
+                raise RuntimeError("Array has been deleted")
+            return self.sharding.device_set
+
+        def is_deleted(self):
+            return self.deleted
+
+        def delete(self):
+            self.deleted = True
+
+    monkeypatch.setattr(array_mod, "_is_jax_array", lambda a: True)
+    counter = obs.counter(obs.EXCEPTIONS_SWALLOWED)
+    before = counter.value
+    donated = array_mod.DONATION_STATS["donated_templates"]
+    template = _OnTpu()
+    with knobs.override_restore_donate("auto"):
+        array_mod.donate_template(template)
+        assert template.deleted
+        array_mod.donate_template(template)  # the aliased second path
+    assert array_mod.DONATION_STATS["donated_templates"] == donated + 1
+    assert counter.value == before

@@ -25,10 +25,7 @@ def test_ring_matches_dense(causal, sp, pallas):
     # "auto" resolves to off on CPU (interpret mode is for tests only),
     # so the pallas path is opted into explicitly here
     from torchsnapshot_tpu import knobs
-    from torchsnapshot_tpu.ops.flash_attention import PALLAS_AVAILABLE
 
-    if pallas and not PALLAS_AVAILABLE:
-        pytest.skip("pallas unavailable")
     mesh = Mesh(np.array(jax.devices()[:sp]), ("sp",))
     q, k, v = _qkv(2, 32, 4, 16)
     sharding = NamedSharding(mesh, P(None, "sp", None, None))
@@ -76,10 +73,7 @@ def test_ring_grad_flows(pallas):
     # differentiable end-to-end (scan + ppermute have transpose rules;
     # the pallas kernel differentiates through its custom_vjp)
     from torchsnapshot_tpu import knobs
-    from torchsnapshot_tpu.ops.flash_attention import PALLAS_AVAILABLE
 
-    if pallas and not PALLAS_AVAILABLE:
-        pytest.skip("pallas unavailable")
     mesh = Mesh(np.array(jax.devices()[:4]), ("sp",))
     q, k, v = _qkv(1, 16, 2, 8)
     sharding = NamedSharding(mesh, P(None, "sp", None, None))

@@ -260,3 +260,27 @@ def test_budget_bounds_staging_memory():
     # budget 250 allows 2 items staged + 1 oversized-slack; peak must stay
     # well under the unbudgeted 4000
     assert ChunkStager.peak <= 400
+
+
+def test_loop_thread_survives_and_counts_a_loader_failure(monkeypatch):
+    """A native-loader failure on the io-loop thread must not kill the
+    thread (every submit would hang) — and must not be silent."""
+    from torchsnapshot_tpu import _csrc, obs
+    from torchsnapshot_tpu.scheduler import _LoopThread
+
+    def broken():
+        raise OSError("fastio.cpp unreadable (injected)")
+
+    monkeypatch.setattr(_csrc, "load", broken)
+    counter = obs.counter(obs.EXCEPTIONS_SWALLOWED)
+    before = counter.value
+    lt = _LoopThread(name="tsnp-test-loop")
+    try:
+
+        async def ping():
+            return 41 + 1
+
+        assert lt.submit(ping()).result(timeout=10) == 42
+        assert counter.value == before + 1
+    finally:
+        lt.shutdown()

@@ -246,16 +246,4 @@ ALLOWLIST: Tuple[Allow, ...] = (
             "(still-degraded) marker in place."
         ),
     ),
-    Allow(
-        pass_id="exception-hygiene",
-        file="bench.py",
-        context="run_child",
-        justification=(
-            "Optional HBM telemetry: jax CPU fallback backends expose "
-            "no memory_stats(); the BENCH record simply omits the "
-            "hbm_* block then.  The headline metric must never fail "
-            "on a telemetry probe, and the omission is visible in the "
-            "record itself."
-        ),
-    ),
 )

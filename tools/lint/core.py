@@ -33,7 +33,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 SCAN_DIRS: Tuple[str, ...] = (
     "torchsnapshot_tpu", "tools", "benchmarks", "examples",
 )
-SCAN_FILES: Tuple[str, ...] = ("bench.py",)
+SCAN_FILES: Tuple[str, ...] = ("bench.py", "chip_smoke.py")
 _EXCLUDE_PARTS = {"__pycache__"}
 
 

@@ -51,7 +51,7 @@ from .stateful import (  # noqa: F401
     Stateful,
 )
 
-__version__ = "0.1.0"
+__version__ = "0.2.0"
 
 __all__ = [
     "Snapshot",

@@ -1,5 +1,6 @@
 """Native extension loader: builds fastio.so on first use (g++, cached),
-falls back to pure Python silently when no toolchain is available.
+falls back to pure Python — logged once at WARNING — when no toolchain
+is available.
 
 Bindings are ctypes (no pybind11 in the image); all entry points release
 the GIL for the duration of the syscall chain, so the scheduler's worker
@@ -9,6 +10,8 @@ threads overlap I/O properly.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import logging
 import os
 import subprocess
@@ -24,97 +27,100 @@ _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _load_attempted = False
 
+# (compile flags, link flags) per attempt, most-preferred first.  zlib
+# linkage first (its SIMD crc32 beats our slice-by-8 ~2x); then without,
+# for hosts missing zlib.h/libz.  -march=native is a ~25% win for the
+# fused digest loops (the adler closed-form reductions vectorize), but an
+# ISA-specific binary must never outlive its host CPU: it is cached under
+# the CPU fingerprint and only ever loaded by a host with the same one (a
+# copied venv / NFS tree / docker image moved to an older CPU resolves to
+# a different name and rebuilds).
+_BASE_FLAGS = ("-O3", "-shared", "-fPIC")
+_NATIVE_VARIANTS = (
+    (("-march=native", "-DTSNP_USE_ZLIB"), ("-lz",)),
+    (("-march=native",), ()),
+)
+_PORTABLE_VARIANTS = ((("-DTSNP_USE_ZLIB",), ("-lz",)), ((), ()))
 
-def _so_candidates() -> list:
-    """Loadable cache paths, most-preferred first.
 
-    The CPU fingerprint is embedded in the FILENAME, so a native .so and
-    its provenance are published by ONE atomic rename — there is no
-    companion record that a crash or concurrent builder could leave
-    missing/stale (which would let a -march=native binary masquerade as
-    portable and SIGILL on an older CPU)."""
-    fp = _cpu_fingerprint()
-    cands = []
-    if fp:
-        cands.append(os.path.join(_HERE, f"fastio.{fp}.so"))
-    cands.append(os.path.join(_HERE, "fastio.portable.so"))
-    return cands
-
-
-def _build() -> Optional[str]:
-    # Compile to a process-unique temp file and os.replace into the
-    # fingerprint-named destination: atomic on posix, so concurrent
-    # first-use across processes (the multi-process tests spawn several)
-    # can never observe a half-written .so or a native .so under the
-    # portable name — worst case they each build once, last rename wins.
-    tmp = os.path.join(_HERE, f"fastio.so.tmp.{os.getpid()}")
-    # -march=native is a ~25% win for the fused digest loops (the adler
-    # closed-form reductions vectorize), but an ISA-specific binary must
-    # never outlive its host CPU: it is cached under fastio.<fp>.so and
-    # only ever loaded by a host with the same CPU-feature fingerprint
-    # (a copied venv / NFS tree / docker image moved to an older CPU
-    # resolves to a different name and rebuilds).  Hosts where the
-    # fingerprint cannot be read get portable flags only.
-    fp = _cpu_fingerprint()
-    # zlib linkage first (its SIMD crc32 beats our slice-by-8 ~2x);
-    # then without, for hosts missing zlib.h/libz
-    zflags = (["-DTSNP_USE_ZLIB"], ["-lz"])
-    native = (
-        [
-            (["-march=native", *zflags[0]], zflags[1], fp),
-            (["-march=native"], [], fp),
-        ]
-        if fp
-        else []
+def _build_key() -> str:
+    """Hash of fastio.cpp's CONTENT and of every flag set a build may
+    use.  It is part of every cached file's name, so a library built
+    from other source or other flags is simply never a candidate — file
+    times mean nothing after a copy or an artifact restore."""
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update(
+        repr((_BASE_FLAGS, _NATIVE_VARIANTS, _PORTABLE_VARIANTS)).encode()
     )
-    portable = [(zflags[0], zflags[1], ""), ([], [], "")]
+    return h.hexdigest()[:12]
+
+
+def _native_so(fp: str, key: str) -> str:
+    # The CPU fingerprint and the build key are embedded in the FILENAME,
+    # so a native .so and its provenance are published by ONE atomic
+    # rename — there is no companion record that a crash or concurrent
+    # builder could leave missing/stale (which would let a -march=native
+    # binary masquerade as portable and SIGILL on an older CPU).
+    return os.path.join(_HERE, f"fastio.{fp}.{key}.so")
+
+
+def _portable_so(key: str) -> str:
+    return os.path.join(_HERE, f"fastio.portable.{key}.so")
+
+
+def _no_native_marker(fp: str, key: str) -> str:
+    return os.path.join(_HERE, f"fastio.{fp}.{key}.nonative")
+
+
+def _build(fp: str, key: str) -> Optional[str]:
+    # Compile to a process-unique temp file and os.replace into the
+    # destination: atomic on posix, so concurrent first-use across
+    # processes (the multi-process tests spawn several) can never observe
+    # a half-written .so or a native .so under the portable name — worst
+    # case they each build once, last rename wins.
+    tmp = os.path.join(_HERE, f"fastio.so.tmp.{os.getpid()}")
     # ISA-specific variants exist ONLY when a CPU fingerprint can be
-    # recorded; order prefers zlib linkage (its SIMD crc32), then no-zlib
-    variants = native[:1] + portable[:1] + native[1:] + portable[1:]
-    for extra, libs, build_fp in variants:
+    # recorded; order prefers zlib linkage, then no-zlib
+    native = [(v, _native_so(fp, key)) for v in _NATIVE_VARIANTS] if fp else []
+    portable = [(v, _portable_so(key)) for v in _PORTABLE_VARIANTS]
+    attempts = native[:1] + portable[:1] + native[1:] + portable[1:]
+    for (cflags, libs), dest in attempts:
         try:
             subprocess.run(
-                [
-                    "g++",
-                    "-O3",
-                    *extra,
-                    "-shared",
-                    "-fPIC",
-                    "-o",
-                    tmp,
-                    _SRC,
-                    *libs,
-                ],
+                ["g++", *_BASE_FLAGS, *cflags, "-o", tmp, _SRC, *libs],
                 check=True,
                 capture_output=True,
                 timeout=120,
             )
-            dest = os.path.join(
-                _HERE,
-                f"fastio.{build_fp}.so" if build_fp else "fastio.portable.so",
-            )
             os.replace(tmp, dest)
-            if fp and not build_fp:
-                # every native variant failed on a fingerprintable host
-                # (e.g. a g++ that rejects -march=native): record that,
-                # so later processes accept the cached portable build
-                # instead of re-paying the failed native compiles on
-                # every startup
-                _publish_marker(_no_native_marker(fp))
-            return dest
-        except Exception as e:  # noqa: BLE001
+        except (OSError, subprocess.SubprocessError) as e:
             logger.debug(
-                "fastio build failed with %s (%r)", extra or "base flags", e
+                "fastio build failed with %s (%r)", cflags or "base flags", e
             )
             try:
                 os.remove(tmp)
             except OSError:
                 pass
+            continue
+        if fp and dest == _portable_so(key):
+            # every native variant failed on a fingerprintable host
+            # (e.g. a g++ that rejects -march=native): record that,
+            # so later processes accept the cached portable build
+            # instead of re-paying the failed native compiles on
+            # every startup
+            _publish_marker(_no_native_marker(fp, key))
+        # libraries and markers built from other source or flags can
+        # never be loaded again; same-key files for other CPUs stay
+        for stale in glob.glob(os.path.join(_HERE, "fastio.*")):
+            if stale.endswith((".so", ".nonative")) and key not in stale:
+                try:
+                    os.remove(stale)
+                except OSError:
+                    pass
+        return dest
     return None
-
-
-def _no_native_marker(fp: str) -> str:
-    return os.path.join(_HERE, f"fastio.{fp}.nonative")
 
 
 def _publish_marker(path: str) -> None:
@@ -131,8 +137,6 @@ def _cpu_fingerprint() -> str:
     """Hash of this host's CPU feature flags ('' when undeterminable —
     callers then avoid ISA-specific codegen entirely)."""
     try:
-        import hashlib
-
         with open("/proc/cpuinfo") as f:
             for line in f:
                 if line.startswith(("flags", "Features")):
@@ -145,6 +149,8 @@ def _cpu_fingerprint() -> str:
 
 
 def _try_load(path: str) -> Optional[ctypes.CDLL]:
+    if not os.path.exists(path):
+        return None
     try:
         return ctypes.CDLL(path)
     except OSError as e:
@@ -160,41 +166,32 @@ def load() -> Optional[ctypes.CDLL]:
             return _lib
         _load_attempted = True
 
-        def _fresh(path: str) -> bool:
-            try:
-                return os.path.getmtime(path) >= os.path.getmtime(_SRC)
-            except OSError:
-                return False
-
-        cands = _so_candidates()
+        fp, key = _cpu_fingerprint(), _build_key()
         # Only the PREFERRED (native, when fingerprintable) candidate is
-        # accepted from cache: settling for a fresh portable .so while
-        # the native one is stale/absent would silently forfeit the
-        # -march=native win forever (a successful load skips _build) —
-        # UNLESS a fresh .nonative marker records that native compilation
-        # already failed for this CPU, in which case the cached portable
-        # build is the best achievable and rebuilding every process would
-        # just re-pay the failed native compiles.
-        lib = _try_load(cands[0]) if _fresh(cands[0]) else None
-        if (
-            lib is None
-            and len(cands) > 1
-            and _fresh(_no_native_marker(_cpu_fingerprint()))
-            and _fresh(cands[-1])
-        ):
-            lib = _try_load(cands[-1])
+        # accepted from cache: settling for a portable .so while the
+        # native one is absent would silently forfeit the -march=native
+        # win forever (a successful load skips _build) — UNLESS a
+        # .nonative marker records that native compilation already
+        # failed for this CPU and this source, in which case the cached
+        # portable build is the best achievable and rebuilding every
+        # process would just re-pay the failed native compiles.
+        preferred = _native_so(fp, key) if fp else _portable_so(key)
+        lib = _try_load(preferred)
+        if lib is None and fp and os.path.exists(_no_native_marker(fp, key)):
+            lib = _try_load(_portable_so(key))
         if lib is None:
-            dest = _build()
+            dest = _build(fp, key)
             lib = _try_load(dest) if dest else None
         if lib is None:
-            # no toolchain: any fresh lesser candidate beats the pure-
-            # python fallback
-            for cand in cands[1:]:
-                if _fresh(cand):
-                    lib = _try_load(cand)
-                    if lib is not None:
-                        break
+            # no toolchain: a same-source portable library beats the
+            # pure-python fallback
+            lib = _try_load(_portable_so(key))
         if lib is None:
+            logger.warning(
+                "native fastio library unavailable (no cached build for "
+                "this source and g++ failed or is missing); storage, "
+                "digest and codec paths run in pure Python"
+            )
             return None
         lib.tsnp_write_file.restype = ctypes.c_int
         lib.tsnp_write_file.argtypes = [
@@ -203,21 +200,14 @@ def load() -> Optional[ctypes.CDLL]:
             ctypes.c_int64,
             ctypes.c_int,
         ]
-        try:
-            # newer symbol: a cached .so from older source that slips
-            # past the mtime freshness check (e.g. artifact restores
-            # stamping fresh mtimes) must degrade to the unfused path,
-            # not crash every native-ext consumer out of load()
-            lib.tsnp_write_file_digest.restype = ctypes.c_int
-            lib.tsnp_write_file_digest.argtypes = [
-                ctypes.c_char_p,
-                ctypes.c_void_p,
-                ctypes.c_int64,
-                ctypes.c_int,
-                ctypes.POINTER(ctypes.c_uint32),
-            ]
-        except AttributeError:
-            logger.debug("loaded fastio lacks tsnp_write_file_digest")
+        lib.tsnp_write_file_digest.restype = ctypes.c_int
+        lib.tsnp_write_file_digest.argtypes = [
+            ctypes.c_char_p,
+            ctypes.c_void_p,
+            ctypes.c_int64,
+            ctypes.c_int,
+            ctypes.POINTER(ctypes.c_uint32),
+        ]
         lib.tsnp_read_file.restype = ctypes.c_int64
         lib.tsnp_read_file.argtypes = [
             ctypes.c_char_p,
@@ -258,61 +248,48 @@ def load() -> Optional[ctypes.CDLL]:
             ctypes.c_int64,
             ctypes.POINTER(ctypes.c_uint32),
         ]
-        try:
-            # newer symbols (the fast-I/O engine, storage/fastio.py):
-            # tolerate a cached .so from older source — the engine then
-            # reports itself unavailable and the fs plugin keeps the
-            # pre-engine native path
-            lib.tsnp_part_pwrite.restype = ctypes.c_int
-            lib.tsnp_part_pwrite.argtypes = [
-                ctypes.c_int,
-                ctypes.c_int,
+        # the fast-I/O engine (storage/fastio.py)
+        lib.tsnp_part_pwrite.restype = ctypes.c_int
+        lib.tsnp_part_pwrite.argtypes = [
+            ctypes.c_int,
+            ctypes.c_int,
+            ctypes.c_void_p,
+            ctypes.c_int64,
+            ctypes.c_int64,
+            ctypes.c_int64,
+            ctypes.c_void_p,
+            ctypes.c_int64,
+            ctypes.c_int,
+            ctypes.POINTER(ctypes.c_uint32),
+        ]
+        lib.tsnp_part_pread.restype = ctypes.c_int64
+        lib.tsnp_part_pread.argtypes = [
+            ctypes.c_int,
+            ctypes.c_int,
+            ctypes.c_void_p,
+            ctypes.c_int64,
+            ctypes.c_int64,
+            ctypes.c_int64,
+            ctypes.c_void_p,
+            ctypes.c_int64,
+        ]
+        # the "huff" block codec
+        for fn in (lib.tsnp_huff_compress, lib.tsnp_huff_decompress):
+            fn.restype = ctypes.c_int64
+            fn.argtypes = [
                 ctypes.c_void_p,
                 ctypes.c_int64,
-                ctypes.c_int64,
-                ctypes.c_int64,
                 ctypes.c_void_p,
                 ctypes.c_int64,
-                ctypes.c_int,
-                ctypes.POINTER(ctypes.c_uint32),
             ]
-            lib.tsnp_part_pread.restype = ctypes.c_int64
-            lib.tsnp_part_pread.argtypes = [
-                ctypes.c_int,
-                ctypes.c_int,
+        for fn in (lib.tsnp_byte_shuffle, lib.tsnp_byte_unshuffle):
+            fn.restype = None
+            fn.argtypes = [
                 ctypes.c_void_p,
                 ctypes.c_int64,
                 ctypes.c_int64,
-                ctypes.c_int64,
                 ctypes.c_void_p,
-                ctypes.c_int64,
             ]
-        except AttributeError:
-            logger.debug("loaded fastio lacks the part pwrite/pread symbols")
-        try:
-            # newer symbols (the "huff" block codec): tolerate a cached
-            # .so from older source — codec.py then reports huff
-            # unavailable instead of crashing every native-ext consumer
-            for sym in ("tsnp_huff_compress", "tsnp_huff_decompress"):
-                fn = getattr(lib, sym)
-                fn.restype = ctypes.c_int64
-                fn.argtypes = [
-                    ctypes.c_void_p,
-                    ctypes.c_int64,
-                    ctypes.c_void_p,
-                    ctypes.c_int64,
-                ]
-            for sym in ("tsnp_byte_shuffle", "tsnp_byte_unshuffle"):
-                fn = getattr(lib, sym)
-                fn.restype = None
-                fn.argtypes = [
-                    ctypes.c_void_p,
-                    ctypes.c_int64,
-                    ctypes.c_int64,
-                    ctypes.c_void_p,
-                ]
-        except AttributeError:
-            logger.debug("loaded fastio lacks the huff codec symbols")
         _lib = lib
         return _lib
 
@@ -376,11 +353,11 @@ def digest(data) -> Optional[tuple]:
 def byte_shuffle(data, stride: int, inverse: bool = False):
     """Byte-shuffle (or unshuffle) ``data`` with the native cache-blocked
     transpose — GIL-free, one pass, no intermediate copy; None when the
-    native lib (or its shuffle symbols) is unavailable."""
+    native lib is unavailable."""
     import numpy as np
 
     lib = load()
-    if lib is None or not hasattr(lib, "tsnp_byte_shuffle"):
+    if lib is None:
         return None
     view = memoryview(data).cast("B")
     out = np.empty(view.nbytes, dtype=np.uint8)
@@ -390,14 +367,13 @@ def byte_shuffle(data, stride: int, inverse: bool = False):
 
 
 def huff_available() -> bool:
-    """True when the loaded native lib carries the huff codec symbols."""
-    lib = load()
-    return lib is not None and hasattr(lib, "tsnp_huff_compress")
+    """True when the native lib (which carries the huff codec) loaded."""
+    return load() is not None
 
 
 def huff_compress(data, headroom: int = 0):
     """Compress ``data`` with the native block-Huffman coder; None when
-    the native lib (or its huff symbols) is unavailable.  The returned
+    the native lib is unavailable.  The returned
     stream may exceed the input by ~5 bytes per 128KB block on
     incompressible data (raw-mode blocks) — codec.py's min-ratio check
     handles store-raw fallback above this layer.
@@ -409,7 +385,7 @@ def huff_compress(data, headroom: int = 0):
     import numpy as np
 
     lib = load()
-    if lib is None or not hasattr(lib, "tsnp_huff_compress"):
+    if lib is None:
         return None
     view = memoryview(data).cast("B")
     if view.nbytes == 0:
@@ -443,7 +419,7 @@ def huff_decompress(data, raw_len: int):
     import numpy as np
 
     lib = load()
-    if lib is None or not hasattr(lib, "tsnp_huff_decompress"):
+    if lib is None:
         return None
     view = memoryview(data).cast("B")
     if raw_len == 0 and view.nbytes == 0:

@@ -13,9 +13,10 @@ same property the reference relies on.
 
 The reference's GPU-slab variant (pack on device + single DtoH,
 batcher.py:104-162) has a TPU analogue here: when every slab member is a
-device jax.Array, the slab is packed on device (bitcast-to-uint8 +
-concatenate as one XLA op, ops/device_pack.py) and fetched in a single
-transfer, with host-side packing as the fallback.
+device jax.Array of one element width on one device, the slab is packed
+on device (same-width bitcast + concatenate as one XLA op,
+ops/device_pack.py) and fetched in a single transfer; every other slab
+packs on the host.
 """
 
 from __future__ import annotations
@@ -50,11 +51,13 @@ class BatchedBufferStager(BufferStager):
     """Stage sub-buffers into one slab (reference BatchedBufferStager,
     batcher.py:51-103).
 
-    When every member is a device jax.Array, the slab is packed ON DEVICE
-    (bitcast+concat, one XLA op) and fetched with a single transfer — the
-    TPU analogue of the reference's GPU slab (batcher.py:104-162), with
-    host-side fallback on any failure (ditto its OOM fallback,
-    batcher.py:144-152)."""
+    When every member is a device jax.Array with the same element width
+    (what ``batch_write_requests`` groups by) on the same device(s), the
+    slab is packed ON DEVICE (same-width bitcast+concat, one XLA op) and
+    fetched with a single transfer — the TPU analogue of the reference's
+    GPU slab (batcher.py:104-162).  Any other slab packs on the host by
+    choice; a device pack that FAILS (ditto the reference's OOM fallback,
+    batcher.py:144-152) also lands there, logged and counted."""
 
     def __init__(self, stagers: List[Tuple[BufferStager, int]], total: int):
         self.stagers = stagers
@@ -63,6 +66,9 @@ class BatchedBufferStager(BufferStager):
 
         self._all_jax = all(
             isinstance(s, JaxArrayBufferStager) for s, _ in stagers
+        )
+        self._device_packable = self._all_jax and (
+            len({_pack_group(s) for s, _ in stagers}) == 1
         )
 
     async def stage_buffer(self, executor: Optional[Executor] = None) -> memoryview:
@@ -81,11 +87,14 @@ class BatchedBufferStager(BufferStager):
         # Members already offloaded to host memory kind must NOT go through
         # the device pack: computing (concat) on host-kind arrays is not a
         # supported XLA path — copy them out individually instead.
-        if self._all_jax and not self._any_member_on_host():
+        if self._device_packable and not self._any_member_on_host():
             try:
                 return await self._stage_device_packed(executor)
-            except Exception:  # fall back to host-side packing
-                logger.debug("device slab pack failed; host fallback", exc_info=True)
+            except Exception as e:  # e.g. HBM OOM: host-side packing
+                logger.warning(
+                    "device slab pack failed; host fallback", exc_info=True
+                )
+                obs.swallowed_exception("batcher.device_pack", e)
         # Host fallback stages members SEQUENTIALLY so peak memory stays at
         # slab + one member — matching get_staging_cost_bytes regardless of
         # which path ran.  When the native engine is present, each member
@@ -212,6 +221,16 @@ class BatchedBufferStager(BufferStager):
         return self.total + max_member
 
 
+def _pack_group(stager: Any) -> Tuple:
+    """What the operands of one device pack must share: the device(s)
+    they are committed to (one jit takes no others) and the element
+    width (each joins the slab by a same-width bitcast)."""
+    from .ops.device_pack import packed_width
+
+    arr = stager.arr
+    return tuple(sorted(d.id for d in arr.devices())), packed_width(arr.dtype)
+
+
 def _byte_range_targets(entries: Dict[str, Entry]) -> Dict[str, Any]:
     """location → the manifest record whose (location, byte_range) must be
     re-pointed when its blob moves into a slab."""
@@ -245,7 +264,7 @@ def batch_write_requests(
         # big HOST members skip the slab: their pack is a pure extra
         # memcpy with nothing left to amortize.  Device members stay
         # eligible at any size — the device pack collapses N transfers
-        # into one (the win that matters on a tunneled D2H link).
+        # into one.
         fits = 0 < cost < threshold and (
             cost < host_member_max
             or isinstance(wr.buffer_stager, JaxArrayBufferStager)
@@ -259,22 +278,24 @@ def batch_write_requests(
 
     # Device members and host/object members slab SEPARATELY: a single
     # host member in a slab would make _all_jax false and forfeit the
-    # device pack (one D2H transfer per slab — the win the slab exists
-    # for on a tunneled link), and symmetrically poison the read-side
-    # device unpack for every array in the merged run.
+    # device pack (one D2H transfer per slab), and symmetrically poison
+    # the read-side device unpack for every array in the merged run.
+    # Device members further split by element width, so every member
+    # joins its slab by a same-width bitcast (ops/device_pack.py).
+    from .ops.device_pack import packed_width
+
     small.sort(key=lambda x: x[0].path)  # deterministic slab layout
-    groups = [
-        [
-            (wr, c)
-            for wr, c in small
-            if isinstance(wr.buffer_stager, JaxArrayBufferStager)
-        ],
-        [
-            (wr, c)
-            for wr, c in small
-            if not isinstance(wr.buffer_stager, JaxArrayBufferStager)
-        ],
-    ]
+    by_width: Dict[int, List[Tuple[WriteReq, int]]] = {}  # 0 = host
+    for wr, c in small:
+        st = wr.buffer_stager
+        width = (
+            packed_width(st.arr.dtype)
+            if isinstance(st, JaxArrayBufferStager)
+            else 0
+        )
+        by_width.setdefault(width, []).append((wr, c))
+    # device groups widest first, host members last
+    groups = [by_width[w] for w in sorted(by_width, reverse=True)]
     slabs: List[List[Tuple[WriteReq, int]]] = []
     new_reqs = list(rest)
     for group in groups:
@@ -395,6 +416,8 @@ class _MergedRangeConsumer(BufferConsumer):
         from .preparers.array import ArrayBufferConsumer, _is_jax_array
         from .serialization import BUFFER_PROTOCOL, string_to_dtype
 
+        from .ops.device_pack import slab_word_bytes, unpack_slab_to_device
+
         members = []
         out_dtypes = []
         consumers = []
@@ -435,19 +458,26 @@ class _MergedRangeConsumer(BufferConsumer):
                 )
                 out_dtypes.append(np.dtype(out.dtype))
                 consumers.append(c)
-            if not consumers:
+            # mixed element widths or unaligned members (older layouts),
+            # or 8-byte elements the device would narrow: the host path
+            # BY CHOICE, not a counted failure
+            if not consumers or slab_word_bytes(members) is None:
                 return False
-            from .ops.device_pack import unpack_slab_to_device
-
             arrays = unpack_slab_to_device(
                 view, tuple(members), tuple(out_dtypes), device
             )
-        except Exception:  # noqa: BLE001 — host path is always correct
-            logger.debug("device slab unpack failed; host fallback",
-                         exc_info=True)
+        except Exception as e:  # noqa: BLE001 — host path is always correct
+            logger.warning(
+                "device slab unpack failed; host fallback", exc_info=True
+            )
+            obs.swallowed_exception("batcher.device_unpack", e)
             return False
+        from .preparers.array import donate_template
+
         for c, arr in zip(consumers, arrays):
             c.fut.set(arr)
+            # strictly after fut.set: donated ⟹ replacement reachable
+            donate_template(c.obj_out)
         return True
 
     def get_consuming_cost_bytes(self) -> int:

@@ -7,14 +7,17 @@ equivalent is explicit host offload via ``jax`` memory kinds
 (``pinned_host``): arrays placed there are addressable from the host, so
 staging them is a zero-copy ``np.asarray`` instead of a D2H transfer — the
 preparers handle them transparently; this module provides the placement
-helpers and feature detection, with no-op fallbacks when the runtime lacks
-the memories API (same graceful-degradation contract as the reference).
+helpers and feature detection.  Every backend of the supported jax
+(cpu included) exposes ``pinned_host``; a runtime that does not fails
+loudly in ``with_memory_kind`` instead of passing arrays through.
 """
 
 from __future__ import annotations
 
 import logging
 from typing import Any, Iterator, List
+
+from . import obs
 
 _HOST_KINDS = ("pinned_host", "unpinned_host")
 
@@ -28,12 +31,8 @@ logger = logging.getLogger(__name__)
 def host_memory_supported() -> bool:
     import jax
 
-    try:
-        dev = jax.devices()[0]
-        kinds = {m.kind for m in dev.addressable_memories()}
-        return any(k in kinds for k in _HOST_KINDS)
-    except Exception:
-        return False
+    kinds = {m.kind for m in jax.devices()[0].addressable_memories()}
+    return any(k in kinds for k in _HOST_KINDS)
 
 
 def is_host_offloaded(arr: Any) -> bool:
@@ -44,14 +43,10 @@ def is_host_offloaded(arr: Any) -> bool:
 
 
 def offload_to_host(arr: Any):
-    """Move an array to pinned host memory (no-op passthrough when the
-    runtime doesn't support it)."""
+    """Move an array to pinned host memory."""
     import jax
 
-    if not host_memory_supported():
-        return arr
-    sharding = arr.sharding.with_memory_kind("pinned_host")
-    return jax.device_put(arr, sharding)
+    return jax.device_put(arr, arr.sharding.with_memory_kind("pinned_host"))
 
 
 def to_device(arr: Any):
@@ -118,12 +113,13 @@ def _watch_releases(q) -> None:
                 continue
             try:
                 jax.block_until_ready(host_arrays)
-            except Exception:
+            except Exception as e:
                 logger.warning(
                     "eager pinned-host offload failed after dispatch; "
                     "device refs retained for fallback staging",
                     exc_info=True,
                 )
+                obs.swallowed_exception("host_offload.async_transfer", e)
                 continue
             for sts in stager_lists:
                 for st in sts:
@@ -203,12 +199,12 @@ def eager_offload_write_reqs(
     next step; for non-chunked leaves a large enough offload budget also
     suffices.
 
-    Returns the number of bytes made training-independent.  Degrades to a
-    defensive-copy-only pass when the runtime lacks host memory kinds
-    (e.g. CPU meshes).
+    Returns the number of bytes made training-independent.  Every leaf
+    that is left to stage lazily — skipped by the budget, or because the
+    offload dispatch failed — is logged at WARNING and counted through
+    ``obs.swallowed_exception``: the mechanism stays, its silence does
+    not.
     """
-    from . import obs
-
     with obs.span("offload/eager", reqs=len(write_reqs)) as sp:
         moved = _eager_offload_impl(write_reqs, budget_bytes)
         if sp is not None:
@@ -248,6 +244,7 @@ def _eager_offload_impl(write_reqs, budget_bytes: int | None = None) -> int:
 
         arrays, shardings, keys = [], [], []
         claimed = 0
+        skipped = 0
         for key, sts in by_array.items():
             a = sts[0].arr
             if is_host_offloaded(a):
@@ -257,16 +254,22 @@ def _eager_offload_impl(write_reqs, budget_bytes: int | None = None) -> int:
             # device would break donated train states (the next step
             # deletes the buffers they'd stage from).
             if budget_bytes is not None and claimed + a.nbytes > budget_bytes:
-                continue  # stage lazily; safe by immutability (NOT under
-                # donation — see docstring)
-            try:
-                sh = a.sharding.with_memory_kind("pinned_host")
-            except Exception:
-                continue
+                skipped += a.nbytes  # stages lazily; safe by immutability
+                continue  # (NOT under donation — see docstring)
             arrays.append(a)
-            shardings.append(sh)
+            shardings.append(a.sharding.with_memory_kind("pinned_host"))
             keys.append(key)
             claimed += a.nbytes
+        if skipped:
+            logger.warning(
+                "eager host offload: %d bytes exceed the %d-byte budget "
+                "and will stage lazily from the device arrays",
+                skipped, budget_bytes,
+            )
+            obs.swallowed_exception(
+                "host_offload.budget_skip",
+                MemoryError(f"{skipped} bytes past budget {budget_bytes}"),
+            )
         if arrays:
             try:
                 # Dispatch ONE batched DMA and return without waiting for
@@ -277,12 +280,13 @@ def _eager_offload_impl(write_reqs, budget_bytes: int | None = None) -> int:
                 # not transfer completion — HBM is released as the DMA
                 # drains, a fraction of a second later.
                 host_arrays = jax.device_put(arrays, shardings)
-            except Exception:
+            except Exception as e:
                 logger.warning(
-                    "eager host offload unavailable; arrays will stage "
-                    "lazily (safe: jax.Array is immutable)",
+                    "eager host offload dispatch failed; arrays will "
+                    "stage lazily (safe: jax.Array is immutable)",
                     exc_info=True,
                 )
+                obs.swallowed_exception("host_offload.device_put", e)
                 host_arrays = None
             if host_arrays is not None:
                 stager_lists = []

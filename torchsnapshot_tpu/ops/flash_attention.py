@@ -31,6 +31,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _BQ = 128  # query rows per program
 _BK = 128  # kv rows per inner step
@@ -38,75 +40,9 @@ _LANE = 128  # TPU lane width; head_dim padded up to a multiple
 
 _NEG_INF = float("-inf")
 
-try:  # pallas availability probe (older jax, exotic platforms)
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    # the shard_map integration also needs the vma-aware APIs (jax>=0.8:
-    # ShapeDtypeStruct(..., vma=...) and shard_map(check_vma=...)); treat
-    # their absence as pallas-unavailable so every caller falls back to
-    # the XLA path together
-    jax.ShapeDtypeStruct((1,), jnp.float32, vma=frozenset())
-    PALLAS_AVAILABLE = hasattr(jax, "shard_map")
-except Exception:  # pragma: no cover
-    pl = None
-    pltpu = None
-    PALLAS_AVAILABLE = False
-
 
 def _use_interpret() -> bool:
     return jax.default_backend() == "cpu"
-
-
-_PROBE_VERDICT = None
-
-
-def pallas_probe_ok() -> bool:
-    """Compile-and-run a minimal kernel once on the current backend and
-    cache the verdict — how knobs' "auto" decides whether this TPU
-    attachment actually supports Mosaic compilation (some tunneled /
-    virtualized TPU runtimes don't).  A failed probe logs and falls back
-    to the XLA attention path; it never raises."""
-    global _PROBE_VERDICT
-    if _PROBE_VERDICT == "probing":
-        # re-entered from the custom_vjp bwd of the probe's own grad:
-        # answer yes so the probe exercises the PALLAS backward (what
-        # it exists to validate); a compile failure still fails the
-        # outer probe
-        return True
-    if _PROBE_VERDICT is None:
-        if not PALLAS_AVAILABLE:
-            _PROBE_VERDICT = False
-        else:
-            _PROBE_VERDICT = "probing"
-            try:
-                x = jnp.zeros((1, _BQ, 1, _LANE), jnp.bfloat16)
-                jax.block_until_ready(flash_attention(x, x, x, causal=True))
-                # the backward kernels are separate Mosaic programs
-                # (i32 scratch, transposed grid): a runtime where only
-                # the forward compiles must fall back as a unit, or the
-                # first jax.grad step would crash uncatchably
-                g = jax.grad(
-                    lambda q: jnp.sum(
-                        flash_attention(q, x, x, causal=True).astype(
-                            jnp.float32
-                        )
-                        ** 2
-                    )
-                )(x)
-                jax.block_until_ready(g)
-                _PROBE_VERDICT = True
-            except Exception:
-                import logging
-
-                logging.getLogger(__name__).warning(
-                    "pallas probe-compile failed on backend %r; ring "
-                    "attention will use the XLA fallback",
-                    jax.default_backend(),
-                    exc_info=True,
-                )
-                _PROBE_VERDICT = False
-    return _PROBE_VERDICT
 
 
 def _block_scores(

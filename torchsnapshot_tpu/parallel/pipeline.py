@@ -30,15 +30,11 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    from .mesh import get_shard_map
-
-    sm, new_style = get_shard_map()
     # the masked psum broadcast of the last stage's outputs is varying
-    # by construction; skip the replication checker (kwarg name differs
-    # across the jax>=0.8 API split)
-    kwargs = {"check_vma": False} if new_style else {"check_rep": False}
-    return sm(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
+    # by construction; skip the replication checker
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
     )
 
 

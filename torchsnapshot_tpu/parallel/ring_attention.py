@@ -70,19 +70,10 @@ def ring_attention_shard(
     from .. import knobs
 
     if knobs.use_pallas_attention():
-        from ..ops.flash_attention import (
-            PALLAS_AVAILABLE,
-            flash_attention_partials,
-        )
+        from ..ops.flash_attention import flash_attention_partials
 
-        attend = (
-            functools.partial(flash_attention_partials, vma=(axis_name,))
-            if PALLAS_AVAILABLE
-            else None
-        )
+        attend = functools.partial(flash_attention_partials, vma=(axis_name,))
     else:
-        attend = None
-    if attend is None:
         attend = _block_attend
 
     # Derive the fresh carries FROM q so they inherit q's device-varying
@@ -144,32 +135,23 @@ def ring_attention(
     ``batch_axis``)."""
     from jax.sharding import PartitionSpec as P
 
-    from .mesh import get_shard_map
-
-    shard_map, new_style = get_shard_map()
+    from .. import knobs
 
     spec = P(batch_axis, axis_name, None, None)
-    kwargs = {}
-    from .. import knobs
-    from ..ops.flash_attention import PALLAS_AVAILABLE
-
-    if knobs.use_pallas_attention() and PALLAS_AVAILABLE and new_style:
-        # pallas_call's interpret-mode discharge mixes varying and
-        # unvarying operands in its internal dynamic_slices, which trips
-        # shard_map's vma checker (jax suggests check_vma=False as the
-        # workaround); the numerics are covered by the dense-oracle tests.
-        # Gated exactly like the shard-level kernel selection so the
-        # plain XLA path keeps vma checking (and old-style shard_map,
-        # which lacks the kwarg, is never passed it).
-        kwargs["check_vma"] = False
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             ring_attention_shard, axis_name=axis_name, causal=causal
         ),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        **kwargs,
+        # pallas_call's interpret-mode discharge mixes varying and
+        # unvarying operands in its internal dynamic_slices, which trips
+        # shard_map's vma checker (jax suggests check_vma=False as the
+        # workaround); the numerics are covered by the dense-oracle tests.
+        # Gated exactly like the shard-level kernel selection so the
+        # plain XLA path keeps vma checking.
+        check_vma=not knobs.use_pallas_attention(),
     )
     return fn(q, k, v)
 

@@ -79,10 +79,8 @@ def _sharding_metadata(sharding: Any) -> Tuple[Optional[List[str]], Optional[Lis
     """Extract (mesh_axis_names, mesh_shape, spec) from a NamedSharding for
     the manifest (advisory; analogue of DTensorEntry's mesh+dim_map,
     reference manifest.py:211-261)."""
-    try:
-        from jax.sharding import NamedSharding
-    except ImportError:  # pragma: no cover
-        return None, None, None
+    from jax.sharding import NamedSharding
+
     if not isinstance(sharding, NamedSharding):
         return None, None, None
     mesh = sharding.mesh

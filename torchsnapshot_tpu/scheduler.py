@@ -56,6 +56,7 @@ from .io_types import (
 )
 from .obs import buf_nbytes as _buf_nbytes
 from .obs import metrics as obs_metrics
+from .obs import swallowed_exception
 from .obs import tracer as obs_tracer
 from .resilience.failpoints import failpoint
 from .storage import stripe
@@ -231,18 +232,18 @@ class _LoopThread:
         # is otherwise an async pipeline task — a multi-second compile
         # on the event loop stalls every in-flight pipeline at once
         # (surfaced by snaplint effect-escape; load() is memoized, so
-        # this costs one no-op lock acquire ever after).  Best-effort:
-        # a loader failure here must not kill the thread before
-        # run_forever, or every submit() would hang on a dead loop —
-        # the first real native user re-hits load() and degrades to
-        # the pure-python path as before.
+        # this costs one no-op lock acquire ever after).  A loader
+        # failure here must not kill the thread before run_forever, or
+        # every submit() would hang on a dead loop — it is logged and
+        # counted, and load() (memoized) answers None from then on.
         try:
             _csrc.load()
-        except Exception:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001
             logger.warning(
                 "native fastio warm-up failed; continuing without it",
                 exc_info=True,
             )
+            swallowed_exception("scheduler.fastio_warmup", e)
         self.loop.run_forever()
 
     def submit(self, coro: Awaitable) -> concurrent.futures.Future:

@@ -24,19 +24,16 @@ from typing import Any, Tuple
 
 import numpy as np
 
-try:
-    import ml_dtypes
+import ml_dtypes
 
-    _ML_DTYPES = {
-        "bfloat16": np.dtype(ml_dtypes.bfloat16),
-        "float8_e4m3fn": np.dtype(ml_dtypes.float8_e4m3fn),
-        "float8_e5m2": np.dtype(ml_dtypes.float8_e5m2),
-        "float8_e4m3fnuz": np.dtype(getattr(ml_dtypes, "float8_e4m3fnuz", ml_dtypes.float8_e4m3fn)),
-        "int4": np.dtype(ml_dtypes.int4),
-        "uint4": np.dtype(ml_dtypes.uint4),
-    }
-except ImportError:  # pragma: no cover - ml_dtypes ships with jax
-    _ML_DTYPES = {}
+_ML_DTYPES = {
+    "bfloat16": np.dtype(ml_dtypes.bfloat16),
+    "float8_e4m3fn": np.dtype(ml_dtypes.float8_e4m3fn),
+    "float8_e5m2": np.dtype(ml_dtypes.float8_e5m2),
+    "float8_e4m3fnuz": np.dtype(ml_dtypes.float8_e4m3fnuz),
+    "int4": np.dtype(ml_dtypes.int4),
+    "uint4": np.dtype(ml_dtypes.uint4),
+}
 
 from . import knobs
 

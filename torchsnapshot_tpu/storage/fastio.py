@@ -172,17 +172,10 @@ def _probe_direct_readonly(root: str, flag: int) -> bool:
 
 def create_engine(lib: Any, root: str) -> Optional["FastIOEngine"]:
     """The fs plugin's one probe point: a :class:`FastIOEngine` when the
-    knob is on and ``lib`` carries the engine symbols, else None (the
+    knob is on and the native ``lib`` loaded, else None (the
     plugin keeps its pre-engine paths).  O_DIRECT support is probed
     here, once per plugin — never per op."""
     if lib is None or not knobs.fastio_enabled():
-        return None
-    if not hasattr(lib, "tsnp_part_pwrite") or not hasattr(
-        lib, "tsnp_part_pread"
-    ):
-        # stale cached .so from older source slipped past the mtime
-        # freshness check: degrade, don't crash
-        logger.debug("fastio engine symbols missing from loaded lib")
         return None
     want_direct = knobs.fastio_direct_enabled()
     direct_ok = probe_direct(root) if want_direct else False

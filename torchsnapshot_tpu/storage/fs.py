@@ -160,22 +160,16 @@ class FSStoragePlugin(StoragePlugin):
             from .. import _csrc
 
             self._lib = _csrc.load()
-        # fast-I/O engine (storage/fastio.py): probed ONCE here — knob,
-        # engine symbols, and the root's O_DIRECT support all resolve
-        # at plugin init, never per op
+        # fast-I/O engine (storage/fastio.py): probed ONCE here — the
+        # knob and the root's O_DIRECT support resolve at plugin init,
+        # never per op
         self._fastio = None
         if self._lib is not None:
             from . import fastio as _fastio_mod
 
             self._fastio = _fastio_mod.create_engine(self._lib, root)
         # fused digest-while-writing is only real on the native path
-        self.supports_fused_digest = bool(
-            self._fastio is not None
-            or (
-                self._lib is not None
-                and hasattr(self._lib, "tsnp_write_file_digest")
-            )
-        )
+        self.supports_fused_digest = self._lib is not None
         # part-level twin: the engine's pwrite_part fuses each striped
         # part's digest into the write, so the scheduler may defer
         # digest work for stripe-eligible writes too
@@ -349,7 +343,7 @@ class FSStoragePlugin(StoragePlugin):
                 digests = self._fastio.write_file(
                     tmp, view, sync_file, want_digest
                 )
-            elif want_digest and hasattr(self._lib, "tsnp_write_file_digest"):
+            elif want_digest:
                 out = (ctypes.c_uint32 * 2)()
                 rc = self._lib.tsnp_write_file_digest(
                     tmp.encode(), addr, view.nbytes, 1 if sync_file else 0, out

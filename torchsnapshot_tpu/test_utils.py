@@ -119,7 +119,7 @@ def run_multiprocess(
             )
             + textwrap.dedent(body)
         )
-    env = {**os.environ, "PYTHONPATH": "", "JAX_PLATFORMS": "cpu"}
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     procs = [
         subprocess.Popen(
             [sys.executable, script, str(r), str(world_size)],

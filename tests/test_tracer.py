@@ -371,3 +371,229 @@ def test_cli_trace_command(tmp_path, capsys):
     names = {e["name"] for e in doc["traceEvents"] if e["ph"] == "X"}
     assert "storage/read" in names and "materialize" in names
     assert not obs.tracing_enabled()  # CLI restored the knob
+
+
+# ------------------------------------------------------------ executor hop
+
+
+def _walk_to_root(span, by_id):
+    while span.parent_id is not None:
+        span = by_id[span.parent_id]
+    return span
+
+
+def test_hop_splits_queue_wait_from_work_and_links_the_parent(traced):
+    """A one-worker pool held by a blocker: the hop's worker span starts
+    when the worker picks it up, ``queue_ns`` holds the wait before that,
+    and its parent is the span that submitted it on the loop thread."""
+    import asyncio
+    import time
+    from concurrent.futures import ThreadPoolExecutor
+
+    release = threading.Event()
+    pool = ThreadPoolExecutor(1, thread_name_prefix="tsnp-test")
+
+    def work(x):
+        time.sleep(0.02)
+        return x + 1
+
+    async def main():
+        blocker = pool.submit(release.wait)
+        with obs.span("loop_side") as submitting:
+            fut = obs.run_in_executor(pool, work, 41, name="consume/test", nbytes=7)
+            await asyncio.sleep(0.08)
+            release.set()
+            out = await fut
+        blocker.result()
+        return out, submitting
+
+    try:
+        out, submitting = _run_coro(main())
+    finally:
+        release.set()
+        pool.shutdown()
+    assert out == 42
+    spans = {s.name: s for s in traced.spans()}
+    hop = spans["consume/test"]
+    assert hop.parent_id == submitting.span_id
+    assert hop.thread_name.startswith("tsnp-test") and hop.thread_id != submitting.thread_id
+    assert hop.attrs["bytes"] == 7
+    assert hop.attrs["queue_ns"] >= 70e6  # held for 80 ms before a worker was free
+    assert 15e6 <= hop.duration_ns < hop.attrs["queue_ns"]  # the work alone
+    # the wait lies before the span, not inside it
+    assert hop.start_ns - submitting.start_ns >= hop.attrs["queue_ns"] * 0.9
+
+
+def test_hop_with_tracing_off_is_the_plain_call(monkeypatch):
+    """Off: one flag read, then ``loop.run_in_executor(executor, fn, *args)``
+    itself — no span, no clock read, no context copy, no wrapper on the
+    worker."""
+    import asyncio
+    from concurrent.futures import ThreadPoolExecutor
+
+    assert not obs.tracing_enabled()
+
+    def forbidden(*_a, **_k):
+        raise AssertionError("the disabled hop touched the tracer's machinery")
+
+    class NoClock:
+        monotonic_ns = staticmethod(forbidden)
+        monotonic = perf_counter = staticmethod(forbidden)
+
+    monkeypatch.setattr(tracer_mod, "time", NoClock)
+    monkeypatch.setattr(tracer_mod, "copy_context", forbidden)
+    monkeypatch.setattr(tracer_mod, "_hop", forbidden)
+    monkeypatch.setattr(tracer_mod, "Span", forbidden)
+    submitted = []
+    pool = ThreadPoolExecutor(1)
+
+    async def main():
+        loop = asyncio.get_running_loop()
+        plain = loop.run_in_executor
+
+        def spy(executor, fn, *args):
+            submitted.append((executor, fn, args))
+            return plain(executor, fn, *args)
+
+        loop.run_in_executor = spy
+        fut = obs.run_in_executor(pool, divmod, 17, 5, name="consume/test", nbytes=1)
+        assert isinstance(fut, asyncio.Future)
+        return await fut
+
+    before = len(obs.get_tracer())
+    try:
+        assert _run_coro(main()) == (3, 2)
+    finally:
+        pool.shutdown()
+    assert submitted == [(pool, divmod, (17, 5))]  # exactly today's call
+    assert len(obs.get_tracer()) == before
+
+
+def _jax_state(seed, n=6):
+    import jax
+    import jax.numpy as jnp
+
+    from torchsnapshot_tpu import PyTreeState
+
+    key = jax.random.PRNGKey(seed)
+    return PyTreeState({
+        # past the inline-consume threshold, so every leaf takes the hop
+        f"w{i}": jax.random.normal(jax.random.fold_in(key, i), (512, 256), jnp.float32)
+        for i in range(n)
+    })
+
+
+@pytest.mark.parametrize("op", ["take", "async_take", "restore"])
+def test_every_span_of_a_call_reaches_its_root(tmp_path, op):
+    """Worker threads (the staging and consume pools, the fs plugin's pool)
+    and the loop thread record under the API bracket of the call that made
+    them: the root's span_id names the request."""
+    path = str(tmp_path / "snap")
+    tr = obs.get_tracer()
+    if op == "restore":
+        Snapshot.take(path, {"ts": _jax_state(0), "meta": StateDict(step=3)})
+    with knobs.override_trace(1):
+        tr.reset()
+        if op == "take":
+            Snapshot.take(path, {"ts": _jax_state(0), "meta": StateDict(step=3)})
+        elif op == "async_take":
+            Snapshot.async_take(
+                path, {"ts": _jax_state(0), "meta": StateDict(step=3)}
+            ).wait()
+        else:
+            out = {"ts": _jax_state(1), "meta": StateDict(step=-1)}
+            Snapshot(path).restore(out)
+            assert out["meta"]["step"] == 3
+        spans = tr.spans()
+    tr.reset()
+    by_id = {s.span_id: s for s in spans}
+    roots = [s for s in spans if s.name == op and s.parent_id is None]
+    assert len(roots) == 1
+    off_caller = [s for s in spans if s.thread_id != roots[0].thread_id]
+    pools = {s.thread_name.rsplit("_", 1)[0] for s in off_caller}
+    want = {"tsnp-consume", "tsnp-read-loop"} if op == "restore" else {"tsnp-staging", "tsnp-io-loop"}
+    assert want <= pools and "tsnp-fsio" in pools, pools
+    for s in off_caller:
+        assert _walk_to_root(s, by_id) is roots[0], (s.name, s.thread_name)
+    names = {s.name for s in spans}
+    if op == "restore":
+        assert {"restore/metadata", "restore/plan", "restore/pipeline",
+                "restore/finalize", "consume/materialize", "h2d/put",
+                "storage/attempt"} <= names
+        hops = [s for s in spans if s.name == "consume/materialize"]
+        assert all(s.attrs["queue_ns"] >= 0 and s.attrs["bytes"] > 0 for s in hops)
+        assert all(by_id[s.parent_id].name == "pipeline/consume" for s in hops)
+        puts = [s for s in spans if s.name == "h2d/put"]
+        assert all(by_id[s.parent_id].name == "consume/materialize" for s in puts)
+    else:
+        assert {"take/plan", "stage/materialize", "d2h/copy", "stage/digest"} <= names
+        if op == "take":
+            assert {"take/pipeline", "take/commit"} <= names
+
+
+def _tsnp_events_inside(trace_dir, mark):
+    """``tsnp:`` events of the profile under ``trace_dir`` that lie inside
+    the host annotation ``mark``: [(name, stats)]."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    found = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb"
+    )))
+    assert found, os.listdir(trace_dir)
+    events = [
+        ev for plane in ProfileData.from_file(found[-1]).planes
+        for line in plane.lines for ev in line.events
+    ]
+    marks = [ev for ev in events if ev.name == mark]
+    assert len(marks) == 1
+    lo, hi = marks[0].start_ns, marks[0].start_ns + marks[0].duration_ns
+    return [
+        (ev.name, dict(ev.stats)) for ev in events
+        if ev.name.startswith("tsnp:") and lo <= ev.start_ns <= hi
+    ]
+
+
+@pytest.mark.parametrize("tracing", [1, 0], ids=["tracing_on", "tracing_off"])
+def test_spans_sit_on_the_profilers_clock(tmp_path, tracing):
+    """Inside a ``jax.profiler`` session a traced restore's lexical spans are
+    ``tsnp:`` events of the same xplane, each naming its thread; with
+    tracing off the program adds none."""
+    import jax
+
+    path = str(tmp_path / "snap")
+    Snapshot.take(path, {"ts": _jax_state(0)})
+    out = {"ts": _jax_state(1)}
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    trace_dir = str(tmp_path / "trace")
+    tr = obs.get_tracer()
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    try:
+        with knobs.override_trace(tracing):
+            tr.reset()
+            with jax.profiler.TraceAnnotation("test:restore"):
+                Snapshot(path).restore(out)
+            recorded = {s.name for s in tr.spans()}
+    finally:
+        jax.profiler.stop_trace()
+        tr.reset()
+    events = _tsnp_events_inside(trace_dir, "test:restore")
+    if not tracing:
+        assert events == [] and recorded == set()
+        return
+    names = {name for name, _ in events}
+    assert {"tsnp:restore", "tsnp:restore/pipeline", "tsnp:h2d/put",
+            "tsnp:consume/materialize"} <= names
+    # begin/end spans cross threads and stay on the monotonic clock alone
+    assert "pipeline/budget_admission" in recorded
+    assert "tsnp:pipeline/budget_admission" not in names
+    for name, stats in events:
+        if name == "tsnp:h2d/put":
+            assert stats["thread"].startswith("tsnp-consume") and stats["bytes"] > 0
+        if name == "tsnp:consume/materialize":
+            assert stats["queue_ns"] >= 0
+        if name == "tsnp:restore/pipeline":
+            assert stats["thread"] == "MainThread" and stats["workers"] >= 1

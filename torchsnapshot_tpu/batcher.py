@@ -21,7 +21,6 @@ packs on the host.
 
 from __future__ import annotations
 
-import asyncio
 import logging
 from concurrent.futures import Executor
 from typing import Any, Dict, List, Optional, Tuple
@@ -133,7 +132,6 @@ class BatchedBufferStager(BufferStager):
         _INLINE_PY_MAX = 4096
         _EXEC_OFFLOAD_MIN = 256 * 1024
 
-        loop = asyncio.get_running_loop()
         slab = bytearray(self.total)
         slab_view = memoryview(slab)
         piece_digests: dict = {}
@@ -156,8 +154,9 @@ class BatchedBufferStager(BufferStager):
                     else None
                 )
             elif executor is not None and cost >= _EXEC_OFFLOAD_MIN:
-                digest = await loop.run_in_executor(
-                    executor, _pack_one, dst, view
+                digest = await obs.run_in_executor(
+                    executor, _pack_one, dst, view,
+                    name="stage/copy", nbytes=cost,
                 )
             else:
                 digest = _pack_one(dst, view)
@@ -192,10 +191,10 @@ class BatchedBufferStager(BufferStager):
         arrays = [
             s.arr if s.index is None else s.arr[s.index] for s, _ in self.stagers
         ]
-        loop = asyncio.get_running_loop()
         if executor is not None:
-            slab = await loop.run_in_executor(
-                executor, pack_arrays_to_host, arrays
+            slab = await obs.run_in_executor(
+                executor, pack_arrays_to_host, arrays,
+                name="stage/materialize", nbytes=self.total,
             )
         else:
             slab = pack_arrays_to_host(arrays)
@@ -357,8 +356,6 @@ class _MergedRangeConsumer(BufferConsumer):
     async def consume_buffer(
         self, buf: Any, executor: Optional[Executor] = None
     ) -> None:
-        import asyncio
-
         from .io_types import check_read_crc
 
         view = memoryview(buf).cast("B")
@@ -373,8 +370,9 @@ class _MergedRangeConsumer(BufferConsumer):
                 # own slice (off-loop: tens of MB per member would
                 # stall every concurrent read pipeline)
                 if executor is not None:
-                    await asyncio.get_running_loop().run_in_executor(
-                        executor, check_read_crc, req, piece
+                    await obs.run_in_executor(
+                        executor, check_read_crc, req, piece,
+                        name="consume/crc", nbytes=end - start,
                     )
                 else:
                     check_read_crc(req, piece)
@@ -384,8 +382,9 @@ class _MergedRangeConsumer(BufferConsumer):
         # every concurrent read pipeline if it ran on the loop thread
         if self._device_unpack_eligible() and knobs.device_unpack_enabled():
             if executor is not None:
-                done = await asyncio.get_running_loop().run_in_executor(
-                    executor, self._try_device_unpack, view
+                done = await obs.run_in_executor(
+                    executor, self._try_device_unpack, view,
+                    name="consume/unpack", nbytes=view.nbytes,
                 )
             else:
                 done = self._try_device_unpack(view)

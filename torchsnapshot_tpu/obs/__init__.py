@@ -172,6 +172,7 @@ from .tracer import (  # noqa: F401
     get_tracer,
     next_flow_id,
     refresh_enabled,
+    run_in_executor,
     set_tracing,
     span,
     tracing_enabled,
@@ -181,6 +182,7 @@ __all__ = [
     "Span",
     "Tracer",
     "span",
+    "run_in_executor",
     "get_tracer",
     "current_span",
     "tracing_enabled",

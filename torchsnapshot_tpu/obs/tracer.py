@@ -4,11 +4,28 @@ Spans record where a ``take()``/``restore()`` spent its time: each span
 carries monotonic start/end (ns), free-form attributes, its recording
 thread and (when applicable) asyncio task identity, and a parent link so
 exports can reconstruct the tree.  Parenthood propagates through a
-``contextvars.ContextVar``, which is the one mechanism that is correct
-across BOTH threads (each thread has its own context) and asyncio tasks
-(each task snapshots the context at creation) — exactly the two
-execution domains the scheduler pipeline spans (caller thread, staging
-executor threads, loop-thread tasks).
+``contextvars.ContextVar``: an asyncio task snapshots the context at
+creation (``run_coroutine_threadsafe`` snapshots the SUBMITTING thread's),
+so loop-thread spans nest under the caller's.  A pool thread does NOT
+inherit it — ``loop.run_in_executor`` starts the worker in an empty
+context — so every executor hop of the take and restore pipelines goes
+through ``run_in_executor`` below, which carries the submitting context
+across, opens the worker's span under the loop-thread span that submitted
+it, and splits the wait for a worker (``queue_ns``) from the work (the
+span's own duration).  Every span of one ``take``/``restore`` then reaches
+the API bracket's span by parent links: the root's ``span_id`` is the
+request's identifier.
+
+Two clocks.  ``start_ns``/``end_ns`` are ``time.monotonic_ns``.  A lexical
+span (``span()``, the executor hop) ALSO enters
+``jax.profiler.TraceAnnotation("tsnp:" + name)`` when ``jax`` is already
+imported (this module never imports it), so inside a ``jax.profiler``
+session the same interval sits on its host-thread line of the
+``.xplane.pb`` that holds the TPU plane — worker and loop threads give
+their OS thread the Python thread's name for that (``tsnp-consume_N``).
+``begin``/``end`` spans (``pipeline/budget_admission``) open and close on
+different turns of the loop or different threads, which an annotation
+cannot, and stay monotonic-only.
 
 Cost discipline: tracing is OFF by default and the disabled path is
 allocation-free — ``span()`` checks the module-level ``ENABLED`` flag
@@ -27,12 +44,14 @@ excluded — the original event already fired for those.)
 
 from __future__ import annotations
 
+import asyncio
 import contextlib
 import itertools
+import sys
 import threading
 import time
-from contextvars import ContextVar
-from typing import Any, Dict, Iterator, List, Optional
+from contextvars import ContextVar, copy_context
+from typing import Any, Callable, Dict, Iterator, List, Optional
 
 from .. import knobs
 
@@ -116,8 +135,6 @@ class Span:
 
 def _current_task_name() -> Optional[str]:
     try:
-        import asyncio
-
         task = asyncio.current_task()
     except RuntimeError:  # no running event loop on this thread
         return None
@@ -230,6 +247,26 @@ def span(name: str, fire_event: bool = True, **attrs: Any):
     return _span_cm(name, fire_event, attrs)
 
 
+# The profiler's clock: ``jax.profiler.TraceAnnotation`` once ``jax`` has
+# been imported by the program (looked up in ``sys.modules``, never
+# imported here).  Outside a profiler session entering one is a flag check.
+XPLANE_PREFIX = "tsnp:"
+_SCALARS = (bool, int, float, str)
+
+
+def _annotation(s: "Span") -> Any:
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    cls = getattr(profiler, "TraceAnnotation", None)
+    if cls is None:
+        return None
+    # ``thread``: the profiler keys a line by pthread id, which a later
+    # thread reuses (each call makes new pools), so one line can hold several
+    # threads' events under the name of one; the event says whose it is
+    stats = {k: v for k, v in s.attrs.items() if isinstance(v, _SCALARS)}
+    stats["thread"] = s.thread_name
+    return cls(XPLANE_PREFIX + s.name, **stats)
+
+
 @contextlib.contextmanager
 def _span_cm(
     name: str, fire_event: bool, attrs: Dict[str, Any]
@@ -237,6 +274,9 @@ def _span_cm(
     parent = _current.get()
     s = Span(name, parent.span_id if parent else None, attrs)
     token = _current.set(s)
+    annotation = _annotation(s)
+    if annotation is not None:
+        annotation.__enter__()
     s.start_ns = time.monotonic_ns()
     try:
         yield s
@@ -245,10 +285,68 @@ def _span_cm(
         raise
     finally:
         s.end_ns = time.monotonic_ns()
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
         _current.reset(token)
         _TRACER._record(s)
         if fire_event:
             _fire_span_event(s)
+
+
+# ------------------------------------------------------- executor hop
+
+
+def name_os_thread() -> None:
+    """Give the calling OS thread its Python thread's name (Linux; the
+    kernel keeps 15 bytes).  The profiler names a host-thread line after
+    the OS thread, and before Python 3.14 ``threading`` does not set it,
+    so every pool and loop thread would read ``python``.  Called by traced
+    workers and loop threads only: once per thread, never with tracing off."""
+    t = threading.current_thread()
+    if getattr(t, "_tsnp_os_named", False):
+        return
+    t._tsnp_os_named = True  # type: ignore[attr-defined]
+    try:
+        with open("/proc/thread-self/comm", "w") as f:
+            f.write(t.name[:15])
+    except OSError:
+        pass  # not Linux, or /proc not mounted: the line keeps its name
+
+
+def _hop(
+    name: str, submit_ns: int, nbytes: Optional[int], fn: Callable, args: tuple
+) -> Any:
+    # on the worker, inside a copy of the submitting task's context
+    queue_ns = time.monotonic_ns() - submit_ns
+    name_os_thread()
+    attrs: Dict[str, Any] = {"queue_ns": queue_ns}
+    if nbytes is not None:
+        attrs["bytes"] = nbytes
+    with _span_cm(name, True, attrs):
+        return fn(*args)
+
+
+def run_in_executor(
+    executor: Any,
+    fn: Callable,
+    *args: Any,
+    name: str,
+    nbytes: Optional[int] = None,
+) -> "asyncio.Future":
+    """``loop.run_in_executor(executor, fn, *args)`` from a coroutine, traced.
+
+    With tracing off this is one flag read and then exactly that call.
+    With tracing on the worker runs ``fn`` inside a copy of the calling
+    task's context, under a span ``name`` whose parent is the span that
+    submitted it and whose attrs hold ``queue_ns`` (submit → a worker
+    picked it up) and ``bytes``; the span's duration is the work alone."""
+    loop = asyncio.get_running_loop()
+    if not ENABLED:
+        return loop.run_in_executor(executor, fn, *args)
+    return loop.run_in_executor(
+        executor, copy_context().run, _hop,
+        name, time.monotonic_ns(), nbytes, fn, args,
+    )
 
 
 def _fire_span_event(s: Span) -> None:

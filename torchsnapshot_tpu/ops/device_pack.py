@@ -133,7 +133,8 @@ def pack_arrays_to_host(arrays: List[Any]) -> np.ndarray:
         obs.swallowed_exception("device_pack.copy_to_host_async", e)
     # materializes (async failures surface here); the host reads the
     # words as the bytes they are
-    out = np.asarray(packed).view(np.uint8)
+    with obs.span("d2h/copy", bytes=packed.nbytes):
+        out = np.asarray(packed).view(np.uint8)
     _count("pack")
     return out
 
@@ -259,7 +260,8 @@ def tile_update_device(acc, tile_np: np.ndarray, off: int):
         device,
     )
     with transfer_gate() as pending:
-        tile = jax.device_put(tile_np, device)
+        with obs.span("h2d/put", bytes=tile_np.nbytes):
+            tile = jax.device_put(tile_np, device)
         pending.append(tile)
         out = fn(acc, tile, np.int32(off))
     _count("tile_update")
@@ -325,13 +327,15 @@ def unpack_slab_to_device(buf, members, out_dtypes, device) -> List[Any]:
     gated = knobs.serialize_transfers()
 
     def dispatch(slab):
-        return [
-            fn(slab, np.int32(off // word_bytes))
-            for fn, (off, _, _) in zip(fns, members)
-        ]
+        with obs.span("unpack/dispatch", members=len(members)):
+            return [
+                fn(slab, np.int32(off // word_bytes))
+                for fn, (off, _, _) in zip(fns, members)
+            ]
 
     with transfer_gate(gated) as pending:
-        slab = jax.device_put(u8.view(_word(word_bytes)), device)
+        with obs.span("h2d/put", bytes=u8.nbytes):
+            slab = jax.device_put(u8.view(_word(word_bytes)), device)
         if gated:
             jax.block_until_ready([slab])
             out = dispatch(slab)

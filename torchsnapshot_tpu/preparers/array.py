@@ -19,7 +19,6 @@ io_preparers/chunked_tensor.py:36-128.  TPU-native differences:
 
 from __future__ import annotations
 
-import asyncio
 import contextlib
 import functools
 import threading
@@ -193,8 +192,6 @@ class JaxArrayBufferStager(BufferStager):
         self.fallback_arr: Any = None
 
     async def stage_buffer(self, executor: Optional[Executor] = None) -> memoryview:
-        loop = asyncio.get_running_loop()
-
         def _materialize(src: Any) -> np.ndarray:
             is_deleted = getattr(src, "is_deleted", None)
             if callable(is_deleted) and is_deleted():
@@ -232,11 +229,15 @@ class JaxArrayBufferStager(BufferStager):
                 # the async prefetch; np.asarray below does the copy
                 # synchronously either way
                 obs.swallowed_exception("array_stager.copy_to_host_async", e)
-            return np.asarray(a)
+            with obs.span("d2h/copy", bytes=self.nbytes):
+                return np.asarray(a)
 
         async def _run(src: Any) -> np.ndarray:
             if executor is not None:
-                return await loop.run_in_executor(executor, _materialize, src)
+                return await obs.run_in_executor(
+                    executor, _materialize, src,
+                    name="stage/materialize", nbytes=self.nbytes,
+                )
             return _materialize(src)
 
         try:
@@ -277,9 +278,11 @@ class HostArrayBufferStager(BufferStager):
     async def stage_buffer(self, executor: Optional[Executor] = None) -> memoryview:
         arr = self.arr
         if self.defensive_copy:
-            loop = asyncio.get_running_loop()
             if executor is not None:
-                arr = await loop.run_in_executor(executor, fast_copy, arr)
+                arr = await obs.run_in_executor(
+                    executor, fast_copy, arr,
+                    name="stage/copy", nbytes=arr.nbytes,
+                )
             else:
                 arr = fast_copy(arr)
             self.arr = None
@@ -331,8 +334,8 @@ class HostArrayBufferStager(BufferStager):
             return dst
 
         if executor is not None:
-            return await asyncio.get_running_loop().run_in_executor(
-                executor, copy
+            return await obs.run_in_executor(
+                executor, copy, name="stage/copy", nbytes=hi - lo
             )
         return copy()
 
@@ -376,7 +379,8 @@ def materialize_into_template(np_arr: np.ndarray, obj_out: Any) -> Any:
         # consumers run on an executor, so H2D puts from several
         # threads overlap unless knobs.serialize_transfers() gates them
         with transfer_gate() as pending:
-            out = jax.device_put(shaped, sharding)
+            with obs.span("h2d/put", bytes=shaped.nbytes):
+                out = jax.device_put(shaped, sharding)
             pending.append(out)
         # NOTE: the template is NOT donated here.  Callers donate only
         # after the replacement is visible through the leaf's Future
@@ -434,9 +438,9 @@ class ArrayBufferConsumer(BufferConsumer):
             and not _is_jax_array(self.obj_out)
         )
         if executor is not None and not inline:
-            loop = asyncio.get_running_loop()
-            result = await loop.run_in_executor(
-                executor, materialize_into_template, np_arr, self.obj_out
+            result = await obs.run_in_executor(
+                executor, materialize_into_template, np_arr, self.obj_out,
+                name="consume/materialize", nbytes=np_arr.nbytes,
             )
         else:
             result = materialize_into_template(np_arr, self.obj_out)
@@ -591,8 +595,9 @@ class _DeviceTiledConsumer(BufferConsumer):
             # the update runs transfer_gate (lock + block on the DMA),
             # which must never block the scheduler loop thread — same
             # rule as ArrayBufferConsumer's materialize dispatch
-            await asyncio.get_running_loop().run_in_executor(
-                executor, self.acc.update, np_arr, start
+            await obs.run_in_executor(
+                executor, self.acc.update, np_arr, start,
+                name="consume/materialize", nbytes=np_arr.nbytes,
             )
         else:
             self.acc.update(np_arr, start)
@@ -1051,9 +1056,11 @@ class _ChunkConsumer(BufferConsumer):
         def copy() -> None:
             fast_copyto(self.host_buf[r0:r1], np_arr)
 
-        loop = asyncio.get_running_loop()
         if executor is not None:
-            await loop.run_in_executor(executor, copy)
+            await obs.run_in_executor(
+                executor, copy,
+                name="consume/materialize", nbytes=np_arr.nbytes,
+            )
         else:
             copy()
         self.countdown.step()

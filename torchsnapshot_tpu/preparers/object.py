@@ -7,10 +7,10 @@ behind the ALLOW_PICKLE_OBJECTS knob (see serialization.py).
 
 from __future__ import annotations
 
-import asyncio
 from concurrent.futures import Executor
 from typing import Any, List, Optional, Tuple
 
+from .. import obs
 from ..io_types import BufferConsumer, BufferStager, Future, ReadReq, WriteReq
 from ..manifest import ObjectEntry
 from ..serialization import deserialize_object, serialize_object
@@ -39,10 +39,10 @@ class ObjectBufferConsumer(BufferConsumer):
     async def consume_buffer(
         self, buf: Any, executor: Optional[Executor] = None
     ) -> None:
-        loop = asyncio.get_running_loop()
         if executor is not None:
-            obj = await loop.run_in_executor(
-                executor, deserialize_object, buf, self.entry.serializer
+            obj = await obs.run_in_executor(
+                executor, deserialize_object, buf, self.entry.serializer,
+                name="consume/materialize",
             )
         else:
             obj = deserialize_object(buf, self.entry.serializer)

@@ -27,13 +27,12 @@ with a different template sharding.
 
 from __future__ import annotations
 
-import asyncio
 from concurrent.futures import Executor
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from .. import knobs
+from .. import knobs, obs
 from ..io_types import BufferConsumer, BufferStager, Future, ReadReq, WriteReq
 from ..manifest import Shard, ShardedArrayEntry
 from ..serialization import (
@@ -527,9 +526,11 @@ class _ShardConsumer(BufferConsumer):
                 d = self.buffers[lbox][d_sl] if d_sl else self.buffers[lbox][...]
                 fast_copyto(d, s)
 
-        loop = asyncio.get_running_loop()
         if executor is not None:
-            await loop.run_in_executor(executor, scatter)
+            await obs.run_in_executor(
+                executor, scatter,
+                name="consume/materialize", nbytes=src.nbytes,
+            )
         else:
             scatter()
         self.countdown.step()

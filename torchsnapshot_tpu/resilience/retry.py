@@ -159,7 +159,6 @@ async def retry_call(
 async def _retry_loop(
     fn, op_name, backend, classify, progress, executor, breaker
 ) -> Any:
-    loop = asyncio.get_running_loop() if executor is not None else None
     attempt = 0
     # floor for the progress window: idle time BEFORE this op began is
     # not this op's stall (see SharedProgress.should_retry)
@@ -178,7 +177,11 @@ async def _retry_loop(
     while True:
         try:
             if executor is not None:
-                result = await loop.run_in_executor(executor, fn)
+                # traced, the attempt's spans (fastio/*, storage/mmap_read)
+                # nest under the storage span that asked for it
+                result = await obs.run_in_executor(
+                    executor, fn, name="storage/attempt"
+                )
             else:
                 result = fn()
                 if asyncio.iscoroutine(result):

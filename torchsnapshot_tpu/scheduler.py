@@ -191,8 +191,9 @@ async def _encode_staged_buffer(
             return [d[0], d[1], stored_size]
 
         if executor is not None:
-            stored_digest = await asyncio.get_running_loop().run_in_executor(
-                executor, _digest_stored
+            stored_digest = await obs_tracer.run_in_executor(
+                executor, _digest_stored,
+                name="stage/digest", nbytes=stored_size,
             )
         else:
             stored_digest = _digest_stored()
@@ -226,6 +227,8 @@ class _LoopThread:
 
     def _run(self) -> None:
         asyncio.set_event_loop(self.loop)
+        if obs_tracer.ENABLED:
+            obs_tracer.name_os_thread()  # the line's name in an xplane
         # Warm the lazy native-library loader BEFORE the loop runs:
         # load() may open /proc/cpuinfo and even compile the .so on
         # its first call in a process, and the first digest/codec user
@@ -571,13 +574,15 @@ async def _execute_write_pipelines(
             # content checksums into the manifest (entries are serialized
             # at commit, strictly after staging completes) — off-loop,
             # the staged buffer is immutable from here on
-            await asyncio.get_running_loop().run_in_executor(
+            await obs_tracer.run_in_executor(
                 executor,
                 _apply_checksum_sinks,
                 p.buf,
                 wr.checksum_sinks,
                 wr.digest_sink,
                 precomputed,
+                name="stage/digest",
+                nbytes=p.buf_size,
             )
         m_phase_stage.observe(time.perf_counter() - t_stage)
         if will_encode and not (
@@ -659,13 +664,15 @@ async def _execute_write_pipelines(
             )
             if p.defer_digest:
                 if d is None:
-                    await asyncio.get_running_loop().run_in_executor(
+                    await obs_tracer.run_in_executor(
                         executor,
                         _apply_checksum_sinks,
                         p.buf,
                         wr.checksum_sinks,
                         wr.digest_sink,
                         None,
+                        name="stage/digest",
+                        nbytes=p.buf_size,
                     )
                 else:
                     for sink, _rng in wr.checksum_sinks or ():
@@ -680,13 +687,15 @@ async def _execute_write_pipelines(
             if d is None:
                 # plugin didn't fuse: compute now (same values, one
                 # extra pass — exactly what the old order always paid)
-                await asyncio.get_running_loop().run_in_executor(
+                await obs_tracer.run_in_executor(
                     executor,
                     _apply_checksum_sinks,
                     p.buf,
                     wr.checksum_sinks,
                     wr.digest_sink,
                     None,
+                    name="stage/digest",
+                    nbytes=p.buf_size,
                 )
             else:
                 for sink, _rng in wr.checksum_sinks or ():
@@ -926,39 +935,54 @@ def sync_execute_write_reqs(
     point from staged-in-client-RAM to DMA-dispatched (the pipeline
     itself kicks off lazily from the commit thread's sync_complete so the
     caller's blocked window pays for nothing but planning + dispatch)."""
-    executor = ThreadPoolExecutor(
-        max_workers=knobs.get_staging_threads(), thread_name_prefix="tsnp-staging"
-    )
-    # Largest-first staging keeps the budget well-packed and starts the
-    # biggest D2H transfers earliest.
-    pipelines = sorted(
-        (_WritePipeline(wr) for wr in write_reqs),
-        key=lambda p: p.staging_cost,
-        reverse=True,
-    )
-    budget = _Budget(memory_budget_bytes)
-    staging_done = threading.Event()
-    stats = {"bytes_written": 0, "begin_ts": time.monotonic()}
-    loop_thread = _LoopThread()
-
-    def _start() -> concurrent.futures.Future:
-        return loop_thread.submit(
-            _execute_write_pipelines(
-                pipelines, storage, budget, executor, staging_done, stats
-            )
+    workers = knobs.get_staging_threads()
+    # take/pipeline: pool and loop creation and the pipelines up to
+    # staging-done, on the caller's thread; the parent of every loop-thread
+    # and worker span of a blocking take (storage I/O still draining is
+    # waited for under take/commit).  The deferred path opens none: its
+    # pipelines start later, on the commit thread.
+    with (
+        obs_tracer.span("take/pipeline", workers=workers, writes=len(write_reqs))
+        if wait_for_staging
+        else obs_tracer.NULL_CM
+    ):
+        executor = ThreadPoolExecutor(
+            max_workers=workers, thread_name_prefix="tsnp-staging"
         )
+        # Largest-first staging keeps the budget well-packed and starts the
+        # biggest D2H transfers earliest.
+        pipelines = sorted(
+            (_WritePipeline(wr) for wr in write_reqs),
+            key=lambda p: p.staging_cost,
+            reverse=True,
+        )
+        budget = _Budget(memory_budget_bytes)
+        staging_done = threading.Event()
+        stats = {"bytes_written": 0, "begin_ts": time.monotonic()}
+        loop_thread = _LoopThread()
 
-    if not wait_for_staging:
-        # Unblock-early path: every buffer is already independent of
-        # training state (eager_offload_write_reqs), so nothing here needs
-        # to run before control returns.  Defer the pipeline kick-off to
-        # the background thread that calls sync_complete().
-        return PendingIOWork(None, loop_thread, executor, stats, starter=_start)
+        def _start() -> concurrent.futures.Future:
+            return loop_thread.submit(
+                _execute_write_pipelines(
+                    pipelines, storage, budget, executor, staging_done, stats
+                )
+            )
 
-    fut = _start()
-    while not staging_done.wait(timeout=0.05):
-        if fut.done():
-            break
+        if not wait_for_staging:
+            # Unblock-early path: every buffer is already independent of
+            # training state (eager_offload_write_reqs), so nothing here
+            # needs to run before control returns.  Defer the pipeline
+            # kick-off to the background thread that calls sync_complete()
+            # (traced, that thread runs in the async_take's context, so
+            # the pipelines' spans still reach the call that planned them).
+            return PendingIOWork(
+                None, loop_thread, executor, stats, starter=_start
+            )
+
+        fut = _start()
+        while not staging_done.wait(timeout=0.05):
+            if fut.done():
+                break
     pending = PendingIOWork(fut, loop_thread, executor, stats)
     if fut.done() and fut.exception() is not None:
         pending.sync_complete()  # raises
@@ -1479,17 +1503,19 @@ async def _execute_read_pipelines(
             path=p.read_req.path,
             cost=p.consuming_cost,
         ) as sp:
+            nbytes = None
             if sp is not None:
                 # actual size, not the pre-read estimate (object entries
                 # declare cost 1) — p.buf is released below, measure now
-                sp.attrs["bytes"] = _buf_nbytes(p.buf)
+                nbytes = sp.attrs["bytes"] = _buf_nbytes(p.buf)
             t_consume = time.perf_counter()
             if (
                 p.read_req.expected_crc32 is not None
                 and knobs.verify_on_restore()
             ):
-                await asyncio.get_running_loop().run_in_executor(
-                    executor, check_read_crc, p.read_req, p.buf
+                await obs_tracer.run_in_executor(
+                    executor, check_read_crc, p.read_req, p.buf,
+                    name="consume/crc", nbytes=nbytes,
                 )
             await p.read_req.buffer_consumer.consume_buffer(p.buf, executor)
             p.buf = None
@@ -1605,42 +1631,49 @@ def sync_execute_read_reqs(
     reads execute FIRST, so every sibling's wait for this rank's
     publications is bounded by the designated reads' latency, not by
     wherever they happened to land in the queue."""
-    executor = ThreadPoolExecutor(
-        max_workers=knobs.get_staging_threads(), thread_name_prefix="tsnp-consume"
-    )
-    # Restore prioritization (ReadReq.priority): stable sort, so a
-    # server's first-requested layers head the admission queue and can
-    # start serving before the full snapshot lands.  The common case
-    # (all priorities 0, no fan-out) keeps its original order untouched.
-    if publish_first:
-        read_reqs = sorted(
-            read_reqs,
-            key=lambda rr: (
-                rr.priority, 0 if rr.path in publish_first else 1
-            ),
+    workers = knobs.get_staging_threads()
+    # restore/pipeline: this call whole on the caller's thread (pool and
+    # loop creation, the pipelines, shutdown); the parent of every
+    # loop-thread and worker span of the read
+    with obs_tracer.span(
+        "restore/pipeline", workers=workers, reads=len(read_reqs)
+    ):
+        executor = ThreadPoolExecutor(
+            max_workers=workers, thread_name_prefix="tsnp-consume"
         )
-    elif any(rr.priority for rr in read_reqs):
-        read_reqs = sorted(read_reqs, key=lambda rr: rr.priority)
-    pipelines = [_ReadPipeline(rr) for rr in read_reqs]
-    budget = _Budget(memory_budget_bytes)
-    loop_thread = _LoopThread(name="tsnp-read-loop")
-    t0 = time.monotonic()
-    fut = loop_thread.submit(
-        _execute_read_pipelines(
-            pipelines, storage, budget, executor, codec_tables, cas_reads
-        )
-    )
-    try:
-        fut.result()
-        # read throughput breadcrumb (reference logs the symmetric
-        # number on its read path, scheduler.py:443-444)
-        total = sum(p.consuming_cost for p in pipelines)
-        dt = max(time.monotonic() - t0, 1e-9)
-        if total:
-            logger.info(
-                "rank %d: read %.2fGB in %.2fs (%.2f GB/s)",
-                rank, total / 1e9, dt, total / 1e9 / dt,
+        # Restore prioritization (ReadReq.priority): stable sort, so a
+        # server's first-requested layers head the admission queue and can
+        # start serving before the full snapshot lands.  The common case
+        # (all priorities 0, no fan-out) keeps its original order untouched.
+        if publish_first:
+            read_reqs = sorted(
+                read_reqs,
+                key=lambda rr: (
+                    rr.priority, 0 if rr.path in publish_first else 1
+                ),
             )
-    finally:
-        executor.shutdown(wait=False)
-        loop_thread.shutdown()
+        elif any(rr.priority for rr in read_reqs):
+            read_reqs = sorted(read_reqs, key=lambda rr: rr.priority)
+        pipelines = [_ReadPipeline(rr) for rr in read_reqs]
+        budget = _Budget(memory_budget_bytes)
+        loop_thread = _LoopThread(name="tsnp-read-loop")
+        t0 = time.monotonic()
+        fut = loop_thread.submit(
+            _execute_read_pipelines(
+                pipelines, storage, budget, executor, codec_tables, cas_reads
+            )
+        )
+        try:
+            fut.result()
+            # read throughput breadcrumb (reference logs the symmetric
+            # number on its read path, scheduler.py:443-444)
+            total = sum(p.consuming_cost for p in pipelines)
+            dt = max(time.monotonic() - t0, 1e-9)
+            if total:
+                logger.info(
+                    "rank %d: read %.2fGB in %.2fs (%.2f GB/s)",
+                    rank, total / 1e9, dt, total / 1e9 / dt,
+                )
+        finally:
+            executor.shutdown(wait=False)
+            loop_thread.shutdown()

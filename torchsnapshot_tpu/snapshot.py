@@ -24,6 +24,7 @@ snapshot.py:112-1072).  The orchestration mirrors the reference call stacks
 from __future__ import annotations
 
 import contextlib
+import contextvars
 import dataclasses
 import json
 import logging
@@ -967,8 +968,12 @@ class Snapshot:
             # complete snapshot).
             committed = {"done": False}
             try:
+                # take/commit: the wait for storage I/O still draining
+                # after take/pipeline, then the checksum gather, the
+                # flight record and the metadata marker
                 with coordinator.abort_scope(commit_uid), \
-                        coordinator.liveness_scope(session.monitor):
+                        coordinator.liveness_scope(session.monitor), \
+                        obs.span("take/commit", rank=coordinator.rank):
                     pending_io.sync_complete()
                     # tiered storage: replicate fast-tier payloads to
                     # peers and enqueue write-back promotion, strictly
@@ -1157,6 +1162,10 @@ class Snapshot:
             except BaseException:
                 session.stop()
                 raise
+            # traced, the commit thread records under this call's span
+            trace_context = (
+                contextvars.copy_context() if obs.tracing_enabled() else None
+            )
         pending = PendingSnapshot(
             path=path,
             metadata=metadata,
@@ -1173,6 +1182,7 @@ class Snapshot:
             cas_store=cas_store,
             takeover_ctx=takeover_ctx,
             liveness_session=session,
+            trace_context=trace_context,
         )
         # goodput: the unblock point IS this return — training state is
         # independent of the snapshot from here; staging/IO/commit (and
@@ -1947,94 +1957,101 @@ class Snapshot:
                 session.start()
                 with coordinator.abort_scope(abort_uid), \
                         coordinator.liveness_scope(session.monitor):
-                    metadata = self.metadata
-                    manifest_for_rank = get_manifest_for_rank(metadata, rank)
-                    storage = _storage_for(self.path, self._storage_options)
-                    self._prime_tier_digests(storage)
-                    cas_reads = self._cas_reads()
-                    # fan-out restore (topology/fanout.py): per-slice
-                    # designated readers pull each replicated object
-                    # from the durable tier exactly once and
-                    # redistribute over the coordination KV — restore
-                    # cost O(objects) per slice, not O(objects × ranks).
-                    # The wrapper goes OUTSIDE any host cache, so the
-                    # one GET per slice is itself host-deduped; all
-                    # ranks must call restore with rank-agreed
-                    # paths/priority arguments (the same SPMD contract
-                    # every other restore collective already assumes).
-                    topo = topology_mod.detect_topology(
-                        coordinator, exchange_prefix=f"{abort_uid}/topo"
-                    )
-                    transport = None
-                    if topology_mod.fanout_enabled(topo):
-                        shared = topology_mod.shared_read_locations(
-                            metadata.manifest
+                    # restore/metadata: what the caller's thread does
+                    # before the first leaf is planned (metadata and this
+                    # rank's manifest, storage open, topology and fan-out
+                    # set-up, the key gather)
+                    with obs.span("restore/metadata", rank=rank):
+                        metadata = self.metadata
+                        manifest_for_rank = get_manifest_for_rank(
+                            metadata, rank
                         )
-                        if shared:
-                            # payload transport (transport/): the
-                            # capability-probed engine the fan-out's
-                            # redistribution bytes ride — collectives
-                            # when the runtime supports them, the KV
-                            # blob path otherwise
-                            transport = transport_mod.resolve_transport(
-                                coordinator, topology=topo
-                            )
-                            storage = topology_mod.FanoutReadPlugin(
-                                storage, coordinator, topo,
-                                f"{abort_uid}/fan", shared,
-                                transport=transport,
-                            )
-                    local_keys = sorted(app_state.keys())
-                    if world > 1:
-                        global_keys = sorted(
-                            set().union(
-                                *coordinator.all_gather_object(local_keys)
-                            )
+                        storage = _storage_for(self.path, self._storage_options)
+                        self._prime_tier_digests(storage)
+                        cas_reads = self._cas_reads()
+                        # fan-out restore (topology/fanout.py): per-slice
+                        # designated readers pull each replicated object
+                        # from the durable tier exactly once and
+                        # redistribute over the coordination KV — restore
+                        # cost O(objects) per slice, not O(objects × ranks).
+                        # The wrapper goes OUTSIDE any host cache, so the
+                        # one GET per slice is itself host-deduped; all
+                        # ranks must call restore with rank-agreed
+                        # paths/priority arguments (the same SPMD contract
+                        # every other restore collective already assumes).
+                        topo = topology_mod.detect_topology(
+                            coordinator, exchange_prefix=f"{abort_uid}/topo"
                         )
-                    else:
-                        global_keys = local_keys
-                    # RNG state is restored last so earlier restores
-                    # cannot perturb it (reference snapshot.py:371-381)
-                    global_keys.sort(
-                        key=lambda k: isinstance(app_state.get(k), RNGState)
-                    )
-                    # collective fan-out session: whole shared objects
-                    # move as ordered broadcasts over the live jax
-                    # runtime instead of KV blobs.  Requires a session-
-                    # capable transport, every slice fanning out
-                    # (fanout_world_uniform — the gate protocol needs
-                    # all world ranks), and a FULL restore (a paths
-                    # filter makes "which shared objects get read" a
-                    # per-rank question the pre-agreed schedule cannot
-                    # answer).  The plan rides the global key order so
-                    # the schedule advances with the per-key barriers.
-                    if (
-                        transport is not None
-                        and getattr(transport, "mode", None) == "session"
-                        and isinstance(
-                            storage, topology_mod.FanoutReadPlugin
-                        )
-                        and paths is None
-                        and topology_mod.fanout_world_uniform(topo)
-                    ):
-                        try:
-                            plan_paths = (
-                                topology_mod.ordered_shared_locations(
-                                    metadata.manifest,
-                                    storage.shared_paths,
-                                    global_keys,
+                        transport = None
+                        if topology_mod.fanout_enabled(topo):
+                            shared = topology_mod.shared_read_locations(
+                                metadata.manifest
+                            )
+                            if shared:
+                                # payload transport (transport/): the
+                                # capability-probed engine the fan-out's
+                                # redistribution bytes ride — collectives
+                                # when the runtime supports them, the KV
+                                # blob path otherwise
+                                transport = transport_mod.resolve_transport(
+                                    coordinator, topology=topo
+                                )
+                                storage = topology_mod.FanoutReadPlugin(
+                                    storage, coordinator, topo,
+                                    f"{abort_uid}/fan", shared,
+                                    transport=transport,
+                                )
+                        local_keys = sorted(app_state.keys())
+                        if world > 1:
+                            global_keys = sorted(
+                                set().union(
+                                    *coordinator.all_gather_object(local_keys)
                                 )
                             )
-                            storage.transport_session = (
-                                transport.open_fanout_session(
-                                    topo, f"{abort_uid}/fan", plan_paths
+                        else:
+                            global_keys = local_keys
+                        # RNG state is restored last so earlier restores
+                        # cannot perturb it (reference snapshot.py:371-381)
+                        global_keys.sort(
+                            key=lambda k: isinstance(app_state.get(k), RNGState)
+                        )
+                        # collective fan-out session: whole shared objects
+                        # move as ordered broadcasts over the live jax
+                        # runtime instead of KV blobs.  Requires a session-
+                        # capable transport, every slice fanning out
+                        # (fanout_world_uniform — the gate protocol needs
+                        # all world ranks), and a FULL restore (a paths
+                        # filter makes "which shared objects get read" a
+                        # per-rank question the pre-agreed schedule cannot
+                        # answer).  The plan rides the global key order so
+                        # the schedule advances with the per-key barriers.
+                        if (
+                            transport is not None
+                            and getattr(transport, "mode", None) == "session"
+                            and isinstance(
+                                storage, topology_mod.FanoutReadPlugin
+                            )
+                            and paths is None
+                            and topology_mod.fanout_world_uniform(topo)
+                        ):
+                            try:
+                                plan_paths = (
+                                    topology_mod.ordered_shared_locations(
+                                        metadata.manifest,
+                                        storage.shared_paths,
+                                        global_keys,
+                                    )
                                 )
-                            )
-                        except Exception as e:  # noqa: BLE001 — the
-                            # restore proceeds on the KV path
-                            transport_mod.count_fallback(
-                                "session-open", e
-                            )
+                                storage.transport_session = (
+                                    transport.open_fanout_session(
+                                        topo, f"{abort_uid}/fan", plan_paths
+                                    )
+                                )
+                            except Exception as e:  # noqa: BLE001 — the
+                                # restore proceeds on the KV path
+                                transport_mod.count_fallback(
+                                    "session-open", e
+                                )
                     for key in global_keys:
                         if key in app_state:
                             self._load_stateful(
@@ -2044,35 +2061,38 @@ class Snapshot:
                             )
                         if world > 1:
                             coordinator.barrier()
-                    # fan-out blob cleanup: the per-key barriers above
-                    # prove every rank is past its reads, so the
-                    # transient publications — KV blobs, collective
-                    # session gate keys, device-registry entries — can
-                    # be reclaimed (a restore must not permanently grow
-                    # the coordination service's store)
-                    tsession = getattr(
-                        storage, "transport_session", None
-                    )
-                    if tsession is not None:
-                        tsession.close()
-                    cleanup = getattr(storage, "cleanup_published", None)
-                    if cleanup is not None:
-                        cleanup()
-                    # restore flight record: cross-rank merge only (no
-                    # persistence — the snapshot may live on read-only
-                    # storage); rank 0 keeps the merged record
-                    # in-process (obs.aggregate.last_record("restore")).
-                    # All ranks just left the final barrier, so the
-                    # single-phase exchange converges in one KV round.
-                    obs.aggregate.exchange_and_merge(
-                        coordinator,
-                        abort_uid,
-                        obs.aggregate.rank_payload(
-                            rank, "restore", obs_before
-                        ),
-                        op="restore",
-                        path=self.path,
-                    )
+                    # restore/finalize, the call's half: what the
+                    # caller's thread does after the last key is loaded
+                    with obs.span("restore/finalize", rank=rank):
+                        # fan-out blob cleanup: the per-key barriers above
+                        # prove every rank is past its reads, so the
+                        # transient publications — KV blobs, collective
+                        # session gate keys, device-registry entries — can
+                        # be reclaimed (a restore must not permanently grow
+                        # the coordination service's store)
+                        tsession = getattr(
+                            storage, "transport_session", None
+                        )
+                        if tsession is not None:
+                            tsession.close()
+                        cleanup = getattr(storage, "cleanup_published", None)
+                        if cleanup is not None:
+                            cleanup()
+                        # restore flight record: cross-rank merge only (no
+                        # persistence — the snapshot may live on read-only
+                        # storage); rank 0 keeps the merged record
+                        # in-process (obs.aggregate.last_record("restore")).
+                        # All ranks just left the final barrier, so the
+                        # single-phase exchange converges in one KV round.
+                        obs.aggregate.exchange_and_merge(
+                            coordinator,
+                            abort_uid,
+                            obs.aggregate.rank_payload(
+                                rank, "restore", obs_before
+                            ),
+                            op="restore",
+                            path=self.path,
+                        )
             except SnapshotAbortedError:
                 raise
             except BaseException as e:
@@ -2081,33 +2101,34 @@ class Snapshot:
                 )
                 raise
             finally:
-                session.stop()
-                stamp_stripe(restore_event)
-                if storage is not None:
-                    # error-path transport teardown (idempotent after
-                    # the happy path's close above): the session thread
-                    # must not outlive the restore, and the device
-                    # registry must not accrete across restores
-                    tsession = getattr(
-                        storage, "transport_session", None
-                    )
-                    if tsession is not None:
-                        try:
-                            tsession.close()
-                        except Exception as e:  # noqa: BLE001
-                            obs.swallowed_exception(
-                                "restore.transport_close", e
-                            )
-                    transport = getattr(storage, "transport", None)
-                    if transport is not None:
-                        try:
-                            transport.close()
-                        except Exception as e:  # noqa: BLE001
-                            obs.swallowed_exception(
-                                "restore.transport_close", e
-                            )
-                    storage.sync_close()
-                self._close_cas_reads(cas_reads)
+                with obs.span("restore/finalize", rank=rank):
+                    session.stop()
+                    stamp_stripe(restore_event)
+                    if storage is not None:
+                        # error-path transport teardown (idempotent after
+                        # the happy path's close above): the session thread
+                        # must not outlive the restore, and the device
+                        # registry must not accrete across restores
+                        tsession = getattr(
+                            storage, "transport_session", None
+                        )
+                        if tsession is not None:
+                            try:
+                                tsession.close()
+                            except Exception as e:  # noqa: BLE001
+                                obs.swallowed_exception(
+                                    "restore.transport_close", e
+                                )
+                        transport = getattr(storage, "transport", None)
+                        if transport is not None:
+                            try:
+                                transport.close()
+                            except Exception as e:  # noqa: BLE001
+                                obs.swallowed_exception(
+                                    "restore.transport_close", e
+                                )
+                        storage.sync_close()
+                    self._close_cas_reads(cas_reads)
             obs.maybe_write_metrics_textfile()
 
     def _load_stateful(
@@ -2122,127 +2143,117 @@ class Snapshot:
         cas_reads: Optional[Tuple[Any, Dict[str, Any]]] = None,
         priority: Optional[Sequence[str]] = None,
     ) -> None:
-        # reference _load_stateful, snapshot.py:727-782
-        with obs.span("restore/load_stateful", key=key, rank=rank):
-            self._load_stateful_impl(
-                key, stateful, manifest_for_rank, storage, strict, rank,
-                paths=paths, cas_reads=cas_reads, priority=priority,
-            )
-
-    def _load_stateful_impl(
-        self,
-        key: str,
-        stateful: Any,
-        manifest_for_rank: Manifest,
-        storage: Any,
-        strict: bool,
-        rank: int,
-        paths: Optional[Sequence[str]] = None,
-        cas_reads: Optional[Tuple[Any, Dict[str, Any]]] = None,
-        priority: Optional[Sequence[str]] = None,
-    ) -> None:
-        key_manifest = {
-            p: e
-            for p, e in manifest_for_rank.items()
-            if p == key or p.startswith(key + "/")
-        }
-        if not key_manifest:
-            if strict:
-                raise KeyError(
-                    f"app_state key {key!r} not found in snapshot manifest"
-                )
-            logger.warning("skipping %r: not in snapshot", key)
-            return
-        if paths is not None and not any(
-            not is_container_entry(e) and path_is_replicated(p, paths)
-            for p, e in key_manifest.items()
-        ):
-            return  # nothing under this key matches the filter
-        # degraded snapshot (takeover, docs/resilience.md): logical
-        # paths only a dead rank held are typed-missing, not silently
-        # zero.  A marker blocks THIS restore only when this rank's view
-        # would actually source the dead rank's bytes: its own rank IS
-        # the origin (per-rank private state), the entry is sharded (the
-        # merged view includes the dead rank's lost boxes), or it is
-        # replicated and was not taken over (every view overlays the
-        # dead writer's copy).  A peer's intact private copy of the same
-        # logical path restores normally.  Steer around the gap with
-        # restore(paths=...), or heal it first (SnapshotManager.repair()
-        # / the next take).
-        degraded = getattr(self.metadata, "degraded", None) or {}
-        if degraded:
-            hits = sorted(
-                p
+        # reference _load_stateful, snapshot.py:727-782.  restore/plan,
+        # restore/pipeline (scheduler.sync_execute_read_reqs) and
+        # restore/finalize partition this call on the caller's thread
+        with obs.span("restore/plan", key=key) as plan_span:
+            key_manifest = {
+                p: e
+                for p, e in manifest_for_rank.items()
+                if p == key or p.startswith(key + "/")
+            }
+            if not key_manifest:
+                if strict:
+                    raise KeyError(
+                        f"app_state key {key!r} not found in snapshot manifest"
+                    )
+                logger.warning("skipping %r: not in snapshot", key)
+                return
+            if paths is not None and not any(
+                not is_container_entry(e) and path_is_replicated(p, paths)
                 for p, e in key_manifest.items()
-                if p in degraded
-                and not is_container_entry(e)
-                and (paths is None or path_is_replicated(p, paths))
-                and (
-                    rank == degraded[p].get("origin_rank")
-                    or isinstance(e, ShardedArrayEntry)
-                    or bool(getattr(e, "replicated", False))
+            ):
+                return  # nothing under this key matches the filter
+            # degraded snapshot (takeover, docs/resilience.md): logical
+            # paths only a dead rank held are typed-missing, not silently
+            # zero.  A marker blocks THIS restore only when this rank's view
+            # would actually source the dead rank's bytes: its own rank IS
+            # the origin (per-rank private state), the entry is sharded (the
+            # merged view includes the dead rank's lost boxes), or it is
+            # replicated and was not taken over (every view overlays the
+            # dead writer's copy).  A peer's intact private copy of the same
+            # logical path restores normally.  Steer around the gap with
+            # restore(paths=...), or heal it first (SnapshotManager.repair()
+            # / the next take).
+            degraded = getattr(self.metadata, "degraded", None) or {}
+            if degraded:
+                hits = sorted(
+                    p
+                    for p, e in key_manifest.items()
+                    if p in degraded
+                    and not is_container_entry(e)
+                    and (paths is None or path_is_replicated(p, paths))
+                    and (
+                        rank == degraded[p].get("origin_rank")
+                        or isinstance(e, ShardedArrayEntry)
+                        or bool(getattr(e, "replicated", False))
+                    )
                 )
-            )
-            if hits:
-                raise DegradedSnapshotError(self.path, hits)
-        # current state provides in-place/sharding templates
-        # (reference snapshot.py:754-762)
-        _, targets = flatten(stateful.state_dict(), prefix=key)
-        self._map_legacy_leaf_targets(key, stateful, key_manifest, targets)
+                if hits:
+                    raise DegradedSnapshotError(self.path, hits)
+            # current state provides in-place/sharding templates
+            # (reference snapshot.py:754-762)
+            _, targets = flatten(stateful.state_dict(), prefix=key)
+            self._map_legacy_leaf_targets(key, stateful, key_manifest, targets)
 
-        container_entries: Manifest = {}
-        read_reqs: List[ReadReq] = []
-        futures: Dict[str, Future] = {}
-        for lpath, entry in key_manifest.items():
-            if is_container_entry(entry):
-                container_entries[lpath] = entry
-                continue
-            if paths is not None and not path_is_replicated(lpath, paths):
-                # partial restore: no read for unmatched leaves — but
-                # list/tuple structure must survive inflation, so seed
-                # the slot with the CURRENT value instead of dropping it
-                # (a dropped ListEntry child would compact the list and
-                # shift later elements onto wrong indices).  Membership,
-                # not is-None: a present-but-None leaf still holds its
-                # list slot.
-                if lpath in targets:
-                    fut: Future = Future(targets[lpath])
-                    fut.set(targets[lpath])
-                    futures[lpath] = fut
-                continue
-            reqs, fut = prepare_read(entry, obj_out=targets.get(lpath))
-            if priority:
-                pri = _read_priority_for(lpath, priority)
-                for r in reqs:
-                    r.priority = pri
-            read_reqs.extend(reqs)
-            futures[lpath] = fut
-        if not knobs.is_batching_disabled():
-            read_reqs = batch_read_requests(read_reqs)
-        budget = get_process_memory_budget_bytes()
+            container_entries: Manifest = {}
+            read_reqs: List[ReadReq] = []
+            futures: Dict[str, Future] = {}
+            for lpath, entry in key_manifest.items():
+                if is_container_entry(entry):
+                    container_entries[lpath] = entry
+                    continue
+                if paths is not None and not path_is_replicated(lpath, paths):
+                    # partial restore: no read for unmatched leaves — but
+                    # list/tuple structure must survive inflation, so seed
+                    # the slot with the CURRENT value instead of dropping it
+                    # (a dropped ListEntry child would compact the list and
+                    # shift later elements onto wrong indices).  Membership,
+                    # not is-None: a present-but-None leaf still holds its
+                    # list slot.
+                    if lpath in targets:
+                        fut: Future = Future(targets[lpath])
+                        fut.set(targets[lpath])
+                        futures[lpath] = fut
+                    continue
+                reqs, fut = prepare_read(entry, obj_out=targets.get(lpath))
+                if priority:
+                    pri = _read_priority_for(lpath, priority)
+                    for r in reqs:
+                        r.priority = pri
+                read_reqs.extend(reqs)
+                futures[lpath] = fut
+            if not knobs.is_batching_disabled():
+                read_reqs = batch_read_requests(read_reqs)
+            budget = get_process_memory_budget_bytes()
+            codec_tables = self._codec_tables()
+            if plan_span is not None:
+                plan_span.attrs["leaves"] = len(futures)
+                plan_span.attrs["reads"] = len(read_reqs)
         try:
             sync_execute_read_reqs(
                 read_reqs, storage, budget, rank,
-                codec_tables=self._codec_tables(),
+                codec_tables=codec_tables,
                 cas_reads=cas_reads,
                 # fan-out: front-load the reads THIS rank must publish
                 # for its slice siblings, so their waits are minimal
                 publish_first=getattr(storage, "local_publish_paths", None),
             )
-            restored = {lpath: fut.obj for lpath, fut in futures.items()}
-            state_dict = inflate(
-                container_entries,
-                restored,
-                prefix=key,
-                allow_missing=(not strict) or paths is not None,
-            )
-            # propagate strict to load_state_dict when the stateful
-            # accepts it (reference snapshot.py:775-778 for nn.Module); a
-            # paths filter implies non-strict (unmatched leaves keep
-            # current values)
-            load_with_strict(
-                stateful, state_dict, strict and paths is None
-            )
+            with obs.span("restore/finalize", key=key):
+                restored = {lpath: fut.obj for lpath, fut in futures.items()}
+                state_dict = inflate(
+                    container_entries,
+                    restored,
+                    prefix=key,
+                    allow_missing=(not strict) or paths is not None,
+                )
+                # propagate strict to load_state_dict when the stateful
+                # accepts it (reference snapshot.py:775-778 for
+                # nn.Module); a paths filter implies non-strict (unmatched
+                # leaves keep current values)
+                load_with_strict(
+                    stateful, state_dict, strict and paths is None
+                )
         except BaseException:
             self._repair_after_failed_restore(
                 key, stateful, container_entries, futures, targets
@@ -2693,6 +2704,7 @@ class PendingSnapshot:
         cas_store: Optional[Any] = None,
         takeover_ctx: Optional[_TakeoverContext] = None,
         liveness_session: Optional[LivenessSession] = None,
+        trace_context: Optional[contextvars.Context] = None,
     ) -> None:
         self.path = path
         self._storage_options = storage_options
@@ -2732,12 +2744,18 @@ class PendingSnapshot:
         self._committed = False
         self._exc: Optional[BaseException] = None
         self._snapshot: Optional[Snapshot] = None
+        # traced: a copy of the async_take's context, so the commit
+        # thread's spans (and the pipelines it starts) reach that call
+        self._trace_context = trace_context
         self._thread = threading.Thread(
             target=self._complete_snapshot, name="tsnp-commit", daemon=True
         )
         self._thread.start()
 
-    def _complete_snapshot(self) -> None:
+    def _complete_snapshot(self, in_trace_context: bool = False) -> None:
+        if self._trace_context is not None and not in_trace_context:
+            self._trace_context.run(self._complete_snapshot, True)
+            return
         # KV ops only — never collectives, never uid-counter-based gathers
         # (those belong to the foreground thread's program order)
         coord = self._coordinator

@@ -16,11 +16,17 @@ file is an arrangement of them with its parameters:
     drop     let go of the live state
     restore  fresh template, ``Snapshot(newest).restore``, block on every leaf,
              let go of it
+
+A traffic file may also say where its snapshots go: ``"sink": "ram"`` is a
+tmpfs of the run's own under ``TMPDIR`` (the local RAM-disk tier of a
+multi-tier checkpointer), and without the key they go under ``TMPDIR`` as
+they are (``make_sink``).
 """
 
 from __future__ import annotations
 
 import contextlib
+import ctypes
 import importlib.util
 import json
 import os
@@ -32,13 +38,20 @@ import time
 from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
+import psutil
 
 from . import arith, state, trace_reduce
 
 NO_CHIP = 3  # exit code: no accelerator, or fewer chips than the cell asks
+NO_SINK = 4  # exit code: the mix asks for a RAM-backed sink and the run can have none
+SINK_STATES = 4  # room a RAM sink must have, in states: kept, in flight, staging, slack
 
 
 class NoChip(RuntimeError):
+    pass
+
+
+class NoSink(RuntimeError):
     pass
 
 
@@ -151,6 +164,85 @@ def _dir_bytes(path: str) -> int:
     return total
 
 
+# ---------------------------------------------------------------------- sink
+
+
+def _fs_type(path: str) -> str:
+    """The type of the file system ``path`` sits on, by ``/proc/mounts``
+    (``statvfs`` gives no type in Python)."""
+    path, best, kind = os.path.realpath(path), "", "unknown"
+    try:
+        with open("/proc/mounts") as f:
+            mounts = [line.split() for line in f]
+    except OSError:
+        return kind
+    for _device, mount, fstype, *_ in mounts:
+        under = path == mount or path.startswith(mount.rstrip("/") + "/")
+        if under and len(mount) >= len(best):
+            best, kind = mount, fstype
+    return kind
+
+
+def _mount_own_tmpfs(path: str) -> None:
+    """A tmpfs on ``path`` in a mount namespace of the process's own: no
+    other process sees it, and it goes, pages and all, with the process,
+    however that ends.  It is there for the calling thread and the threads
+    started after the call, so a run calls this before JAX starts any."""
+    libc = ctypes.CDLL(None, use_errno=True)
+    ms_rec, ms_private = 1 << 14, 1 << 18
+    os.unshare(os.CLONE_NEWNS)
+    options = f"size={psutil.virtual_memory().total},mode=0700".encode()
+    for args in (
+        (None, b"/", None, ms_rec | ms_private, None),  # nothing reaches the parent's
+        (b"tmpfs", path.encode(), b"tmpfs", 0, options),
+    ):
+        if libc.mount(*args):
+            raise OSError(ctypes.get_errno(), os.strerror(ctypes.get_errno()))
+
+
+def make_sink(kind: str):
+    """The run's ``chipbench_*`` directory, always a new one under
+    ``TMPDIR``, and the file system it sits on.  ``tmp``: as it is.  ``ram``:
+    as it is where ``TMPDIR`` is a tmpfs, and otherwise a tmpfs of the run's
+    own mounted on it; where that cannot be had the run ends (``NoSink``)
+    and never falls back to a disk."""
+    if kind not in ("tmp", "ram"):
+        raise ValueError(f"unknown sink {kind!r}: a mix's sink is 'ram' or absent")
+    path = tempfile.mkdtemp(prefix="chipbench_")
+    base, fstype = os.path.dirname(path), _fs_type(path)
+    if kind == "tmp" or fstype == "tmpfs":
+        return path, f"{fstype} {base}"
+    try:
+        _mount_own_tmpfs(path)
+    except OSError as e:
+        os.rmdir(path)
+        raise NoSink(
+            f"the mix asks for a RAM-backed sink: {base} is {fstype}, and the "
+            f"run may not mount a tmpfs of its own there ({e})"
+        ) from e
+    return path, f"tmpfs own mount under {base}"
+
+
+def need_room(path: str, need: int) -> None:
+    """A RAM sink holds ``need`` bytes, by ``statvfs`` and by the host's
+    available memory (a tmpfs may be sized past it), or the run ends."""
+    fs = os.statvfs(path)
+    room = min(fs.f_bavail * fs.f_frsize, psutil.virtual_memory().available)
+    if room < need:
+        raise NoSink(
+            f"the mix asks for a RAM-backed sink and {os.path.dirname(path)} "
+            f"has room for {room} B of {need}"
+        )
+
+
+def remove_sink(path: str) -> None:
+    shutil.rmtree(path, ignore_errors=True)
+    if os.path.isdir(path):  # emptied, and busy: the run's own mount
+        ctypes.CDLL(None).umount2(path.encode(), 2)  # MNT_DETACH
+        with contextlib.suppress(OSError):
+            os.rmdir(path)
+
+
 # -------------------------------------------------------------------- driver
 
 
@@ -189,11 +281,14 @@ class Driver:
                 picks["below"], size=min(picks["loops"], picks["below"]), replace=False
             )).tolist()
         )
-        # which of the window's snapshots is read back after it, drawn from
-        # the seed; the others are deleted as soon as they are committed, so
-        # that a run keeps little on disk (``keep_one_of``: how many it makes)
-        made = mix.get("keep_one_of")
-        self.keep_at = int(np.random.default_rng(seed).integers(made)) if made else None
+        # ``keep_one``: of the window's snapshots one is read back after it,
+        # each as likely as any other, by draws from the seed (a reservoir of
+        # one); the others go once they are committed, so that a run holds
+        # two at the most
+        self.keep_draws = (
+            np.random.default_rng([seed, 1]) if mix.get("keep_one") else None
+        )
+        self.kept: Optional[Dict[str, Any]] = None
         self.commits = self.window_commits = 0
         self.ts = None
         self.steps_done = 0
@@ -240,7 +335,10 @@ class Driver:
         state on the device at the call, and how a resumed state must sit."""
         with self._op("check"):
             ref = self.digest(self.ts)
-        return {"ref": ref, "step": self.steps_done}
+        found = {"ref": ref, "step": self.steps_done}
+        if self.fault == "late_snapshot":
+            self.step()  # the state moves on between the call and the copy
+        return found
 
     def _take_kwargs(self) -> Dict[str, Any]:
         if self.fault != "control_bf16":
@@ -282,8 +380,6 @@ class Driver:
         from torchsnapshot_tpu import Snapshot
 
         snap = {"path": self._new_dir(), **self._reference()}
-        if self.fault == "late_snapshot":
-            self.step()  # the state moves on between the call and the copy
         with self._op("take"):
             Snapshot.take(snap["path"], self._app(self.ts, snap["step"]),
                           **self._take_kwargs())
@@ -316,17 +412,19 @@ class Driver:
     def _committed(self, snap: Dict[str, Any]) -> None:
         self.bytes_written += _dir_bytes(snap["path"])
         self.commits += 1
-        if not self.in_window or self.keep_at is None:
+        if not self.in_window or self.keep_draws is None:
             self.snapshots.append(snap)
             return
         marker = os.path.isfile(os.path.join(snap["path"], ".snapshot_metadata"))
         self.wrong["answers_missing"] += int(not marker)
-        if self.window_commits == self.keep_at:
-            self.snapshots.append(snap)
-        else:
-            with self._op("check"):
-                shutil.rmtree(snap["path"])
         self.window_commits += 1
+        # commit k takes the kept one's place with the chance 1/k
+        goes = snap
+        if self.keep_draws.random() < 1 / self.window_commits:
+            goes, self.kept = self.kept, snap
+        if goes is not None:
+            with self._op("check"):
+                shutil.rmtree(goes["path"])
 
     def drop(self) -> None:
         self.ts = None
@@ -416,6 +514,8 @@ class Driver:
         while plan.get("fill") and (time_left() or filled < plan.get("fill_least", 0)):
             self.run_ops(plan["fill"])
             filled += 1
+        if self.kept is not None:
+            self.snapshots.append(self.kept)
         self.in_window = False
 
 
@@ -482,30 +582,34 @@ def run_cell(
     started_at = started_at if started_at is not None else time.time()
     cell = Cell(root, workload)
     mix = cell.traffic
-
-    import jax
-
-    if not allow_cpu:
-        enable_compile_cache(root)
-    devices = pick_devices(cell.chips, allow_cpu)
-    kind = devices[0].device_kind
-    if devices[0].platform == "tpu":
-        cell.peak_of(kind)
-
-    from torchsnapshot_tpu import obs
-    from torchsnapshot_tpu.obs import tracer as program_tracer
-    from torchsnapshot_tpu.ops import device_pack
-    from torchsnapshot_tpu.preparers.array import DONATION_STATS
-
-    def swallowed() -> int:
-        return obs.metrics_snapshot()["counters"].get("exceptions.swallowed", 0)
-
-    swallowed0 = swallowed()
-    snap_root = tempfile.mkdtemp(prefix="chipbench_")
+    # before JAX starts its threads: a tmpfs of the run's own is there for
+    # this thread and for those started after it
+    sink = mix.get("sink", "tmp")
+    snap_root, sink_fs = make_sink(sink)
     trace_dir = os.path.join(snap_root, "trace")
     try:
+        import jax
+
+        if not allow_cpu:
+            enable_compile_cache(root)
+        devices = pick_devices(cell.chips, allow_cpu)
+        kind = devices[0].device_kind
+        if devices[0].platform == "tpu":
+            cell.peak_of(kind)
+
+        from torchsnapshot_tpu import obs
+        from torchsnapshot_tpu.obs import tracer as program_tracer
+        from torchsnapshot_tpu.ops import device_pack
+        from torchsnapshot_tpu.preparers.array import DONATION_STATS
+
+        def swallowed() -> int:
+            return obs.metrics_snapshot()["counters"].get("exceptions.swallowed", 0)
+
+        swallowed0 = swallowed()
         driver = Driver(cell, seed, devices, snap_root, fault=fault)
         driver.make_state()
+        if sink == "ram":
+            need_room(snap_root, SINK_STATES * driver.notes["state_bytes"])
         driver.run_ops(mix["setup"])
         setup_timeline, driver.timeline = driver.timeline, []
         print("chipbench set-up: " + ", ".join(
@@ -520,8 +624,6 @@ def run_cell(
 
         with contextlib.ExitStack() as pollers:
             if trace:
-                import psutil
-
                 proc = psutil.Process()
                 rss0 = proc.memory_info().rss
                 hbm = pollers.enter_context(MaxPoller(_bytes_in_use(devices), 0.02))
@@ -573,7 +675,7 @@ def run_cell(
             record = trace_reduce.load_xplane(xplane)
             reduced = trace_reduce.reduce(record)
     finally:
-        shutil.rmtree(snap_root, ignore_errors=True)
+        remove_sink(snap_root)
 
     checks: Dict[str, Dict[str, Any]] = {
         key: {"value": n, "limit": 0} for key, n in driver.wrong.items()
@@ -629,6 +731,7 @@ def run_cell(
     result["window_s"] = arith.window_seconds(timeline)
     result["state_bytes"] = driver.notes["state_bytes"]
     result["bytes_written"] = driver.bytes_written
+    result["sink"], result["sink_fs"] = sink, sink_fs
     result["checks"] = checks
     return result
 

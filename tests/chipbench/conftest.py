@@ -28,6 +28,22 @@ def _rewrite(path, change):
         json.dump(data, f)
 
 
+@pytest.fixture(autouse=True)
+def no_mount(monkeypatch, tmp_path):
+    """The tests share their process, so no run in it mounts a tmpfs of its
+    own: a RAM sink is its plain directory under ``TMPDIR``, and this lists
+    where a mount was asked for (``test_a_tmpfs_of_the_runs_own_goes_with_the_run``
+    makes the real one, in a process of its own)."""
+    import tempfile
+
+    from chipbench import bench
+
+    mounted = []
+    monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
+    monkeypatch.setattr(bench, "_mount_own_tmpfs", mounted.append)
+    return mounted
+
+
 @pytest.fixture(scope="session")
 def repo():
     return REPO

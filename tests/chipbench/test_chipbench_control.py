@@ -8,7 +8,7 @@ import pytest
 from chipbench import state
 
 RESTORE_CELLS = ["ouro-2.6b-d9.kill_resume", "ouro-2.6b-d32.reshard_resume"]
-SAVE_CELLS = ["ouro-2.6b-d3.preempt_sync_save", "ouro-2.6b-d4.async_save_train"]
+SAVE_CELLS = ["ouro-2.6b-d9.preempt_sync_save", "ouro-2.6b-d4.async_save_train"]
 
 
 @pytest.mark.parametrize("workload", RESTORE_CELLS + SAVE_CELLS)
@@ -26,7 +26,7 @@ def test_an_answer_altered_where_it_is_produced_is_caught(benchmark_json, run_ti
     assert result["checks"]["leaves_mismatched"]["value"] >= 1
 
 
-@pytest.mark.parametrize("workload", SAVE_CELLS[:1])
+@pytest.mark.parametrize("workload", SAVE_CELLS)
 def test_a_snapshot_of_a_later_state_is_caught(benchmark_json, run_tiny, workload):
     result = run_tiny(workload, fault="late_snapshot")
     assert result["correct"] is False

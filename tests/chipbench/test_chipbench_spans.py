@@ -259,3 +259,114 @@ def test_spans_of_a_window_stay_under_the_recorder_cap(traced_run):
     _, ctx = traced_run
     assert tracer.get_tracer().dropped == 0
     assert 0 < len(ctx.spans) < tracer._MAX_SPANS
+
+
+# ------------------------------------------------- the save side: per take
+
+SAVE_CELL = "ouro-2.6b-d9.preempt_sync_save"
+SAVE_READERS = ["plan.take_s", "stage.queue_s", "d2h.copy_s", "stage.digest_s"]
+
+
+@pytest.fixture(scope="module")
+def save_cell(repo):
+    return bench.Cell(repo, SAVE_CELL)
+
+
+def test_benchmark_json_lists_the_save_readers_for_the_sync_cell(save_cell):
+    listed = {m["name"]: m for m in save_cell.per_layer_metrics()}
+    for name in SAVE_READERS:
+        assert listed[name]["moves"] == "save_commit_s"
+        assert listed[name]["workloads"] == [SAVE_CELL]
+        assert callable(save_cell.reader(name))
+
+
+def _take(spans, timeline, at_ms, stall=None, piped=True, asynchronous=False):
+    """One blocking take as the program records it: 2 ms of plan, then a
+    pipeline whose staging pool materializes one slab (7 ms in the queue,
+    30 ms of work, 20 of them in the copy off the device), copies one host
+    array (1 ms, 5 ms) and digests both (2 ms in the queue and 4 ms each).
+    ``stall`` adds 1000 ms to one phase and moves everything after it."""
+    add = {k: 1000 if k == stall else 0 for k in ("plan", "queue", "d2h", "digest")}
+    t = at_ms + 1
+    t = _span(spans, "take/plan", None, t, 2 + add["plan"], leaves=2, rank=0).end_ns / MS
+    pipe = _span(spans, "take/pipeline", None, t, 0, workers=4, writes=2) if piped else None
+    queue = 7 + add["queue"]
+    work = 30 + add["d2h"]
+    stage = _span(spans, "stage/materialize", pipe, t + queue, work,
+                  thread="tsnp-stage_0", queue_ns=queue * MS, bytes=1 << 22)
+    _span(spans, "d2h/copy", stage, t + queue + 5, 20 + add["d2h"], thread="tsnp-stage_0",
+          bytes=1 << 22)
+    _span(spans, "stage/copy", pipe, t + 1, 5, thread="tsnp-stage_1", queue_ns=1 * MS,
+          bytes=1 << 20)
+    t = stage.end_ns / MS
+    for _ in range(2):
+        t = _span(spans, "stage/digest", pipe, t + 2, 4 + add["digest"] / 2,
+                  thread="tsnp-stage_0", queue_ns=2 * MS, bytes=1 << 20).end_ns / MS
+    if pipe is not None:
+        pipe.end_ns = int((t + 3) * MS)
+    end = t + 5
+    timeline.append({"op": "step", "t0": at_ms / 1e3 - 0.01, "t1": at_ms / 1e3})
+    record = {"op": "take", "t0": at_ms / 1e3, "t1": end / 1e3}
+    if asynchronous:
+        record["asynchronous"] = True
+    timeline.append(record)
+    return end
+
+
+def _save_context(stall=None, takes=2, **kw):
+    spans, timeline, at = [], [], 7000.0
+    for i in range(takes):
+        at = _take(spans, timeline, at, stall=stall if i == 1 else None, **kw) + 15
+    # a copy off the device outside every take belongs to no save
+    _span(spans, "d2h/copy", None, at + 50, 500)
+    return bench.Context(timeline=timeline, spans=spans)
+
+
+def _read_saves(cell, ctx):
+    return {name: cell.reader(name)(ctx) for name in SAVE_READERS}
+
+
+def test_save_readers_on_a_hand_built_window(save_cell):
+    got = _read_saves(save_cell, _save_context())
+    assert got["plan.take_s"] == pytest.approx(0.002)
+    assert got["stage.queue_s"] == pytest.approx(0.007 + 0.001 + 2 * 0.002)
+    assert got["d2h.copy_s"] == pytest.approx(0.020)
+    assert got["stage.digest_s"] == pytest.approx(0.008)
+
+
+SAVE_STALLS = {
+    "plan": "plan.take_s", "queue": "stage.queue_s",
+    "d2h": "d2h.copy_s", "digest": "stage.digest_s",
+}
+
+
+@pytest.mark.parametrize("phase", sorted(SAVE_STALLS))
+def test_a_stall_planted_in_a_save_moves_its_reader_and_no_other(save_cell, phase):
+    clean = _read_saves(save_cell, _save_context())
+    stalled = _read_saves(save_cell, _save_context(stall=phase))
+    moved = {n for n in SAVE_READERS if stalled[n] != pytest.approx(clean[n], abs=1e-9)}
+    own = SAVE_STALLS[phase]
+    assert moved == {own}
+    # one second in one of two takes: half a second per save
+    assert stalled[own] - clean[own] == pytest.approx(0.5)
+
+
+@pytest.mark.parametrize("name", SAVE_READERS[1:])
+def test_a_save_reader_finds_nothing_without_a_blocking_take(save_cell, name):
+    read = save_cell.reader(name)
+    assert read(_save_context(takes=0)) is None
+    assert read(bench.Context(timeline=[], spans=[])) is None
+    # takes in the timeline, but the run was not traced
+    assert read(bench.Context(timeline=_save_context().timeline, spans=[])) is None
+    # a program that records no pipeline (the commits before PR 26)
+    assert read(_save_context(piped=False)) is None
+    # the call of an async_take returns before its spans: not a save here
+    assert read(_save_context(asynchronous=True)) is None
+
+
+def test_a_restore_window_has_no_save_and_a_save_window_no_restore(cell, save_cell):
+    restores, saves = _context(), _save_context()
+    for name in SAVE_READERS[1:]:
+        assert save_cell.reader(name)(restores) is None
+    for name in READERS:
+        assert cell.reader(name)(saves) is None

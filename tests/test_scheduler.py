@@ -284,3 +284,217 @@ def test_loop_thread_survives_and_counts_a_loader_failure(monkeypatch):
         assert counter.value == before + 1
     finally:
         lt.shutdown()
+
+
+# ------------------------------------------------ the order of staging work
+#
+# The staging pool is a FIFO.  A budget that admits every request at once
+# must not queue every materialization ahead of the first staged object's
+# checksum: no more materializations are in the pool than it has workers.
+
+
+class _Record:
+    """What the stagers and checksum sinks of one save did, in order."""
+
+    def __init__(self):
+        self.lock = threading.Lock()
+        self.events = []  # ("materialize" | "checksum", k) as each starts
+        self.in_flight = 0  # stage_buffer calls entered and not left
+        self.most_in_flight = 0
+
+    def mark(self, what, k):
+        with self.lock:
+            self.events.append((what, k))
+
+    def at(self, what, k):
+        return self.events.index((what, k))
+
+
+class RecordingStager(BufferStager):
+    """Materializes on the pool, as a device array's stager does."""
+
+    def __init__(self, record, k, payload, work_s=0.0, fail=False):
+        self.record, self.k, self.payload = record, k, payload
+        self.work_s, self.fail = work_s, fail
+
+    def _materialize(self):
+        self.record.mark("materialize", self.k)
+        time.sleep(self.work_s)
+        if self.fail:
+            raise RuntimeError(f"injected staging failure on {self.k}")
+        return self.payload
+
+    async def stage_buffer(self, executor=None):
+        rec = self.record
+        with rec.lock:
+            rec.in_flight += 1
+            rec.most_in_flight = max(rec.most_in_flight, rec.in_flight)
+        try:
+            return await asyncio.get_running_loop().run_in_executor(
+                executor, self._materialize
+            )
+        finally:
+            with rec.lock:
+                rec.in_flight -= 1
+
+    def get_staging_cost_bytes(self):
+        return len(self.payload)
+
+
+def _recorded_reqs(record, n, work_s, fail_at=None):
+    # staging is largest-first: object k is the k-th to stage.  The two
+    # workers' completions are kept half a materialization apart (object 1
+    # alone takes half as long again), so at each completion exactly one
+    # worker is free and what it runs next is in the pool's queue order
+    reqs = []
+    for k in range(n):
+        stager = RecordingStager(
+            record, k, bytes([k]) * (200 - k),
+            work_s=work_s * (1.5 if k == 1 else 1.0), fail=(k == fail_at),
+        )
+        sink = lambda crc, k=k: record.mark("checksum", k)  # noqa: E731
+        reqs.append(
+            WriteReq(path=f"o{k}", buffer_stager=stager, checksum_sinks=[(sink, None)])
+        )
+    return reqs
+
+
+def _ends_within(seconds, fn):
+    """``fn``'s result or exception; a pipeline that waits forever fails
+    the test instead of hanging the suite."""
+    box = {}
+
+    def run():
+        try:
+            box["result"] = fn()
+        except BaseException as e:  # noqa: BLE001
+            box["error"] = e
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    t.join(seconds)
+    assert not t.is_alive(), f"the pipeline did not end within {seconds} s"
+    if "error" in box:
+        raise box["error"]
+    return box["result"]
+
+
+def _save(reqs, storage, budget=1 << 30):
+    def run():
+        pending = sync_execute_write_reqs(reqs, storage, budget, rank=0)
+        pending.sync_complete()
+        return pending
+
+    return _ends_within(30, run)
+
+
+def test_no_more_materializations_in_the_pool_than_it_has_workers():
+    record = _Record()
+    storage = TrackingStorage()
+    with knobs.override_staging_threads(2):
+        _save(_recorded_reqs(record, 8, work_s=0.02), storage)
+    assert len(storage.writes) == 8
+    assert record.most_in_flight == 2, record.events
+
+
+def test_a_staged_objects_checksum_runs_before_later_materializations():
+    record = _Record()
+    storage = TrackingStorage()
+    with knobs.override_staging_threads(2):
+        _save(_recorded_reqs(record, 8, work_s=0.04), storage)
+    assert sorted(storage.writes) == sorted(f"o{k}" for k in range(8))
+    for k in range(6):
+        assert record.at("checksum", k) < record.at("materialize", k + 2), (
+            k, record.events
+        )
+
+
+def test_a_stager_that_raises_gives_its_place_back():
+    record = _Record()
+    storage = TrackingStorage()
+    with knobs.override_staging_threads(2):
+        with pytest.raises(RuntimeError, match="injected staging failure on 1"):
+            _save(_recorded_reqs(record, 8, work_s=0.01, fail_at=1), storage)
+    assert record.in_flight == 0
+
+
+def test_a_cancelled_save_leaves_no_request_waiting():
+    from concurrent.futures import ThreadPoolExecutor
+
+    from torchsnapshot_tpu.scheduler import (
+        _Budget,
+        _WritePipeline,
+        _execute_write_pipelines,
+    )
+
+    record = _Record()
+    staging_done = threading.Event()
+    executor = ThreadPoolExecutor(max_workers=1)
+
+    async def cancelled_mid_save():
+        pipelines = [
+            _WritePipeline(wr) for wr in _recorded_reqs(record, 6, work_s=0.05)
+        ]
+        save = asyncio.ensure_future(_execute_write_pipelines(
+            pipelines, TrackingStorage(), _Budget(1 << 30), executor, 1,
+            staging_done, {"bytes_written": 0},
+        ))
+        await asyncio.sleep(0.02)  # object 0 materializes, 1..5 wait their turn
+        save.cancel()
+        with pytest.raises(asyncio.CancelledError):
+            await save
+        for _ in range(200):  # the cancelled stagers run their ``finally``
+            if len(asyncio.all_tasks()) == 1:
+                break
+            await asyncio.sleep(0.01)
+        return len(asyncio.all_tasks())
+
+    try:
+        assert _ends_within(30, lambda: asyncio.run(cancelled_mid_save())) == 1
+    finally:
+        executor.shutdown(wait=True)
+    assert staging_done.is_set()
+    assert record.in_flight == 0
+    assert ("materialize", 5) not in record.events
+
+
+def test_a_host_packed_slab_stages_its_members_in_one_place():
+    # the host fallback stages a slab's members in turn inside the slab's
+    # own stage_buffer: it holds one place throughout, so a pool (and a
+    # bound) of 1 must not leave it waiting for itself
+    import numpy as np
+
+    from torchsnapshot_tpu.batcher import BatchedBufferStager
+    from torchsnapshot_tpu.preparers.array import HostArrayBufferStager
+
+    members = [
+        np.full(300_000 + 1000 * i, i, dtype=np.uint8) for i in range(4)
+    ]  # over the slab's executor-hop floor: each member's copy runs on the pool
+    crcs = {}
+
+    def slab(tag):
+        stagers = [(HostArrayBufferStager(m, defensive_copy=False), m.nbytes) for m in members]
+        sinks, offset = [], 0
+        for i, m in enumerate(members):
+            sinks.append((
+                lambda crc, key=(tag, i): crcs.__setitem__(key, crc),
+                (offset, offset + m.nbytes),
+            ))
+            offset += m.nbytes
+        return WriteReq(
+            path=f"slab.{tag}", buffer_stager=BatchedBufferStager(stagers, offset),
+            checksum_sinks=sinks,
+        )
+
+    storage = TrackingStorage()
+    reqs = [slab("a"), slab("b"), WriteReq(path="leaf", buffer_stager=ChunkStager(b"x" * 10))]
+    with knobs.override_staging_threads(1):
+        _save(reqs, storage)
+    want = b"".join(m.tobytes() for m in members)
+    assert storage.writes["slab.a"] == want and storage.writes["slab.b"] == want
+    assert storage.writes["leaf"] == b"x" * 10
+    import zlib
+
+    assert crcs == {
+        (tag, i): zlib.crc32(m.tobytes()) for tag in "ab" for i, m in enumerate(members)
+    }

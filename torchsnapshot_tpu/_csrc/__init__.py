@@ -290,6 +290,16 @@ def load() -> Optional[ctypes.CDLL]:
                 ctypes.c_int64,
                 ctypes.c_void_p,
             ]
+        # the staging arena (staging_arena.py): numpy calls the allocator
+        # through the handler struct, Python only these
+        lib.tsnp_arena_handler.restype = ctypes.c_void_p
+        lib.tsnp_arena_handler.argtypes = []
+        lib.tsnp_arena_set_cap.restype = None
+        lib.tsnp_arena_set_cap.argtypes = [ctypes.c_uint64]
+        lib.tsnp_arena_end_save.restype = None
+        lib.tsnp_arena_end_save.argtypes = []
+        lib.tsnp_arena_stats.restype = None
+        lib.tsnp_arena_stats.argtypes = [ctypes.POINTER(ctypes.c_uint64)]
         _lib = lib
         return _lib
 

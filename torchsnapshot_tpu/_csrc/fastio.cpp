@@ -1115,3 +1115,222 @@ int64_t tsnp_huff_decompress(const uint8_t *src, int64_t n, uint8_t *dst,
 }
 
 }  // extern "C"
+
+// ---------------------------------------------------------------------------
+// Staging arena: a numpy data allocator (NEP 49, PyDataMem_Handler) that
+// staging_arena.py puts in place around a device→host copy.  The host side
+// of such a copy is one malloc of the object's size and one free when its
+// write is done.  glibc serves a request over 32 MiB
+// (DEFAULT_MMAP_THRESHOLD_MAX) with an mmap of its own and gives it back
+// with munmap, so every object faults its bytes in anew; where pages are
+// dear (a sandboxed host) that is most of what a copy waits for.  Here a
+// block of that size is kept when numpy frees it and handed out again to
+// the next request of the same size: the next slab of this save, the same
+// object of the next.  Bytes kept and bytes handed out together stay under
+// the cap (the save's memory budget) wherever what is handed out alone
+// does: a request that no kept block fits first unmaps kept blocks, oldest
+// first, until its own mapping has room.  A block that no request took for
+// a whole save goes at that save's end.  Smaller requests, calloc and
+// realloc are malloc's.
+
+#include <cstdlib>
+#include <mutex>
+#include <sys/mman.h>
+#include <unordered_map>
+#include <vector>
+
+namespace {
+
+constexpr size_t kArenaMin = size_t(32) << 20;
+
+struct ArenaBlock {
+  void *p;
+  size_t size;
+  uint64_t save;  // the save in which it was last given back
+};
+
+// never destroyed: numpy may free an array while the process exits
+std::mutex &g_arena_mu = *new std::mutex;
+auto &g_arena_kept = *new std::vector<ArenaBlock>;             // oldest first
+auto &g_arena_live = *new std::unordered_map<void *, size_t>;  // handed out
+size_t g_arena_kept_bytes = 0, g_arena_live_bytes = 0;
+size_t g_arena_cap = 0;
+uint64_t g_arena_save = 0;
+uint64_t g_arena_reused = 0, g_arena_mapped = 0;
+
+// under the lock: kept blocks leave, oldest first, until kept + live +
+// ``more`` is within the cap or nothing is kept
+std::vector<ArenaBlock> arena_make_room(size_t more) {
+  std::vector<ArenaBlock> out;
+  size_t n = 0;
+  while (n < g_arena_kept.size() &&
+         g_arena_kept_bytes + g_arena_live_bytes + more > g_arena_cap) {
+    g_arena_kept_bytes -= g_arena_kept[n].size;
+    out.push_back(g_arena_kept[n++]);
+  }
+  g_arena_kept.erase(g_arena_kept.begin(), g_arena_kept.begin() + n);
+  return out;
+}
+
+void arena_unmap(const std::vector<ArenaBlock> &blocks) {
+  for (const ArenaBlock &b : blocks)
+    munmap(b.p, b.size);
+}
+
+void *arena_map(size_t size) {
+  void *p = mmap(nullptr, size, PROT_READ | PROT_WRITE,
+                 MAP_PRIVATE | MAP_ANONYMOUS, -1, 0);
+  return p == MAP_FAILED ? nullptr : p;
+}
+
+void *arena_malloc(void *, size_t size) {
+  if (size < kArenaMin)
+    return malloc(size ? size : 1);
+  std::vector<ArenaBlock> gone;
+  {
+    std::lock_guard<std::mutex> lock(g_arena_mu);
+    for (size_t i = g_arena_kept.size(); i-- > 0;) {
+      if (g_arena_kept[i].size == size) {
+        void *p = g_arena_kept[i].p;
+        g_arena_kept.erase(g_arena_kept.begin() + i);
+        g_arena_kept_bytes -= size;
+        g_arena_live[p] = size;
+        g_arena_live_bytes += size;
+        ++g_arena_reused;
+        return p;
+      }
+    }
+    gone = arena_make_room(size);
+    g_arena_live_bytes += size;  // the room is this request's from here on
+  }
+  arena_unmap(gone);
+  void *p = arena_map(size);
+  if (p == nullptr) {  // the system has no room: not while blocks are kept
+    {
+      std::lock_guard<std::mutex> lock(g_arena_mu);
+      gone = std::move(g_arena_kept);
+      g_arena_kept.clear();
+      g_arena_kept_bytes = 0;
+    }
+    arena_unmap(gone);
+    p = arena_map(size);
+  }
+  std::lock_guard<std::mutex> lock(g_arena_mu);
+  if (p == nullptr) {
+    g_arena_live_bytes -= size;
+    return nullptr;
+  }
+  g_arena_live[p] = size;
+  ++g_arena_mapped;
+  return p;
+}
+
+void arena_free(void *, void *p, size_t) {
+  if (p == nullptr)
+    return;
+  std::vector<ArenaBlock> gone;
+  {
+    std::lock_guard<std::mutex> lock(g_arena_mu);
+    auto it = g_arena_live.find(p);
+    if (it == g_arena_live.end()) {
+      free(p);  // malloc's own
+      return;
+    }
+    g_arena_kept.push_back({p, it->second, g_arena_save});
+    g_arena_kept_bytes += it->second;
+    g_arena_live_bytes -= it->second;
+    g_arena_live.erase(it);
+    gone = arena_make_room(0);
+  }
+  arena_unmap(gone);
+}
+
+void *arena_calloc(void *, size_t n, size_t size) {
+  return calloc(n ? n : 1, size ? size : 1);
+}
+
+// malloc's own stay malloc's; a block of the arena moves out to malloc
+// (nothing on the save path resizes a staged array)
+void *arena_realloc(void *ctx, void *p, size_t size) {
+  size_t old = 0;
+  {
+    std::lock_guard<std::mutex> lock(g_arena_mu);
+    auto it = g_arena_live.find(p);
+    if (it != g_arena_live.end())
+      old = it->second;
+  }
+  if (old == 0)
+    return realloc(p, size ? size : 1);
+  void *q = malloc(size ? size : 1);
+  if (q == nullptr)
+    return nullptr;
+  memcpy(q, p, old < size ? old : size);
+  arena_free(ctx, p, old);
+  return q;
+}
+
+// numpy's PyDataMem_Handler, version 1 (numpy/ndarraytypes.h).  Every array
+// made under the arena points at this struct until it is freed, which may
+// be while the interpreter shuts down: it lives as long as the process.
+struct NpyHandler {
+  char name[127];
+  uint8_t version;
+  struct {
+    void *ctx;
+    void *(*malloc)(void *, size_t);
+    void *(*calloc)(void *, size_t, size_t);
+    void *(*realloc)(void *, void *, size_t);
+    void (*free)(void *, void *, size_t);
+  } allocator;
+};
+
+NpyHandler g_arena_handler = {
+    "tsnp_staging_arena",
+    1,
+    {nullptr, arena_malloc, arena_calloc, arena_realloc, arena_free}};
+
+}  // namespace
+
+extern "C" {
+
+// what PyCapsule_New("mem_handler") wraps for PyDataMem_SetHandler
+void *tsnp_arena_handler(void) { return &g_arena_handler; }
+
+// The most bytes kept and handed out together; less lets the oldest kept
+// blocks go at once, 0 all of them.
+void tsnp_arena_set_cap(uint64_t cap) {
+  std::vector<ArenaBlock> gone;
+  {
+    std::lock_guard<std::mutex> lock(g_arena_mu);
+    g_arena_cap = static_cast<size_t>(cap);
+    gone = arena_make_room(0);
+  }
+  arena_unmap(gone);
+}
+
+// A save has ended: what was kept all through it and not asked for goes.
+void tsnp_arena_end_save(void) {
+  std::vector<ArenaBlock> gone, stay;
+  {
+    std::lock_guard<std::mutex> lock(g_arena_mu);
+    for (const ArenaBlock &b : g_arena_kept)
+      (b.save < g_arena_save ? gone : stay).push_back(b);
+    for (const ArenaBlock &b : gone)
+      g_arena_kept_bytes -= b.size;
+    g_arena_kept.swap(stay);
+    ++g_arena_save;
+  }
+  arena_unmap(gone);
+}
+
+// out: bytes kept, bytes handed out, requests served from a kept block,
+// requests served by a new mapping
+void tsnp_arena_stats(uint64_t *out) {
+  std::lock_guard<std::mutex> lock(g_arena_mu);
+  out[0] = g_arena_kept_bytes;
+  out[1] = g_arena_live_bytes;
+  out[2] = g_arena_reused;
+  out[3] = g_arena_mapped;
+}
+
+}  // extern "C"

@@ -29,7 +29,7 @@ from typing import Any, List, Optional
 
 import numpy as np
 
-from .. import obs
+from .. import obs, staging_arena
 
 
 def packed_width(dtype) -> int:
@@ -127,14 +127,17 @@ def pack_arrays_to_host(arrays: List[Any]) -> np.ndarray:
             _pack_jit = jax.jit(_pack)
         pack_fn = _pack_jit
     packed = pack_fn(arrays)
-    try:
-        packed.copy_to_host_async()
-    except Exception as e:
-        obs.swallowed_exception("device_pack.copy_to_host_async", e)
-    # materializes (async failures surface here); the host reads the
-    # words as the bytes they are
-    with obs.span("d2h/copy", bytes=packed.nbytes):
-        out = np.asarray(packed).view(np.uint8)
+    # the slab's host array is made by whichever of the two calls comes to
+    # it first: both inside the arena
+    with staging_arena.allocating():
+        try:
+            packed.copy_to_host_async()
+        except Exception as e:
+            obs.swallowed_exception("device_pack.copy_to_host_async", e)
+        # materializes (async failures surface here); the host reads the
+        # words as the bytes they are
+        with obs.span("d2h/copy", bytes=packed.nbytes):
+            out = np.asarray(packed).view(np.uint8)
     _count("pack")
     return out
 

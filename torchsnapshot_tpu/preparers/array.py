@@ -4,11 +4,18 @@
 Reference: torchsnapshot/io_preparers/tensor.py:50-409 and
 io_preparers/chunked_tensor.py:36-128.  TPU-native differences:
 
-- The device→host copy is ``jax.Array.copy_to_host_async()`` (launched at
-  staging-admission time on XLA's transfer stream) followed by
-  ``np.asarray`` in a worker thread — the analogue of the reference's CUDA
-  DtoH in a thread pool with the GIL released
-  (io_preparers/tensor.py:249-255).
+- The device→host copy is ``jax.Array.copy_to_host_async()`` and then
+  ``np.asarray``, both on the staging worker that materializes the object,
+  one line apart (``_materialize`` below, ``ops/device_pack.py``
+  ``pack_arrays_to_host``): a transfer is asked for when a worker is free
+  to wait for it, never at admission or ahead of the pool — the analogue
+  of the reference's CUDA DtoH in a thread pool with the GIL released
+  (io_preparers/tensor.py:249-255).  It should stay so: on a TPU v5 lite,
+  into newly made host arrays, four workers that each ask and wait move
+  2.5–2.6 GB/s, one alone 2.4, and 16 x 403 MiB asked for at once 1.1–1.4
+  (PERF.md §5, the D2H probe of PR 28: many outstanding transfers
+  collapse, as they do host→device).  The host array itself comes from
+  ``staging_arena``: a block kept from the save before, not a new mapping.
 - Chunked staging slices the array **on device** (bounded HBM copy) so host
   memory stays bounded by the chunk size while D2H overlaps storage I/O.
 - Defensive copies for async snapshots apply only to *host* arrays
@@ -27,7 +34,7 @@ from typing import Any, List, Optional, Tuple
 
 import numpy as np
 
-from .. import knobs, obs
+from .. import knobs, obs, staging_arena
 from ..io_types import BufferConsumer, BufferStager, Future, ReadReq, WriteReq
 from ..manifest import ArrayEntry, ChunkedArrayEntry, Shard
 import logging
@@ -222,15 +229,20 @@ class JaxArrayBufferStager(BufferStager):
                     "Offloaded leaves are immune; " + why
                 )
             a = src if self.index is None else src[self.index]
-            try:
-                a.copy_to_host_async()
-            except Exception as e:
-                # some array types (fully replicated committed) decline
-                # the async prefetch; np.asarray below does the copy
-                # synchronously either way
-                obs.swallowed_exception("array_stager.copy_to_host_async", e)
-            with obs.span("d2h/copy", bytes=self.nbytes):
-                return np.asarray(a)
+            # the host array is made by whichever of the two calls comes
+            # to it first: both inside the arena
+            with staging_arena.allocating():
+                try:
+                    a.copy_to_host_async()
+                except Exception as e:
+                    # some array types (fully replicated committed) decline
+                    # the async prefetch; np.asarray below does the copy
+                    # synchronously either way
+                    obs.swallowed_exception(
+                        "array_stager.copy_to_host_async", e
+                    )
+                with obs.span("d2h/copy", bytes=self.nbytes):
+                    return np.asarray(a)
 
         async def _run(src: Any) -> np.ndarray:
             if executor is not None:

@@ -44,6 +44,7 @@ import psutil
 from . import _csrc
 from . import codec as codec_mod
 from . import knobs
+from . import staging_arena
 from .cas import store as cas_store_mod
 from .io_types import (
     ReadIO,
@@ -354,6 +355,7 @@ class PendingIOWork:
             self._completed = True
             self._executor.shutdown(wait=False)
             self._loop_thread.shutdown()
+            staging_arena.end_save()
         elapsed = self._stats.get("end_ts", time.monotonic()) - self._stats["begin_ts"]
         gb = self._stats["bytes_written"] / 1e9
         if elapsed > 0 and gb > 0:
@@ -404,6 +406,7 @@ async def _execute_write_pipelines(
     storage: StoragePlugin,
     budget: _Budget,
     executor: ThreadPoolExecutor,
+    workers: int,
     staging_done: threading.Event,
     stats: dict,
 ) -> None:
@@ -510,6 +513,16 @@ async def _execute_write_pipelines(
     # deque on every task completion (O(n^2) across a large take)
     min_pending_cost = min((p.admission_cost for p in pipelines), default=0)
 
+    # Order, not bytes (the budget bounds those): the staging pool is a
+    # FIFO, and a budget that admits every request at once would queue all
+    # their materializations ahead of the first staged object's checksum.
+    # No more materializations than the pool has workers are in it at any
+    # time, so a staged object's later stages are next in line and copy,
+    # checksum and write of different objects overlap for the whole save.
+    # (The wait for a place is the loop's, not the pool's: no ``stage/*``
+    # span's ``queue_ns`` counts it.)
+    materializing = asyncio.Semaphore(workers)
+
     async def stage_one(p: _WritePipeline) -> _WritePipeline:
         with obs_tracer.span(
             "pipeline/staging", path=p.write_req.path, cost=p.staging_cost
@@ -527,7 +540,8 @@ async def _execute_write_pipelines(
         # slowness lands in the phase the flight record attributes
         t_stage = time.perf_counter()
         failpoint("scheduler.stage", path=p.write_req.path)
-        p.buf = await p.write_req.buffer_stager.stage_buffer(executor)
+        async with materializing:
+            p.buf = await p.write_req.buffer_stager.stage_buffer(executor)
         p.buf_size = _buf_nbytes(p.buf)
         wr = p.write_req
         # chunk-store writes never encode (chunk keys ARE raw digests;
@@ -957,6 +971,9 @@ def sync_execute_write_reqs(
             reverse=True,
         )
         budget = _Budget(memory_budget_bytes)
+        # the budget bounds the arena's bytes too, kept ones included;
+        # PendingIOWork.sync_complete ends what begins here
+        staging_arena.begin_save(memory_budget_bytes)
         staging_done = threading.Event()
         stats = {"bytes_written": 0, "begin_ts": time.monotonic()}
         loop_thread = _LoopThread()
@@ -964,7 +981,8 @@ def sync_execute_write_reqs(
         def _start() -> concurrent.futures.Future:
             return loop_thread.submit(
                 _execute_write_pipelines(
-                    pipelines, storage, budget, executor, staging_done, stats
+                    pipelines, storage, budget, executor, workers,
+                    staging_done, stats,
                 )
             )
 

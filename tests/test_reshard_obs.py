@@ -1,0 +1,116 @@
+"""What the sharded restore path records: ``reshard/plan``, ``reshard/scatter``
+and ``reshard/assemble`` spans, one ``h2d/put`` span a device of a sharded
+leaf, and the counter ``reshard.host_alloc_bytes`` (the local boxes' bytes)
+beside ``bytes_read`` (what the sink gave)."""
+
+import jax
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from torchsnapshot_tpu import PyTreeState, Snapshot, knobs, obs
+from torchsnapshot_tpu.obs import tracer
+
+COUNTERS = (obs.RESHARD_HOST_ALLOC_BYTES, obs.BYTES_READ)
+
+
+def _mesh(dp, tp):
+    return Mesh(np.array(jax.devices()[: dp * tp]).reshape(dp, tp), ("dp", "tp"))
+
+
+def _state(mesh, seed):
+    rng = np.random.default_rng(seed)
+
+    def put(shape, spec):
+        return jax.device_put(
+            rng.standard_normal(shape).astype(np.float32), NamedSharding(mesh, P(*spec))
+        )
+
+    return {
+        "cols": put((8, 16), (None, "tp")),  # a saved shard's halves go to two devices
+        "rows": put((16, 8), ("tp", None)),  # dim-0 slabs
+        "norm": put((32,), (None,)),  # replicated on every device
+    }
+
+
+def _counters():
+    snap = obs.metrics_snapshot()["counters"]
+    return {name: snap.get(name, 0) for name in COUNTERS}
+
+
+@pytest.fixture
+def restored(tmp_path):
+    """A state saved under 2x2 and restored under 1x4 with tracing on: the
+    templates, the restored leaves, the spans and what the counters gained."""
+    saved = _state(_mesh(2, 2), 1)
+    Snapshot.take(str(tmp_path / "snap"), {"ts": PyTreeState(saved)})
+    templates = _state(_mesh(1, 4), 2)
+    app = {"ts": PyTreeState(dict(templates))}
+    before = _counters()
+    with knobs.override_trace(True):
+        tracer.get_tracer().reset()
+        Snapshot(str(tmp_path / "snap")).restore(app)
+        spans = tracer.get_tracer().spans()
+    gained = {name: after - before[name] for name, after in _counters().items()}
+    return saved, templates, app["ts"].tree, spans, gained
+
+
+def test_a_sharded_leafs_device_puts_record_h2d_put_spans(restored):
+    saved, templates, tree, spans, _ = restored
+    for name, leaf in tree.items():
+        assert np.array_equal(np.asarray(leaf), np.asarray(saved[name])), name
+        assert leaf.sharding.is_equivalent_to(templates[name].sharding, leaf.ndim)
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s.name, []).append(s)
+    assembles = by_name["reshard/assemble"]
+    assert len(assembles) == len(by_name["reshard/plan"]) == 3
+    assert sorted(s.attrs["devices"] for s in assembles) == [4, 4, 4]
+    assert sum(s.attrs["bytes"] for s in assembles) == sum(x.nbytes for x in tree.values())
+    puts = by_name["h2d/put"]
+    inside = {a.span_id for a in assembles}
+    assert all(p.parent_id in inside for p in puts)
+    # a put a device for each leaf the tp axis cuts, one broadcasting put
+    # for the replicated one
+    per_device = [p for p in puts if "device" in p.attrs]
+    assert sorted(p.attrs["device"] for p in per_device) == sorted(
+        2 * [d.id for d in jax.devices()[:4]]
+    )
+    assert len(puts) == len(per_device) + 1
+    assert sum(p.attrs["bytes"] for p in per_device) == saved["cols"].nbytes + saved["rows"].nbytes
+    plans = by_name["reshard/plan"]
+    # the plan's counts: saved shards read, unique local boxes, read requests
+    assert sorted(s.attrs["saved_shards"] for s in plans) == [1, 2, 2]
+    assert sorted(s.attrs["local_boxes"] for s in plans) == [1, 4, 4]
+    assert all(s.attrs["read_reqs"] >= 1 for s in plans)
+    scatters = by_name["reshard/scatter"]
+    assert sum(s.attrs["bytes"] for s in scatters) == sum(x.nbytes for x in tree.values())
+    hops = {s.span_id: s for s in by_name["consume/materialize"]}
+    assert all(s.parent_id in hops and "queue_ns" in hops[s.parent_id].attrs for s in scatters)
+
+
+def test_host_alloc_bytes_is_the_sum_of_the_local_boxes(restored):
+    _, templates, tree, _, gained = restored
+    local_boxes = 0
+    for leaf in templates.values():
+        shards = {str(s.index): s.data.nbytes for s in leaf.addressable_shards}
+        local_boxes += sum(shards.values())  # one buffer a unique box
+    state_bytes = sum(x.nbytes for x in tree.values())
+    assert gained[obs.RESHARD_HOST_ALLOC_BYTES] == local_boxes == state_bytes
+    # every saved byte comes from the sink once: a saved shard whose halves
+    # go to two devices is still one read
+    assert gained[obs.BYTES_READ] == state_bytes
+
+
+def test_the_spans_cost_nothing_recorded_with_tracing_off(tmp_path):
+    saved = _state(_mesh(2, 2), 3)
+    Snapshot.take(str(tmp_path / "snap"), {"ts": PyTreeState(saved)})
+    app = {"ts": PyTreeState(_state(_mesh(1, 4), 4))}
+    tracer.get_tracer().reset()
+    before = _counters()
+    Snapshot(str(tmp_path / "snap")).restore(app)
+    assert not [s for s in tracer.get_tracer().spans() if s.name.startswith(("reshard/", "h2d/"))]
+    # the counters are always on
+    assert _counters()[obs.RESHARD_HOST_ALLOC_BYTES] - before[obs.RESHARD_HOST_ALLOC_BYTES] == sum(
+        x.nbytes for x in saved.values()
+    )

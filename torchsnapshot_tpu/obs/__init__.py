@@ -93,6 +93,7 @@ from .metrics import (  # noqa: F401
     LIVENESS_HEARTBEATS,
     PROMOTION_LAG_S,
     REGISTRY,
+    RESHARD_HOST_ALLOC_BYTES,
     RESILIENCE_ABORTS,
     RESILIENCE_BACKOFF_DELAY_S,
     RESILIENCE_BREAKER_TRIPS,

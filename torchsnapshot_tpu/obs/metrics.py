@@ -294,6 +294,10 @@ TAKEOVER_BYTES = "takeover.bytes"
 TAKEOVER_DEGRADED_COMMITS = "takeover.degraded_commits"
 TAKEOVER_PATHS_REPAIRED = "takeover.paths_repaired"
 TAKEOVER_PROMOTER_DEAD_PEERS = "takeover.promoter_dead_peers"
+# Resharding restore (preparers/sharded.py): bytes of host assembly
+# buffers made, one per unique local box of a leaf (the plan's other
+# counts are attrs of its reshard/plan span).
+RESHARD_HOST_ALLOC_BYTES = "reshard.host_alloc_bytes"
 # Exception hygiene (tools/lint exception-hygiene pass): every
 # deliberate broad-except swallow on a fallback path increments this
 # via obs.swallowed_exception, so "how often are we falling back" is a

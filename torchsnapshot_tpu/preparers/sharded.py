@@ -261,135 +261,166 @@ class ShardedArrayIOPreparer:
         obj_out: Any = None,
         buffer_size_limit_bytes: Optional[int] = None,
     ) -> Tuple[List[ReadReq], Future]:
-        fut: Future = Future()
-        shape = tuple(entry.shape)
-        dtype = string_to_dtype(entry.dtype)
-        itemsize = dtype.itemsize
+        # reshard/plan: the overlap algebra of one leaf and the allocation
+        # of its assembly buffers, on the caller's thread
+        with obs.span("reshard/plan") as sp:
+            fut: Future = Future()
+            shape = tuple(entry.shape)
+            dtype = string_to_dtype(entry.dtype)
+            itemsize = dtype.itemsize
 
-        # Dedup saved shards by box (replicas may appear in merged manifests).
-        saved: Dict[Box, Shard] = {}
-        for s in entry.shards:
-            saved.setdefault(make_box(s.offsets, s.sizes), s)
+            # Dedup saved shards by box (replicas may appear in merged manifests).
+            saved: Dict[Box, Shard] = {}
+            for s in entry.shards:
+                saved.setdefault(make_box(s.offsets, s.sizes), s)
 
-        if obj_out is not None and is_multi_device_jax_array(obj_out):
-            sharding = obj_out.sharding
-            local_boxes: Dict[Box, List[Any]] = {}
-            idx_map = sharding.devices_indices_map(tuple(obj_out.shape))
-            for dev in sharding.addressable_devices:
-                box = index_to_box(idx_map[dev], obj_out.shape)
-                local_boxes.setdefault(box, []).append(dev)
-            target_dtype = np.dtype(obj_out.dtype)
-        else:
-            # No sharded template: materialize the full array, then hand it
-            # to the template logic (numpy in-place / device_put / fresh).
-            local_boxes = {make_box((0,) * len(shape), shape): [None]}
-            target_dtype = dtype
-
-        buffers: Dict[Box, np.ndarray] = {
-            box: np.empty(box[1], dtype=dtype) for box in local_boxes
-        }
-
-        # saved box -> [(overlap, local_box), ...]
-        plans: List[Tuple[Shard, Box, List[Tuple[Box, Box]]]] = []
-        for sbox, shard in saved.items():
-            overlaps = []
-            for lbox in local_boxes:
-                inter = box_intersect(sbox, lbox)
-                if inter is not None:
-                    overlaps.append((inter, lbox))
-            if overlaps:
-                plans.append((shard, sbox, overlaps))
-
-        def assemble() -> None:
             if obj_out is not None and is_multi_device_jax_array(obj_out):
-                import jax
+                sharding = obj_out.sharding
+                local_boxes: Dict[Box, List[Any]] = {}
+                idx_map = sharding.devices_indices_map(tuple(obj_out.shape))
+                for dev in sharding.addressable_devices:
+                    box = index_to_box(idx_map[dev], obj_out.shape)
+                    local_boxes.setdefault(box, []).append(dev)
+                target_dtype = np.dtype(obj_out.dtype)
+            else:
+                # No sharded template: materialize the full array, then hand it
+                # to the template logic (numpy in-place / device_put / fresh).
+                local_boxes = {make_box((0,) * len(shape), shape): [None]}
+                target_dtype = dtype
 
-                from .array import transfer_gate
+            buffers: Dict[Box, np.ndarray] = {
+                box: np.empty(box[1], dtype=dtype) for box in local_boxes
+            }
+            alloc_bytes = sum(b.nbytes for b in buffers.values())
+            target_shards = sum(len(devs) for devs in local_boxes.values())
+            obs.counter(obs.RESHARD_HOST_ALLOC_BYTES).inc(alloc_bytes)
 
-                if target_dtype != dtype:
-                    for box in list(buffers):
-                        buffers[box] = buffers[box].astype(target_dtype)
-                full_box = make_box(
-                    (0,) * len(obj_out.shape), tuple(obj_out.shape)
+            # saved box -> [(overlap, local_box), ...]
+            plans: List[Tuple[Shard, Box, List[Tuple[Box, Box]]]] = []
+            for sbox, shard in saved.items():
+                overlaps = []
+                for lbox in local_boxes:
+                    inter = box_intersect(sbox, lbox)
+                    if inter is not None:
+                        overlaps.append((inter, lbox))
+                if overlaps:
+                    plans.append((shard, sbox, overlaps))
+            if sp is not None:
+                sp.attrs.update(
+                    saved_shards=len(plans), local_boxes=len(local_boxes)
                 )
-                if set(local_boxes) == {full_box}:
-                    # fully-replicated template: one broadcasting device_put
+
+            def assemble() -> None:
+                # reshard/assemble: the filled assembly buffers become the
+                # restored leaf, on the thread that counted the last shard in
+                # (the read loop's)
+                with obs.span(
+                    "reshard/assemble", devices=target_shards, bytes=alloc_bytes
+                ):
+                    _assemble()
+
+            def _assemble() -> None:
+                if obj_out is not None and is_multi_device_jax_array(obj_out):
+                    import jax
+
+                    from .array import transfer_gate
+
+                    if target_dtype != dtype:
+                        for box in list(buffers):
+                            buffers[box] = buffers[box].astype(target_dtype)
+                    full_box = make_box(
+                        (0,) * len(obj_out.shape), tuple(obj_out.shape)
+                    )
+                    if set(local_boxes) == {full_box}:
+                        # fully-replicated template: one broadcasting device_put
+                        with transfer_gate() as pending:
+                            with obs.span(
+                                "h2d/put", bytes=buffers[full_box].nbytes
+                            ):
+                                out = jax.device_put(buffers[full_box], sharding)
+                            pending.append(out)
+                        # fut.set BEFORE donation: a donated template must
+                        # always imply a replacement reachable through the
+                        # Future (1x-restore; see donate_template)
+                        fut.set(out)
+                        donate_template(obj_out)
+                        return
+                    arrays = []
                     with transfer_gate() as pending:
-                        out = jax.device_put(buffers[full_box], sharding)
-                        pending.append(out)
-                    # fut.set BEFORE donation: a donated template must
-                    # always imply a replacement reachable through the
-                    # Future (1x-restore; see donate_template)
+                        for box, devs in local_boxes.items():
+                            for dev in devs:
+                                with obs.span(
+                                    "h2d/put",
+                                    bytes=buffers[box].nbytes,
+                                    device=dev.id,
+                                ):
+                                    arrays.append(
+                                        jax.device_put(buffers[box], dev)
+                                    )
+                        pending.extend(arrays)
+                    out = jax.make_array_from_single_device_arrays(
+                        tuple(obj_out.shape), sharding, arrays
+                    )
                     fut.set(out)
                     donate_template(obj_out)
-                    return
-                arrays = []
-                with transfer_gate() as pending:
-                    for box, devs in local_boxes.items():
-                        for dev in devs:
-                            arrays.append(jax.device_put(buffers[box], dev))
-                    pending.extend(arrays)
-                out = jax.make_array_from_single_device_arrays(
-                    tuple(obj_out.shape), sharding, arrays
-                )
-                fut.set(out)
-                donate_template(obj_out)
-            else:
-                (buf,) = buffers.values()
-                result = materialize_into_template(buf, obj_out)
-                fut.set(result)
-                if result is not obj_out:
-                    donate_template(obj_out)
+                else:
+                    (buf,) = buffers.values()
+                    result = materialize_into_template(buf, obj_out)
+                    fut.set(result)
+                    if result is not obj_out:
+                        donate_template(obj_out)
 
-        if not plans:  # degenerate: nothing to read (e.g. zero-size array)
-            assemble()
-            return [], fut
+            if not plans:  # degenerate: nothing to read (e.g. zero-size array)
+                assemble()
+                return [], fut
 
-        countdown = _Countdown(n=len(plans), on_zero=assemble)
-        read_reqs: List[ReadReq] = []
-        for shard, sbox, overlaps in plans:
-            expected_crc: Optional[int] = None
-            # Minimal fetch: if every overlap is a dim-0 slab of the saved
-            # blob, fetch just the covering row range.
-            if all(is_dim0_slab(ov, sbox) for ov, _ in overlaps) and sbox[1]:
-                r0 = min(ov[0][0] for ov, _ in overlaps) - sbox[0][0]
-                r1 = max(ov[0][0] + ov[1][0] for ov, _ in overlaps) - sbox[0][0]
-                row_bytes = (box_nelems(sbox) // max(1, sbox[1][0])) * itemsize
-                base = shard.byte_range[0] if shard.byte_range else 0
-                byte_range: Optional[List[int]] = [
-                    base + r0 * row_bytes,
-                    base + r1 * row_bytes,
-                ]
-                read_offsets = list(sbox[0])
-                read_offsets[0] += r0
-                read_sizes = list(sbox[1])
-                read_sizes[0] = r1 - r0
-                read_box = make_box(read_offsets, read_sizes)
-                if r0 == 0 and r1 == sbox[1][0]:
-                    # the covering row range IS the whole shard payload:
-                    # its recorded checksum applies
+            countdown = _Countdown(n=len(plans), on_zero=assemble)
+            read_reqs: List[ReadReq] = []
+            for shard, sbox, overlaps in plans:
+                expected_crc: Optional[int] = None
+                # Minimal fetch: if every overlap is a dim-0 slab of the saved
+                # blob, fetch just the covering row range.
+                if all(is_dim0_slab(ov, sbox) for ov, _ in overlaps) and sbox[1]:
+                    r0 = min(ov[0][0] for ov, _ in overlaps) - sbox[0][0]
+                    r1 = max(ov[0][0] + ov[1][0] for ov, _ in overlaps) - sbox[0][0]
+                    row_bytes = (box_nelems(sbox) // max(1, sbox[1][0])) * itemsize
+                    base = shard.byte_range[0] if shard.byte_range else 0
+                    byte_range: Optional[List[int]] = [
+                        base + r0 * row_bytes,
+                        base + r1 * row_bytes,
+                    ]
+                    read_offsets = list(sbox[0])
+                    read_offsets[0] += r0
+                    read_sizes = list(sbox[1])
+                    read_sizes[0] = r1 - r0
+                    read_box = make_box(read_offsets, read_sizes)
+                    if r0 == 0 and r1 == sbox[1][0]:
+                        # the covering row range IS the whole shard payload:
+                        # its recorded checksum applies
+                        expected_crc = shard.crc32
+                else:
+                    byte_range = list(shard.byte_range) if shard.byte_range else None
+                    read_box = sbox
+                    # this branch reads the WHOLE shard payload: its recorded
+                    # checksum applies (partial row-range reads above don't)
                     expected_crc = shard.crc32
-            else:
-                byte_range = list(shard.byte_range) if shard.byte_range else None
-                read_box = sbox
-                # this branch reads the WHOLE shard payload: its recorded
-                # checksum applies (partial row-range reads above don't)
-                expected_crc = shard.crc32
-            read_reqs.extend(
-                _emit_shard_reads(
-                    shard.location,
-                    read_box,
-                    byte_range,
-                    expected_crc,
-                    entry.dtype,
-                    itemsize,
-                    overlaps,
-                    buffers,
-                    countdown,
-                    buffer_size_limit_bytes,
+                read_reqs.extend(
+                    _emit_shard_reads(
+                        shard.location,
+                        read_box,
+                        byte_range,
+                        expected_crc,
+                        entry.dtype,
+                        itemsize,
+                        overlaps,
+                        buffers,
+                        countdown,
+                        buffer_size_limit_bytes,
+                    )
                 )
-            )
-        return read_reqs, fut
+            if sp is not None:
+                sp.attrs["read_reqs"] = len(read_reqs)
+            return read_reqs, fut
 
 
 def _emit_shard_reads(
@@ -518,13 +549,20 @@ class _ShardConsumer(BufferConsumer):
         src = array_from_buffer(buf, self.dtype, self.read_box[1])
 
         def scatter() -> None:
-            for inter, lbox in self.overlaps:
-                s_sl = relative_slices(inter, self.read_box)
-                d_sl = relative_slices(inter, lbox)
-                # 0-d boxes: arr[()] yields a scalar, not a view — use [...]
-                s = src[s_sl] if s_sl else src[...]
-                d = self.buffers[lbox][d_sl] if d_sl else self.buffers[lbox][...]
-                fast_copyto(d, s)
+            # reshard/scatter: the copies alone, inside the worker's
+            # consume/materialize (whose queue_ns is the wait for the worker)
+            with obs.span("reshard/scatter", bytes=src.nbytes):
+                for inter, lbox in self.overlaps:
+                    s_sl = relative_slices(inter, self.read_box)
+                    d_sl = relative_slices(inter, lbox)
+                    # 0-d boxes: arr[()] yields a scalar, not a view — use [...]
+                    s = src[s_sl] if s_sl else src[...]
+                    d = (
+                        self.buffers[lbox][d_sl]
+                        if d_sl
+                        else self.buffers[lbox][...]
+                    )
+                    fast_copyto(d, s)
 
         if executor is not None:
             await obs.run_in_executor(

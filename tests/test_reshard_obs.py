@@ -1,7 +1,9 @@
 """What the sharded restore path records: ``reshard/plan``, ``reshard/scatter``
 and ``reshard/assemble`` spans, one ``h2d/put`` span a device of a sharded
 leaf, and the counter ``reshard.host_alloc_bytes`` (the local boxes' bytes)
-beside ``bytes_read`` (what the sink gave)."""
+beside ``bytes_read`` (what the sink gave); and, for a leaf on the direct
+path, ``reshard/direct`` in place of ``reshard/scatter`` and the counter
+``reshard.direct_bytes`` in place of ``reshard.host_alloc_bytes``."""
 
 import jax
 import numpy as np
@@ -11,7 +13,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from torchsnapshot_tpu import PyTreeState, Snapshot, knobs, obs
 from torchsnapshot_tpu.obs import tracer
 
-COUNTERS = (obs.RESHARD_HOST_ALLOC_BYTES, obs.BYTES_READ)
+COUNTERS = (obs.RESHARD_HOST_ALLOC_BYTES, obs.RESHARD_DIRECT_BYTES, obs.BYTES_READ)
 
 
 def _mesh(dp, tp):
@@ -38,8 +40,7 @@ def _counters():
     return {name: snap.get(name, 0) for name in COUNTERS}
 
 
-@pytest.fixture
-def restored(tmp_path):
+def _restore_traced(tmp_path, device_unpack):
     """A state saved under 2x2 and restored under 1x4 with tracing on: the
     templates, the restored leaves, the spans and what the counters gained."""
     saved = _state(_mesh(2, 2), 1)
@@ -47,12 +48,22 @@ def restored(tmp_path):
     templates = _state(_mesh(1, 4), 2)
     app = {"ts": PyTreeState(dict(templates))}
     before = _counters()
-    with knobs.override_trace(True):
+    with knobs.override_trace(True), knobs.override_device_unpack(device_unpack):
         tracer.get_tracer().reset()
         Snapshot(str(tmp_path / "snap")).restore(app)
         spans = tracer.get_tracer().spans()
     gained = {name: after - before[name] for name, after in _counters().items()}
     return saved, templates, app["ts"].tree, spans, gained
+
+
+@pytest.fixture
+def restored(tmp_path):
+    return _restore_traced(tmp_path, "auto")  # on CPU: the host path
+
+
+@pytest.fixture
+def restored_direct(tmp_path):
+    return _restore_traced(tmp_path, True)
 
 
 def test_a_sharded_leafs_device_puts_record_h2d_put_spans(restored):
@@ -89,6 +100,56 @@ def test_a_sharded_leafs_device_puts_record_h2d_put_spans(restored):
     assert all(s.parent_id in hops and "queue_ns" in hops[s.parent_id].attrs for s in scatters)
 
 
+def test_a_direct_leafs_pieces_record_reshard_direct_spans(restored_direct):
+    saved, templates, tree, spans, gained = restored_direct
+    for name, leaf in tree.items():
+        assert np.array_equal(np.asarray(leaf), np.asarray(saved[name])), name
+        assert leaf.sharding.is_equivalent_to(templates[name].sharding, leaf.ndim)
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s.name, []).append(s)
+    # no copy happens, so none is recorded; a leaf is still planned and
+    # assembled once
+    assert "reshard/scatter" not in by_name
+    assembles = by_name["reshard/assemble"]
+    assert len(assembles) == len(by_name["reshard/plan"]) == 3
+    assert sum(s.attrs["bytes"] for s in assembles) == sum(x.nbytes for x in tree.values())
+    # one reshard/direct a read piece (two saved shards for each leaf the tp
+    # axis cuts, one for the replicated one), on a consume worker, inside
+    # the hop that carries the wait for the worker
+    directs = by_name["reshard/direct"]
+    assert len(directs) == 5
+    hops = {s.span_id: s for s in by_name["consume/materialize"]}
+    assert all(d.parent_id in hops and "queue_ns" in hops[d.parent_id].attrs for d in directs)
+    assert all(d.thread_name.startswith("tsnp-consume") for d in directs)
+    # each mapped piece is populated in one call before its bytes are used,
+    # in the same hop
+    populates = by_name["reshard/populate"]
+    assert sorted(p.parent_id for p in populates) == sorted(d.parent_id for d in directs)
+    assert sum(p.attrs["bytes"] for p in populates) == sum(x.nbytes for x in tree.values())
+    # every put is a child of its piece's span, names its device, and the
+    # puts of a piece sum to the bytes it sent over the link
+    puts = by_name["h2d/put"]
+    inside = {d.span_id: d for d in directs}
+    assert all(p.parent_id in inside and "device" in p.attrs for p in puts)
+    for d in directs:
+        mine = [p for p in puts if p.parent_id == d.span_id]
+        assert len(mine) == d.attrs["devices"]
+        assert sum(p.attrs["bytes"] for p in mine) == d.attrs["bytes"]
+    # a column leaf's saved shard goes whole to both devices that share it
+    # (twice the leaf over the link) and is cut there; a row leaf's and the
+    # replicated leaf's boxes are sent as they lie (once a device)
+    cut = [d for d in directs if d.attrs["cut"]]
+    assert len(cut) == 2 and sum(d.attrs["bytes"] for d in cut) == 2 * saved["cols"].nbytes
+    plain = [d for d in directs if not d.attrs["cut"]]
+    assert sum(d.attrs["bytes"] for d in plain) == saved["rows"].nbytes + 4 * saved["norm"].nbytes
+    assert sorted(p.attrs["device"] for p in puts) == sorted(3 * [d.id for d in jax.devices()[:4]])
+    state_bytes = sum(x.nbytes for x in tree.values())
+    assert gained[obs.RESHARD_DIRECT_BYTES] == state_bytes
+    assert gained[obs.RESHARD_HOST_ALLOC_BYTES] == 0
+    assert gained[obs.BYTES_READ] == state_bytes
+
+
 def test_host_alloc_bytes_is_the_sum_of_the_local_boxes(restored):
     _, templates, tree, _, gained = restored
     local_boxes = 0
@@ -97,6 +158,7 @@ def test_host_alloc_bytes_is_the_sum_of_the_local_boxes(restored):
         local_boxes += sum(shards.values())  # one buffer a unique box
     state_bytes = sum(x.nbytes for x in tree.values())
     assert gained[obs.RESHARD_HOST_ALLOC_BYTES] == local_boxes == state_bytes
+    assert gained[obs.RESHARD_DIRECT_BYTES] == 0
     # every saved byte comes from the sink once: a saved shard whose halves
     # go to two devices is still one read
     assert gained[obs.BYTES_READ] == state_bytes

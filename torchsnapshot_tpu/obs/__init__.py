@@ -93,7 +93,9 @@ from .metrics import (  # noqa: F401
     LIVENESS_HEARTBEATS,
     PROMOTION_LAG_S,
     REGISTRY,
+    RESHARD_DIRECT_BYTES,
     RESHARD_HOST_ALLOC_BYTES,
+    RESHARD_POPULATE_REFUSED,
     RESILIENCE_ABORTS,
     RESILIENCE_BACKOFF_DELAY_S,
     RESILIENCE_BREAKER_TRIPS,

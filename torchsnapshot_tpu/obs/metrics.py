@@ -298,6 +298,14 @@ TAKEOVER_PROMOTER_DEAD_PEERS = "takeover.promoter_dead_peers"
 # buffers made, one per unique local box of a leaf (the plan's other
 # counts are attrs of its reshard/plan span).
 RESHARD_HOST_ALLOC_BYTES = "reshard.host_alloc_bytes"
+# ... and bytes of local boxes restored WITHOUT one: put on their devices
+# from the read piece as it lies, cut there where needed.  The two sum to
+# the local boxes' bytes of every sharded leaf restored.
+RESHARD_DIRECT_BYTES = "reshard.direct_bytes"
+# Mapped read pieces whose populate (one mlock + munlock over the piece,
+# preparers/sharded.py::_populate) the kernel refused: their pages are
+# left to first touches, as before the populate existed.
+RESHARD_POPULATE_REFUSED = "reshard.populate_refused"
 # Exception hygiene (tools/lint exception-hygiene pass): every
 # deliberate broad-except swallow on a fallback path increments this
 # via obs.swallowed_exception, so "how often are we falling back" is a

@@ -271,6 +271,42 @@ def tile_update_device(acc, tile_np: np.ndarray, off: int):
     return out
 
 
+@functools.lru_cache(maxsize=256)
+def _jitted_cut(shape, dtype_str, sizes):
+    """One program per (wide shape, dtype, box sizes); the box's start is
+    a RUNTIME argument, so the halves (quarters, ...) of one saved shard
+    share an executable: a resharding restore of a transformer compiles
+    one per distinct column-sharded shape."""
+    import jax
+    from jax import lax
+
+    def cut(wide, *starts):
+        return lax.dynamic_slice(wide, starts, sizes)
+
+    return jax.jit(cut)
+
+
+def cut_box_on_device(wide, starts, sizes):
+    """Cut the box ``(starts, sizes)`` out of the device array ``wide`` on
+    its own device: the resharding restore's carving program
+    (preparers/sharded.py), for a target shard that is a strided region
+    of the saved shard it lies in.  A slice moves words: bitwise.  The
+    caller owns ``wide`` and deletes it once the cut is done."""
+    shape = tuple(int(d) for d in wide.shape)
+    starts = tuple(int(s) for s in starts)
+    sizes = tuple(int(s) for s in sizes)
+    # dynamic_slice CLAMPS an out-of-bounds start instead of raising: a
+    # corrupt plan must fail here, not deliver a shifted region
+    if len(starts) != len(shape) or any(
+        st < 0 or st + sz > dim for st, sz, dim in zip(starts, sizes, shape)
+    ):
+        raise ValueError(f"box {starts}+{sizes} outside array of {shape}")
+    fn = _jitted_cut(shape, str(np.dtype(wide.dtype)), sizes)
+    out = fn(wide, *(np.int32(s) for s in starts))
+    _count("unpack")  # after dispatch succeeded — fallbacks must not count
+    return out
+
+
 def unpack_slab_to_device(buf, members, out_dtypes, device) -> List[Any]:
     """ONE H2D transfer + per-member compiled slice/bitcast programs turn
     a host slab into all of its member device arrays — the restore-side

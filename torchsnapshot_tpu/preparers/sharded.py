@@ -23,12 +23,23 @@ blob, only that byte range is fetched.  The assembled per-device buffers
 become the restored array via ``jax.make_array_from_single_device_arrays``
 — resharding across world sizes/meshes (elasticity) is this same code path
 with a different template sharding.
+
+A leaf whose every local box lies whole inside ONE read piece takes no
+host assembly buffer at all (``_DirectLeaf``): the consume worker hands
+the piece's bytes, as they lie in the mapped file, to ``jax.device_put``
+for each device that holds the box, and where the box is not a contiguous
+range of the piece (a column range) cuts it out on that device
+(``ops.device_pack.cut_box_on_device``).  Same plan, same reads, same
+countdown, same assemble step: only where a piece's bytes go differs.
 """
 
 from __future__ import annotations
 
+import functools
+import logging
+import threading
 from concurrent.futures import Executor
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -60,6 +71,8 @@ from .overlap import (
     make_box,
     relative_slices,
 )
+
+logger = logging.getLogger(__name__)
 
 
 def is_multi_device_jax_array(obj: Any) -> bool:
@@ -274,7 +287,10 @@ class ShardedArrayIOPreparer:
             for s in entry.shards:
                 saved.setdefault(make_box(s.offsets, s.sizes), s)
 
-            if obj_out is not None and is_multi_device_jax_array(obj_out):
+            sharded_template = obj_out is not None and is_multi_device_jax_array(
+                obj_out
+            )
+            if sharded_template:
                 sharding = obj_out.sharding
                 local_boxes: Dict[Box, List[Any]] = {}
                 idx_map = sharding.devices_indices_map(tuple(obj_out.shape))
@@ -288,15 +304,11 @@ class ShardedArrayIOPreparer:
                 local_boxes = {make_box((0,) * len(shape), shape): [None]}
                 target_dtype = dtype
 
-            buffers: Dict[Box, np.ndarray] = {
-                box: np.empty(box[1], dtype=dtype) for box in local_boxes
-            }
-            alloc_bytes = sum(b.nbytes for b in buffers.values())
+            box_bytes = sum(box_nelems(b) for b in local_boxes) * itemsize
             target_shards = sum(len(devs) for devs in local_boxes.values())
-            obs.counter(obs.RESHARD_HOST_ALLOC_BYTES).inc(alloc_bytes)
 
-            # saved box -> [(overlap, local_box), ...]
-            plans: List[Tuple[Shard, Box, List[Tuple[Box, Box]]]] = []
+            # one fetch a saved box that overlaps a local one
+            fetches: List[_Fetch] = []
             for sbox, shard in saved.items():
                 overlaps = []
                 for lbox in local_boxes:
@@ -304,23 +316,56 @@ class ShardedArrayIOPreparer:
                     if inter is not None:
                         overlaps.append((inter, lbox))
                 if overlaps:
-                    plans.append((shard, sbox, overlaps))
+                    fetches.append(_plan_fetch(shard, sbox, overlaps, itemsize))
+
+            # filled by the host path only (at once, or when the direct
+            # path of this leaf falls back)
+            buffers: Dict[Box, np.ndarray] = {}
+            direct: Optional[_DirectLeaf] = None
+            if sharded_template and _direct_applies(
+                obj_out, shape, dtype, local_boxes, fetches,
+                buffer_size_limit_bytes,
+            ):
+                direct = _DirectLeaf(local_boxes, dtype, buffers)
+            else:
+                _make_buffers(buffers, local_boxes, dtype)
             if sp is not None:
                 sp.attrs.update(
-                    saved_shards=len(plans), local_boxes=len(local_boxes)
+                    saved_shards=len(fetches), local_boxes=len(local_boxes)
                 )
 
             def assemble() -> None:
-                # reshard/assemble: the filled assembly buffers become the
-                # restored leaf, on the thread that counted the last shard in
-                # (the read loop's)
+                # reshard/assemble: the filled assembly buffers, or the
+                # boxes already on their devices, become the restored leaf,
+                # on the thread that counted the last shard in (the read
+                # loop's)
                 with obs.span(
-                    "reshard/assemble", devices=target_shards, bytes=alloc_bytes
+                    "reshard/assemble", devices=target_shards, bytes=box_bytes
                 ):
-                    _assemble()
+                    if direct is not None and not direct.fell_back:
+                        _assemble_direct()
+                    else:
+                        _assemble()
+
+            def _assemble_direct() -> None:
+                import jax
+
+                out = jax.make_array_from_single_device_arrays(
+                    tuple(obj_out.shape),
+                    obj_out.sharding,
+                    [
+                        direct.placed[dev]
+                        for devs in local_boxes.values()
+                        for dev in devs
+                    ],
+                )
+                obs.counter(obs.RESHARD_DIRECT_BYTES).inc(box_bytes)
+                # fut.set BEFORE donation, as below
+                fut.set(out)
+                donate_template(obj_out)
 
             def _assemble() -> None:
-                if obj_out is not None and is_multi_device_jax_array(obj_out):
+                if sharded_template:
                     import jax
 
                     from .array import transfer_gate
@@ -370,52 +415,22 @@ class ShardedArrayIOPreparer:
                     if result is not obj_out:
                         donate_template(obj_out)
 
-            if not plans:  # degenerate: nothing to read (e.g. zero-size array)
+            if not fetches:  # degenerate: nothing to read (e.g. zero-size array)
                 assemble()
                 return [], fut
 
-            countdown = _Countdown(n=len(plans), on_zero=assemble)
+            countdown = _Countdown(n=len(fetches), on_zero=assemble)
             read_reqs: List[ReadReq] = []
-            for shard, sbox, overlaps in plans:
-                expected_crc: Optional[int] = None
-                # Minimal fetch: if every overlap is a dim-0 slab of the saved
-                # blob, fetch just the covering row range.
-                if all(is_dim0_slab(ov, sbox) for ov, _ in overlaps) and sbox[1]:
-                    r0 = min(ov[0][0] for ov, _ in overlaps) - sbox[0][0]
-                    r1 = max(ov[0][0] + ov[1][0] for ov, _ in overlaps) - sbox[0][0]
-                    row_bytes = (box_nelems(sbox) // max(1, sbox[1][0])) * itemsize
-                    base = shard.byte_range[0] if shard.byte_range else 0
-                    byte_range: Optional[List[int]] = [
-                        base + r0 * row_bytes,
-                        base + r1 * row_bytes,
-                    ]
-                    read_offsets = list(sbox[0])
-                    read_offsets[0] += r0
-                    read_sizes = list(sbox[1])
-                    read_sizes[0] = r1 - r0
-                    read_box = make_box(read_offsets, read_sizes)
-                    if r0 == 0 and r1 == sbox[1][0]:
-                        # the covering row range IS the whole shard payload:
-                        # its recorded checksum applies
-                        expected_crc = shard.crc32
-                else:
-                    byte_range = list(shard.byte_range) if shard.byte_range else None
-                    read_box = sbox
-                    # this branch reads the WHOLE shard payload: its recorded
-                    # checksum applies (partial row-range reads above don't)
-                    expected_crc = shard.crc32
+            for fetch in fetches:
                 read_reqs.extend(
                     _emit_shard_reads(
-                        shard.location,
-                        read_box,
-                        byte_range,
-                        expected_crc,
+                        fetch,
                         entry.dtype,
                         itemsize,
-                        overlaps,
                         buffers,
                         countdown,
                         buffer_size_limit_bytes,
+                        direct,
                     )
                 )
             if sp is not None:
@@ -423,17 +438,278 @@ class ShardedArrayIOPreparer:
             return read_reqs, fut
 
 
+class _Fetch(NamedTuple):
+    """One saved shard's read: the box fetched (the shard's, or the row
+    range of it that covers every overlap), where it lies in the stored
+    object, the checksum that applies to exactly those bytes, and the
+    (overlap, local box) pairs it feeds."""
+
+    location: str
+    read_box: Box
+    byte_range: Optional[List[int]]
+    expected_crc: Optional[int]
+    overlaps: List[Tuple[Box, Box]]
+
+
+def _plan_fetch(
+    shard: Shard, sbox: Box, overlaps: List[Tuple[Box, Box]], itemsize: int
+) -> _Fetch:
+    # Minimal fetch: if every overlap is a dim-0 slab of the saved
+    # blob, fetch just the covering row range.
+    if all(is_dim0_slab(ov, sbox) for ov, _ in overlaps) and sbox[1]:
+        r0 = min(ov[0][0] for ov, _ in overlaps) - sbox[0][0]
+        r1 = max(ov[0][0] + ov[1][0] for ov, _ in overlaps) - sbox[0][0]
+        row_bytes = (box_nelems(sbox) // max(1, sbox[1][0])) * itemsize
+        base = shard.byte_range[0] if shard.byte_range else 0
+        byte_range: Optional[List[int]] = [
+            base + r0 * row_bytes,
+            base + r1 * row_bytes,
+        ]
+        read_offsets = list(sbox[0])
+        read_offsets[0] += r0
+        read_sizes = list(sbox[1])
+        read_sizes[0] = r1 - r0
+        read_box = make_box(read_offsets, read_sizes)
+        # only where the covering row range IS the whole shard payload
+        # does its recorded checksum apply
+        whole = r0 == 0 and r1 == sbox[1][0]
+        expected_crc = shard.crc32 if whole else None
+    else:
+        byte_range = list(shard.byte_range) if shard.byte_range else None
+        read_box = sbox
+        # this branch reads the WHOLE shard payload: its recorded
+        # checksum applies (partial row-range reads above don't)
+        expected_crc = shard.crc32
+    return _Fetch(shard.location, read_box, byte_range, expected_crc, overlaps)
+
+
+def _make_buffers(
+    buffers: Dict[Box, np.ndarray], local_boxes: Dict[Box, List[Any]], dtype
+) -> None:
+    """One host assembly buffer a unique local box, counted as made."""
+    for box in local_boxes:
+        buffers[box] = np.empty(box[1], dtype=dtype)
+    obs.counter(obs.RESHARD_HOST_ALLOC_BYTES).inc(
+        sum(b.nbytes for b in buffers.values())
+    )
+
+
+def _is_tiled(read_box: Box, itemsize: int, budget: Optional[int]) -> bool:
+    """Whether a fetch is split into dim-0 row-range tiles: over budget,
+    and more than one row to split."""
+    rows = read_box[1][0] if read_box[1] else 0
+    return (
+        budget is not None
+        and box_nelems(read_box) * itemsize > budget
+        and rows > 1
+    )
+
+
+def _direct_applies(
+    obj_out: Any,
+    shape: Tuple[int, ...],
+    dtype: np.dtype,
+    local_boxes: Dict[Box, List[Any]],
+    fetches: List["_Fetch"],
+    budget: Optional[int],
+) -> bool:
+    """Whether a leaf with a multi-device ``jax.Array`` template is
+    restored without host assembly buffers, by what the plan and the
+    template show (all or nothing a leaf):
+
+    - the template has the saved shape and dtype and lies in device
+      memory (a cast, ``pinned_host``: the host path; so is a numpy, a
+      single-device or an absent template, which never comes here);
+    - every local box lies whole inside one fetch, and no fetch is tiled
+      (a tile is a row range; a column box never lies in one).  A local
+      box gathered from several saved shards runs the host path;
+    - ``knobs.device_unpack_enabled()``: the switch that already means
+      "carve restored bytes on the device, not on the host" (auto: off
+      on cpu, where a device is host memory).  Read last: auto imports
+      jax."""
+    if tuple(obj_out.shape) != shape or np.dtype(obj_out.dtype) != dtype:
+        return False
+    if getattr(obj_out.sharding, "memory_kind", None) not in (None, "device"):
+        return False
+    pairs = [pair for fetch in fetches for pair in fetch.overlaps]
+    if any(inter != lbox for inter, lbox in pairs) or sorted(
+        lbox for _, lbox in pairs
+    ) != sorted(local_boxes):
+        return False
+    if any(
+        _is_tiled(fetch.read_box, dtype.itemsize, budget) for fetch in fetches
+    ):
+        return False
+    return knobs.device_unpack_enabled()
+
+
+@functools.lru_cache(maxsize=1)
+def _libc():
+    import ctypes
+
+    return ctypes.CDLL(None, use_errno=True)
+
+
+def _populate(src: np.ndarray) -> None:
+    """Ask the kernel for the page-table entries of a MAPPED read piece in
+    one call, before a transfer or a copy touches its pages one by one.
+
+    ``mlock`` then ``munlock``: the populate every POSIX system has (the
+    pages stay mapped, nothing stays locked; ``MADV_POPULATE_READ`` is
+    Linux 5.14's name for it and gVisor has none).  On a sandboxed host a
+    first touch of a tmpfs mapping is one trap a 4 KiB page whoever's
+    thread takes it (0.5-1.4 GB/s over all threads, PERF.md section 5),
+    and it was the whole of a resharding restore's time there; populated in
+    one call the same pages are there at 5 GB/s a thread.  A refusal
+    (``RLIMIT_MEMLOCK``, no libc) leaves the pages to their first touches,
+    as before, and counts in ``reshard.populate_refused``: a host where the
+    populate never engages says so.  A piece on the heap was touched by the
+    read that made it."""
+    from ..io_types import is_mmap_backed
+
+    if not src.nbytes or not is_mmap_backed(src):
+        return
+    import ctypes
+    import mmap
+
+    with obs.span("reshard/populate", bytes=src.nbytes) as sp:
+        lo = src.ctypes.data - src.ctypes.data % mmap.PAGESIZE
+        span = ctypes.c_void_p(lo), ctypes.c_size_t(
+            src.ctypes.data + src.nbytes - lo
+        )
+        try:
+            libc = _libc()
+            refused = libc.mlock(*span) != 0
+            if not refused:
+                libc.munlock(*span)
+        except (AttributeError, OSError):  # no libc, or none with mlock
+            refused = True
+        if refused:
+            obs.counter(obs.RESHARD_POPULATE_REFUSED).inc()
+            if sp is not None:
+                sp.attrs["refused"] = True
+
+
+class _DirectLeaf:
+    """A leaf on the direct path: its boxes as they land on their devices,
+    and the way back to the host path.
+
+    The first exception on any piece of the leaf sends the WHOLE leaf
+    down the host path, once: the assembly buffers are made then, boxes
+    already on a device are read back into them, and every later piece
+    scatters on the host.  Counted once in ``exceptions.swallowed``."""
+
+    def __init__(
+        self,
+        local_boxes: Dict[Box, List[Any]],
+        dtype: np.dtype,
+        buffers: Dict[Box, np.ndarray],
+    ) -> None:
+        self.local_boxes = local_boxes
+        self.dtype = dtype
+        self.buffers = buffers  # the leaf's own dict: empty while direct
+        self.placed: Dict[Any, Any] = {}  # device -> its box, on it
+        self.fell_back = False
+        self._lock = threading.Lock()  # pieces land on several workers
+
+    def place(
+        self, src: np.ndarray, read_box: Box, overlaps: List[Tuple[Box, Box]]
+    ) -> bool:
+        """Put one read piece's boxes on their devices.  False: the leaf
+        is on the host path, and the caller scatters the piece."""
+        if self.fell_back:
+            return False
+        try:
+            placed = self._put_and_cut(src, read_box, overlaps)
+        except Exception as e:  # noqa: BLE001 — host path is always correct
+            with self._lock:
+                first = not self.fell_back
+                if first:
+                    self._fall_back()
+            if first:
+                logger.warning(
+                    "direct resharding restore failed; host fallback",
+                    exc_info=True,
+                )
+                obs.swallowed_exception("sharded.direct_restore", e)
+            return False
+        with self._lock:
+            if self.fell_back:  # another piece failed meanwhile
+                return False
+            self.placed.update(placed)
+        return True
+
+    def _put_and_cut(
+        self, src: np.ndarray, read_box: Box, overlaps: List[Tuple[Box, Box]]
+    ) -> Dict[Any, Any]:
+        import jax
+
+        from ..ops.device_pack import cut_box_on_device
+        from .array import transfer_gate
+
+        # (bytes to send, where the box starts in them or None, its sizes,
+        # the devices that hold it)
+        sends = []
+        for inter, lbox in overlaps:
+            devs = self.local_boxes[lbox]
+            if is_dim0_slab(inter, read_box):
+                # a row range of the piece (or all of it): contiguous
+                # bytes of the mapping, exactly the box
+                rows = relative_slices(inter, read_box)[:1]
+                sends.append((src[rows] if rows else src, None, None, devs))
+            else:
+                start = tuple(i - r for i, r in zip(inter[0], read_box[0]))
+                sends.append((src, start, inter[1], devs))
+        with obs.span(
+            "reshard/direct",
+            bytes=sum(view.nbytes * len(devs) for view, _, _, devs in sends),
+            devices=sum(len(devs) for _, _, _, devs in sends),
+            cut=any(start is not None for _, start, _, _ in sends),
+        ):
+            placed: Dict[Any, Any] = {}
+            wide = []
+            with transfer_gate() as pending:
+                for view, start, sizes, devs in sends:
+                    for dev in devs:
+                        with obs.span(
+                            "h2d/put", bytes=view.nbytes, device=dev.id
+                        ):
+                            arr = jax.device_put(view, dev)
+                        if start is not None:
+                            wide.append(arr)
+                            arr = cut_box_on_device(arr, start, sizes)
+                        placed[dev] = arr
+                pending.extend(placed.values())
+            # the worker waits for its piece before it takes the next:
+            # at most one piece's wide buffers a worker are on the devices
+            jax.block_until_ready(list(placed.values()))
+            for arr in wide:
+                arr.delete()
+        return placed
+
+    def _fall_back(self) -> None:
+        _make_buffers(self.buffers, self.local_boxes, self.dtype)
+        for box, devs in self.local_boxes.items():
+            landed = next(
+                (self.placed[d] for d in devs if d in self.placed), None
+            )
+            if landed is not None:
+                fast_copyto(self.buffers[box], np.asarray(landed))
+        for arr in self.placed.values():
+            arr.delete()
+        self.placed.clear()
+        # last: a piece that reads it true finds the buffers made
+        self.fell_back = True
+
+
 def _emit_shard_reads(
-    location: str,
-    read_box: Box,
-    byte_range: Optional[List[int]],
-    expected_crc: Optional[int],
+    fetch: _Fetch,
     dtype: str,
     itemsize: int,
-    overlaps: List[Tuple[Box, Box]],
     buffers: Dict[Box, np.ndarray],
     outer: _Countdown,
     budget: Optional[int],
+    direct: Optional["_DirectLeaf"] = None,
 ) -> List[ReadReq]:
     """Emit the read(s) for one saved-shard fetch, splitting an
     over-budget fetch into dim-0 row-range tiles.
@@ -455,13 +731,8 @@ def _emit_shard_reads(
     same VERIFY_ON_RESTORE gate as unbudgeted reads).  A single row
     larger than the budget reads row-at-a-time (the floor; element-level
     splits would tear rows across scatter boxes)."""
-    total_bytes = box_nelems(read_box) * itemsize
-    rows = read_box[1][0] if read_box[1] else 0
-    if (
-        budget is None
-        or total_bytes <= budget
-        or rows <= 1
-    ):
+    location, read_box, byte_range, expected_crc, overlaps = fetch
+    if not _is_tiled(read_box, itemsize, budget):
         return [
             ReadReq(
                 path=location,
@@ -472,6 +743,7 @@ def _emit_shard_reads(
                     overlaps=overlaps,
                     buffers=buffers,
                     countdown=outer,
+                    direct=direct,
                 ),
                 expected_crc32=expected_crc,
             )
@@ -479,7 +751,8 @@ def _emit_shard_reads(
 
     # one "element" per dim-0 row: the shared tile math splits the row
     # range exactly as it splits flat element ranges elsewhere
-    row_bytes = total_bytes // rows
+    rows = read_box[1][0]
+    row_bytes = box_nelems(read_box) * itemsize // rows
     base = byte_range[0] if byte_range else 0
     tiles = _plan_flat_tiles(0, rows, row_bytes, budget, base_byte=base)
     fold = _TileCrcFold(
@@ -521,7 +794,8 @@ def _emit_shard_reads(
 
 class _ShardConsumer(BufferConsumer):
     """Scatter one saved shard's bytes into every overlapping local region
-    (reference ShardedTensorBufferConsumer, sharded_tensor.py:301-333)."""
+    (reference ShardedTensorBufferConsumer, sharded_tensor.py:301-333), or,
+    for a leaf on the direct path, put them on the devices as they lie."""
 
     def __init__(
         self,
@@ -532,7 +806,9 @@ class _ShardConsumer(BufferConsumer):
         countdown: _Countdown,
         crc_fold: Optional[Any] = None,
         crc_key: int = 0,
+        direct: Optional[_DirectLeaf] = None,
     ) -> None:
+        self.direct = direct
         self.read_box = read_box
         self.dtype = dtype
         self.overlaps = overlaps
@@ -564,13 +840,20 @@ class _ShardConsumer(BufferConsumer):
                     )
                     fast_copyto(d, s)
 
+        def place() -> None:
+            _populate(src)
+            if self.direct is None or not self.direct.place(
+                src, self.read_box, self.overlaps
+            ):
+                scatter()
+
         if executor is not None:
             await obs.run_in_executor(
-                executor, scatter,
+                executor, place,
                 name="consume/materialize", nbytes=src.nbytes,
             )
         else:
-            scatter()
+            place()
         self.countdown.step()
 
     def get_consuming_cost_bytes(self) -> int:

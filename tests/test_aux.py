@@ -190,42 +190,6 @@ def test_pallas_auto_is_off_on_cpu():
         assert knobs.use_pallas_attention() is True
 
 
-def test_serialize_transfers_knob():
-    """auto = off on CPU, on for accelerators; 1/0 force.  The gate must
-    be a real lock only when the knob resolves on (restore consumers run
-    on an executor — see preparers/array.py:materialize_into_template)."""
-    import jax
-
-    from torchsnapshot_tpu import knobs
-    from torchsnapshot_tpu.preparers import array as array_prep
-    from torchsnapshot_tpu.preparers.array import transfer_gate
-
-    assert jax.default_backend() == "cpu"
-    with knobs.override_serialize_transfers("auto"):
-        assert knobs.serialize_transfers() is False
-    with knobs.override_serialize_transfers("1"):
-        assert knobs.serialize_transfers() is True
-        # gate holds the lock while the caller's transfers are pending
-        with transfer_gate() as pending:
-            assert array_prep._TRANSFER_LOCK.locked()
-            pending.append(jax.numpy.ones(4))
-        assert not array_prep._TRANSFER_LOCK.locked()
-        # restore still correct with the gate forced on
-        import numpy as np
-
-        from torchsnapshot_tpu.preparers.array import (
-            materialize_into_template,
-        )
-
-        tmpl = jax.numpy.zeros((8,), jax.numpy.float32)
-        out = materialize_into_template(
-            np.arange(8, dtype=np.float32), tmpl
-        )
-        assert np.array_equal(np.asarray(out), np.arange(8))
-    with knobs.override_serialize_transfers("0"):
-        assert knobs.serialize_transfers() is False
-
-
 def test_pallas_attention_auto_depends_on_backend_only(monkeypatch):
     """auto = on for the tpu backend, off everywhere else; nothing is
     probe-compiled to decide it."""
@@ -399,31 +363,11 @@ def test_cli_ls_verify_steps_delete(tmp_path, capsys):
     assert cli(["ls", snap_path]) == 1  # gone -> clean error, not traceback
 
 
-def test_serialize_transfers_auto_is_off_on_every_backend(monkeypatch):
-    """auto resolves from nothing but the knob: off on cpu and tpu alike,
-    whatever JAX_PLATFORMS names; only an explicit "1" gates."""
-    import jax
-
-    from torchsnapshot_tpu import knobs
-
-    for backend, platforms in (
-        ("cpu", "cpu"), ("tpu", "tpu"), ("tpu", ""), ("tpu", "proxy,tpu"),
-    ):
-        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
-        monkeypatch.setenv("JAX_PLATFORMS", platforms)
-        assert knobs.serialize_transfers() is False, (backend, platforms)
-        with knobs.override_serialize_transfers("1"):
-            assert knobs.serialize_transfers() is True
-        with knobs.override_serialize_transfers("0"):
-            assert knobs.serialize_transfers() is False
-
-
 def test_device_unpack_auto_depends_on_backend_only(monkeypatch):
     """auto device-unpack is on for every accelerator backend and off on
     cpu (a host-memory device gains nothing from the one-DMA unpack) —
-    whatever JAX_PLATFORMS names and whatever the transfer gate is set
-    to; explicit "1"/"0" still force it (the CPU test suite relies on
-    "1")."""
+    whatever JAX_PLATFORMS names; explicit "1"/"0" still force it (the
+    CPU test suite relies on "1")."""
     import jax
 
     from torchsnapshot_tpu import knobs
@@ -432,8 +376,6 @@ def test_device_unpack_auto_depends_on_backend_only(monkeypatch):
         monkeypatch.setenv("JAX_PLATFORMS", platforms)
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         assert knobs.device_unpack_enabled() is True, platforms
-        with knobs.override_serialize_transfers("1"):
-            assert knobs.device_unpack_enabled() is True
         with knobs.override_device_unpack("0"):
             assert knobs.device_unpack_enabled() is False
         monkeypatch.setattr(jax, "default_backend", lambda: "cpu")

@@ -235,6 +235,34 @@ def test_rss_profiler_publishes_peak_gauge():
     assert g.value == max(deltas)
 
 
+def test_goodput_rollup_shape_and_json_safety(tmp_path):
+    from torchsnapshot_tpu.obs import goodput
+
+    goodput.reset()
+    try:
+        Snapshot.take(
+            str(tmp_path / "snap"), {"m": StateDict(x=np.arange(2000.0))}
+        )
+        block = goodput.block()
+        for key in (
+            "takes",
+            "durable_commits",
+            "time_to_unblock_s",
+            "durability_lag_s",
+            "overhead_fraction",
+            "blocked_total_s",
+        ):
+            assert key in block, key
+        assert block["takes"] >= 1
+        assert block["durable_commits"] >= 1
+        assert block["time_to_unblock_s"] > 0
+        json.loads(json.dumps(block))  # flight records are strict JSON
+        gauges = obs.metrics_snapshot()["gauges"]
+        assert gauges[obs.GOODPUT_TIME_TO_UNBLOCK_S]["value"] > 0
+    finally:
+        goodput.reset()
+
+
 def test_take_restore_populate_registry(tmp_path):
     obs.reset_metrics()
     path = str(tmp_path / "snap")

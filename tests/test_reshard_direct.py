@@ -208,19 +208,6 @@ def test_verify_on_restore_still_catches_a_corrupted_shard(tmp_path, kind):
     np.testing.assert_array_equal(np.asarray(template), np.zeros_like(value))
 
 
-@pytest.mark.parametrize("serialize", [True, False])
-def test_the_transfer_gate_stays_around_the_puts(tmp_path, serialize):
-    value = _value("cols", seed=8)
-    Snapshot.take(str(tmp_path / "s"), {"app": StateDict(w=_put("cols", _mesh(2, 2), value))})
-    template = _put("cols", _mesh(1, 4), np.zeros_like(value))
-    dest = StateDict(w=template)
-    with knobs.override_device_unpack(True), knobs.override_serialize_transfers(serialize):
-        with _Gained() as g:
-            Snapshot(str(tmp_path / "s")).restore({"app": dest})
-    _assert_restored(dest["w"], value, template)
-    assert (g.host, g.direct, g.cuts) == (0, value.nbytes, 4)
-
-
 @pytest.mark.parametrize("fails_from", [0, 2], ids=["first_cut", "after_a_piece_landed"])
 def test_a_cut_that_raises_falls_back_to_the_host_path_once(tmp_path, monkeypatch, fails_from):
     """Both read pieces of the leaf fail (or the second, with the first

@@ -32,11 +32,10 @@ DEFAULT_BASELINE = os.path.join(
 
 
 def repo_summary(root: str = _REPO_ROOT) -> dict:
-    """One-call repo lint rollup for dashboards/BENCH records: finding
-    counts by disposition, the per-pass unbaselined breakdown, per-pass
-    wall time and the summary-cache hit/miss split — so the BENCH
-    "lint" block shows both the hygiene trajectory AND what sixteen
-    passes cost (and how much the cache buys back)."""
+    """One-call repo lint rollup: finding counts by disposition, the
+    per-pass unbaselined breakdown, per-pass wall time and the
+    summary-cache hit/miss split — both the hygiene trajectory AND what
+    sixteen passes cost (and how much the cache buys back)."""
     result = run_repo(
         root,
         ALL_PASSES,

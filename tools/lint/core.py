@@ -33,7 +33,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 SCAN_DIRS: Tuple[str, ...] = (
     "torchsnapshot_tpu", "tools", "benchmarks", "examples",
 )
-SCAN_FILES: Tuple[str, ...] = ("bench.py", "chip_smoke.py")
+SCAN_FILES: Tuple[str, ...] = ("chip_smoke.py",)
 _EXCLUDE_PARTS = {"__pycache__"}
 
 
@@ -384,7 +384,7 @@ class LintResult:
     unused_allows: List[Allow]       # stale entries (warned, not fatal)
     files_scanned: int = 0
     # per-pass wall time (seconds) and the summary-cache hit/miss
-    # counts — the BENCH "lint" block's cost attribution
+    # counts — repo_summary()'s cost attribution
     timings: Dict[str, float] = dataclasses.field(default_factory=dict)
     summary_cache: Dict[str, int] = dataclasses.field(
         default_factory=dict
@@ -520,8 +520,7 @@ def run_repo(
         # build the shared substrate (call graph, Tarjan SCCs, summary
         # extraction + bottom-up closures) under its own timing key —
         # lazily it would all be charged to whichever ProjectPass runs
-        # first, misdirecting the BENCH cost attribution this exists
-        # for
+        # first, misdirecting the cost attribution this exists for
         t0 = _time.monotonic()
         project.summaries
         timings["interproc-substrate"] = _time.monotonic() - t0

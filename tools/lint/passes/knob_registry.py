@@ -15,8 +15,8 @@ Flagged env-read forms (``os.environ.get``/``[...]``/``setdefault``/
   ``torchsnapshot_tpu/knobs.py``;
 - keys starting with ``TSNP_`` inside the ``torchsnapshot_tpu``
   package (library code must route legacy-prefixed tunables through a
-  knobs.py accessor too; repo tooling like bench.py may keep its own
-  ``TSNP_BENCH_*`` process controls).
+  knobs.py accessor too; repo tooling outside the package may keep its
+  own ``TSNP_*`` process controls).
 
 Non-literal keys can't be checked lexically; the prefix constant in
 knobs.py stays the one sanctioned concatenation site.

@@ -15,7 +15,7 @@ Rules:
 
 1. **No blocking calls in lock bodies** — inside ``with <lock>:`` /
    ``async with <lock>:`` (context expression whose trailing name
-   contains "lock"/"mutex", e.g. ``self._lock``, ``_TRANSFER_LOCK``),
+   contains "lock"/"mutex", e.g. ``self._lock``, ``_REGISTRY_LOCK``),
    direct calls to ``open``, storage-plugin I/O (``sync_read``/
    ``sync_write``/``sync_stat``/``sync_delete``), ``sleep``,
    blocking-KV ``kv_get``, or any Coordinator collective are findings.
@@ -66,7 +66,7 @@ def _lockish(expr: ast.expr) -> str:
         name = expr.attr
     else:
         return ""
-    # word-boundary match on underscore segments: `_TRANSFER_LOCK`,
+    # word-boundary match on underscore segments: `_REGISTRY_LOCK`,
     # `self._lock`, `big_lock` yes; `clock`, `blocked` no
     segments = name.lower().strip("_").split("_")
     return name if any(

@@ -106,7 +106,7 @@ _INIT_EXEMPT = frozenset({"__init__", "__post_init__"})
 def _lock_segments(name: str) -> bool:
     """lock_discipline's word-boundary rule on a bare string, plus the
     plural/guard forms lock REGISTRIES use (``_INDEX_LOCKS``,
-    ``_LOCKS_GUARD``): ``_TRANSFER_LOCK``/``self._lock``/``index_lock``
+    ``_LOCKS_GUARD``): ``_REGISTRY_LOCK``/``self._lock``/``index_lock``
     yes, ``clock``/``blocked`` no.  A dict OF locks is synchronization
     plumbing, not shared application state."""
     segs = name.lower().strip("_").split("_")

@@ -987,8 +987,7 @@ def _cmd_trace(args) -> int:
     """Traced read of a snapshot: materialize every entry with span
     tracing enabled and write the Perfetto trace_event JSON — open it at
     https://ui.perfetto.dev.  (Write-path traces come from running a
-    take with TORCHSNAPSHOT_TPU_TRACE=1 and calling obs.write_trace, as
-    bench.py does.)"""
+    take with TORCHSNAPSHOT_TPU_TRACE=1 and calling obs.write_trace.)"""
     from . import knobs, obs
     from .snapshot import Snapshot
 

@@ -23,7 +23,7 @@ per-step loop:
 Public surface: ``ContinuousCheckpointer`` (loop.py),
 ``recover_state`` (recover.py), ``ContinuousStore`` (store.py),
 ``summary_block`` (doctor/flight-record rollup).  Knobs: CONTINUOUS,
-CONTINUOUS_PROMOTE_EVERY_N, CONTINUOUS_GRACE_S (knobs.py).  See
+CONTINUOUS_GRACE_S (knobs.py).  See
 docs/preemption.md.
 """
 

@@ -119,8 +119,10 @@ class ContinuousCheckpointer:
     standby host).
     ``replica_count`` — peers to mirror each step to (topology-aware:
     different-slice peers preferred).
-    ``promote_every_n`` — None = the CONTINUOUS_PROMOTE_EVERY_N knob
-    (the SIGTERM grace window is knob-only: CONTINUOUS_GRACE_S).
+    ``promote_every_n`` — the in-RAM store promotes to the durable tier
+    every N steps (peer RAM absorbs every step, the durable tier every
+    Nth); 0 = never (peer-only; an explicit promote() still works).
+    (The SIGTERM grace window is knob-only: CONTINUOUS_GRACE_S.)
     ``retain_steps`` — completed steps each store keeps (older chunks
     and manifests are pruned; the HEAD step always survives).
     """
@@ -133,7 +135,7 @@ class ContinuousCheckpointer:
         replica_count: int = 1,
         peer_roots: Optional[Sequence[str]] = None,
         replica_roots: Optional[Sequence[str]] = None,
-        promote_every_n: Optional[int] = None,
+        promote_every_n: int = 16,
         chunk_size_bytes: Optional[int] = None,
         retain_steps: int = 2,
         topology: Any = None,
@@ -292,11 +294,7 @@ class ContinuousCheckpointer:
             self._worker.start()
 
     def promote_every_n(self) -> int:
-        return (
-            knobs.get_continuous_promote_every_n()
-            if self._promote_every_n is None
-            else max(0, int(self._promote_every_n))
-        )
+        return max(0, int(self._promote_every_n))
 
     # ----------------------------------------------------- target choice
 

@@ -18,7 +18,7 @@ usable the caller gets None — a cold start, exactly like
 
 The measured wall time of each successful recovery lands in the
 ``continuous.restore_s`` histogram — the recovery-time objective the
-chaos suite and the ``"continuous"`` bench block assert on.
+chaos suite asserts on.
 """
 
 from __future__ import annotations

@@ -39,6 +39,10 @@ from .serialization import deserialize_object, serialize_object
 
 logger = logging.getLogger(__name__)
 
+# Bytes per KV value (before base64) of a blob published over the KV:
+# fan-out redistribution and the KV transport count their parts with it.
+KV_BLOB_PART_BYTES = 4 * 1024 * 1024
+
 _DEFAULT_TIMEOUT_S = 600.0
 # abort-aware waits poll the poison key at this cadence: a peer's abort
 # surfaces within ~this interval instead of the full wait timeout
@@ -314,7 +318,7 @@ class Coordinator(abc.ABC):
         teardown, never fails the caller."""
 
     def kv_publish_blob(
-        self, prefix: str, data: Any, part_bytes: int = 4 * 1024 * 1024
+        self, prefix: str, data: Any, part_bytes: int = KV_BLOB_PART_BYTES
     ) -> int:
         """Publish one binary blob under EXPLICIT keys for asymmetric
         one-to-many redistribution (the fan-out restore's transport,

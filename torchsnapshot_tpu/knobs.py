@@ -2,7 +2,7 @@
 
 TPU-native rebuild of the reference's config surface (torchsnapshot/knobs.py:23-132):
 every constant is overridable via a ``TORCHSNAPSHOT_TPU_`` environment variable,
-and every knob has a context-manager override for tests.
+and the knobs that tests set have a context-manager override.
 """
 
 from __future__ import annotations
@@ -32,7 +32,6 @@ _FS_SYNC_DATA = "FS_SYNC_DATA"
 _DISABLE_EAGER_HOST_STAGING = "DISABLE_EAGER_HOST_STAGING"
 _PALLAS_ATTENTION = "PALLAS_ATTENTION"
 _REPLICATION_VERIFY = "REPLICATION_VERIFY"
-_SERIALIZE_TRANSFERS = "SERIALIZE_TRANSFERS"
 _WRITE_CHECKSUMS = "WRITE_CHECKSUMS"
 _VERIFY_ON_RESTORE = "VERIFY_ON_RESTORE"
 _DEVICE_UNPACK = "DEVICE_UNPACK"
@@ -41,10 +40,8 @@ _TRACE = "TRACE"
 _FAILPOINTS = "FAILPOINTS"
 _FAILPOINT_SEED = "FAILPOINT_SEED"
 _RETRY_MAX_ATTEMPTS = "RETRY_MAX_ATTEMPTS"
-_RETRY_PROGRESS_WINDOW_S = "RETRY_PROGRESS_WINDOW_S"
 _RETRY_BACKOFF_CAP_S = "RETRY_BACKOFF_CAP_S"
 _BREAKER_THRESHOLD = "BREAKER_THRESHOLD"
-_BREAKER_COOLDOWN_S = "BREAKER_COOLDOWN_S"
 _S3_ENDPOINT_URL = "S3_ENDPOINT_URL"
 _STRIPE_PART_SIZE_BYTES = "STRIPE_PART_SIZE_BYTES"
 _STRIPE_MIN_OBJECT_SIZE_BYTES = "STRIPE_MIN_OBJECT_SIZE_BYTES"
@@ -65,20 +62,14 @@ _TOPOLOGY = "TOPOLOGY"
 _TOPOLOGY_SLICE_ID = "TOPOLOGY_SLICE_ID"
 _TOPOLOGY_HOST_ID = "TOPOLOGY_HOST_ID"
 _FANOUT = "FANOUT"
-_FANOUT_PART_BYTES = "FANOUT_PART_BYTES"
 _FANOUT_TIMEOUT_S = "FANOUT_TIMEOUT_S"
 _TRANSPORT = "TRANSPORT"
 _TRANSPORT_PART_BYTES = "TRANSPORT_PART_BYTES"
-_TRANSPORT_TIMEOUT_S = "TRANSPORT_TIMEOUT_S"
 _CONTINUOUS = "CONTINUOUS"
-_CONTINUOUS_PROMOTE_EVERY_N = "CONTINUOUS_PROMOTE_EVERY_N"
 _CONTINUOUS_GRACE_S = "CONTINUOUS_GRACE_S"
 _FASTIO = "FASTIO"
 _FASTIO_DIRECT = "FASTIO_DIRECT"
-_FASTIO_BUFFER_POOL_BYTES = "FASTIO_BUFFER_POOL_BYTES"
-_PUBLISH_POLL_S = "PUBLISH_POLL_S"
 _PUBLISH_ANNOUNCE = "PUBLISH_ANNOUNCE"
-_PUBLISH_RETAIN = "PUBLISH_RETAIN"
 _LIVENESS_TIMEOUT_S = "LIVENESS_TIMEOUT_S"
 _LIVENESS_INTERVAL_S = "LIVENESS_INTERVAL_S"
 _TAKEOVER = "TAKEOVER"
@@ -138,10 +129,6 @@ _DEFAULTS = {
     #             intersected across ranks (the partitioner requires an
     #             identical replicated item list on every rank).
     _REPLICATION_VERIFY: "full",
-    # Serialize host↔device transfers through one in-process lock on the
-    # restore path.  "auto" = off on every backend (consumer threads'
-    # device_puts overlap freely); "1"/"0" force on/off.
-    _SERIALIZE_TRANSFERS: "auto",
     # Record zlib.crc32 content checksums in the manifest at staging
     # time (checked by Snapshot.verify(deep=True) — catches bit rot and
     # torn writes that byte sizes can't).  Runs in the staging thread
@@ -196,23 +183,19 @@ _DEFAULTS = {
     # Seed for the per-spec RNG streams probabilistic failpoints draw
     # from — the same spec + seed replays the same schedule.
     _FAILPOINT_SEED: 0,
-    # Shared retry policy (resilience/retry.py): per-op attempt cap and
-    # the collective-progress window — an op only gives up when the
-    # WHOLE pipeline has made no progress for the window (any completion
-    # anywhere refreshes the shared clock).  Values match the GCS
-    # plugin's historical constants; all retrying backends (fs, s3,
-    # gcs, memory) now share them.
+    # Shared retry policy (resilience/retry.py): per-op attempt cap.
+    # An op also gives up when the WHOLE pipeline has made no progress
+    # for the policy's window (SharedProgress(window_s=)).  The value
+    # matches the GCS plugin's historical constant; all retrying
+    # backends (fs, s3, gcs, memory) share it.
     _RETRY_MAX_ATTEMPTS: 6,
-    _RETRY_PROGRESS_WINDOW_S: 120.0,
     # Exponential backoff cap: delay = min(2**attempt, cap) * jitter.
     _RETRY_BACKOFF_CAP_S: 32.0,
     # Circuit breaker (resilience/breaker.py): consecutive COMPLETED
-    # failures (retries exhausted) before a backend trips open, and how
-    # long it stays open before a half-open probe is allowed.  Tripped
+    # failures (retries exhausted) before a backend trips open.  Tripped
     # writes fail fast (CircuitOpenError); tiered reads route straight
     # to the replica/durable fallback.
     _BREAKER_THRESHOLD: 5,
-    _BREAKER_COOLDOWN_S: 30.0,
     # Alternate S3 endpoint (minio, localstack, any S3-compatible
     # store) for the s3:// plugin.  None/"" = AWS default.  Env-based
     # so snapshot-level s3:// URLs resolve against the emulator too
@@ -335,9 +318,6 @@ _DEFAULTS = {
     # (and not already covered by a same-host shared cache); "1"/"0"
     # force.
     _FANOUT: "auto",
-    # Chunk size for the fan-out KV redistribution (bytes per KV value
-    # before base64 expansion).
-    _FANOUT_PART_BYTES: 4 * 1024 * 1024,
     # How long a sibling rank waits for its designated reader's
     # publication before falling back to a direct durable read — a dead
     # reader degrades the slice to direct GETs, never wedges it.
@@ -356,24 +336,14 @@ _DEFAULTS = {
     _TRANSPORT: "auto",
     # Device-array chunk size for the collective engine (payload bytes
     # per broadcast part, before lane padding).  Bounds per-part host
-    # staging the same way FANOUT_PART_BYTES bounds KV values.
+    # staging.
     _TRANSPORT_PART_BYTES: 8 * 1024 * 1024,
-    # How long a collective-transport participant waits on the
-    # control-plane gate (go/no-go key) for one transfer before
-    # treating the transfer as failed and degrading to KV.  Bounds
-    # every wait in the engine — the never-wedge contract.
-    _TRANSPORT_TIMEOUT_S: 30.0,
     # Continuous per-step checkpointing (continuous/): the fleet
     # kill-switch for already-constructed ContinuousCheckpointers.
     # 1 (default) = checkpointers run as constructed; 0 = step() becomes
     # a no-op everywhere — the escape hatch when replication itself is
     # suspected of perturbing a production run.
     _CONTINUOUS: 1,
-    # Promote the in-RAM continuous store to the durable tier every N
-    # steps (the write-back promotion cadence: peer RAM absorbs every
-    # step, the durable tier absorbs every Nth).  0 = never promote
-    # (peer-only; an explicit promote() still works).
-    _CONTINUOUS_PROMOTE_EVERY_N: 16,
     # Preemption grace window: how long the SIGTERM preemption-notice
     # hook (resilience/preemption.py) lets registered drains finish the
     # in-flight step replication before the process re-delivers the
@@ -400,19 +370,6 @@ _DEFAULTS = {
     # synchronous to media, which trades take latency for cache
     # hygiene — see docs/fastio.md for when that pays.
     _FASTIO_DIRECT: 0,
-    # Total preallocated aligned bounce-buffer pool for the engine
-    # (split into fixed 4MB buffers, min one).  Direct-path parts each
-    # hold one buffer for the duration of their copy+write; an
-    # exhausted pool backpressures (the part waits for a buffer, and
-    # storage.fastio.pool_waits counts the waits).
-    _FASTIO_BUFFER_POOL_BYTES: 64 * 1024 * 1024,
-    # Live weight publication (publish/): how often a Subscriber's
-    # watcher re-reads the durable publication HEAD when no KV announce
-    # arrives (the degraded-mode cadence — the KV announce is the fast
-    # path, this poll is the floor that keeps a fleet converging when
-    # the announce channel is down or the publisher died between record
-    # and announce).
-    _PUBLISH_POLL_S: 2.0,
     # Whether publishers announce new publication records over the
     # coordination KV (the low-latency wake-up for subscribers).  0
     # degrades every subscriber to pure durable polling — the escape
@@ -420,11 +377,6 @@ _DEFAULTS = {
     # durable record/marker is written either way; announce is never
     # load-bearing for correctness.
     _PUBLISH_ANNOUNCE: 1,
-    # Publication records each publisher retains (older records and any
-    # pool chunks only they referenced are pruned after a successful
-    # publish).  A subscriber holding an older step than the retention
-    # window simply takes a fuller delta against the newest record.
-    _PUBLISH_RETAIN: 4,
     # Rank liveness (resilience/liveness.py): a peer whose op-scoped
     # heartbeat stamp stops advancing for longer than this is declared
     # dead — death-aware waits raise RankDeadError(rank) instead of
@@ -547,12 +499,6 @@ def device_unpack_enabled() -> bool:
     return jax.default_backend() != "cpu"
 
 
-def serialize_transfers() -> bool:
-    # "auto" resolves off on every backend: only an explicit "1" gates
-    # (ROADMAP D2 decides the knob's fate by measurement)
-    return str(_get_raw(_SERIALIZE_TRANSFERS)).lower() in ("1", "true", "on")
-
-
 def is_trace_enabled() -> bool:
     return bool(_get_int(_TRACE))
 
@@ -569,20 +515,12 @@ def get_retry_max_attempts() -> int:
     return max(1, _get_int(_RETRY_MAX_ATTEMPTS))
 
 
-def get_retry_progress_window_s() -> float:
-    return float(_get_raw(_RETRY_PROGRESS_WINDOW_S))
-
-
 def get_retry_backoff_cap_s() -> float:
     return float(_get_raw(_RETRY_BACKOFF_CAP_S))
 
 
 def get_breaker_threshold() -> int:
     return max(1, _get_int(_BREAKER_THRESHOLD))
-
-
-def get_breaker_cooldown_s() -> float:
-    return float(_get_raw(_BREAKER_COOLDOWN_S))
 
 
 def get_s3_endpoint_url() -> Optional[str]:
@@ -728,10 +666,6 @@ def get_fanout() -> str:
     return "auto"
 
 
-def get_fanout_part_bytes() -> int:
-    return max(4096, _get_int(_FANOUT_PART_BYTES))
-
-
 def get_fanout_timeout_s() -> float:
     return max(0.0, float(_get_raw(_FANOUT_TIMEOUT_S)))
 
@@ -757,10 +691,6 @@ def get_transport_part_bytes() -> int:
     return max(4096, _get_int(_TRANSPORT_PART_BYTES))
 
 
-def get_transport_timeout_s() -> float:
-    return max(0.0, float(_get_raw(_TRANSPORT_TIMEOUT_S)))
-
-
 def continuous_enabled() -> bool:
     """Fleet kill-switch for continuous per-step checkpointing: when
     off, every ``ContinuousCheckpointer.step`` is a no-op (see
@@ -768,32 +698,14 @@ def continuous_enabled() -> bool:
     return bool(_get_int(_CONTINUOUS))
 
 
-def get_continuous_promote_every_n() -> int:
-    """Durable-promotion cadence in steps; 0 = never auto-promote."""
-    return max(0, _get_int(_CONTINUOUS_PROMOTE_EVERY_N))
-
-
 def get_continuous_grace_s() -> float:
     return max(0.0, float(_get_raw(_CONTINUOUS_GRACE_S)))
-
-
-def get_publish_poll_s() -> float:
-    """Subscriber durable-poll cadence in seconds (see _PUBLISH_POLL_S
-    above); also the announce-watch timeout, so one interval bounds how
-    stale a subscriber can run behind a dead announce channel."""
-    return max(0.01, float(_get_raw(_PUBLISH_POLL_S)))
 
 
 def publish_announce_enabled() -> bool:
     """Whether publishers announce records over the coordination KV
     (see _PUBLISH_ANNOUNCE above)."""
     return bool(_get_int(_PUBLISH_ANNOUNCE))
-
-
-def get_publish_retain() -> int:
-    """Publication records a publisher keeps (min 1 — the HEAD record
-    always survives)."""
-    return max(1, _get_int(_PUBLISH_RETAIN))
 
 
 def get_liveness_timeout_s() -> float:
@@ -825,10 +737,6 @@ def fastio_direct_enabled() -> bool:
     only where the engine's one-time probe finds O_DIRECT support,
     degrading to buffered + posix_fadvise(DONTNEED) otherwise."""
     return bool(_get_int(_FASTIO_DIRECT))
-
-
-def get_fastio_buffer_pool_bytes() -> int:
-    return max(4 * 1024 * 1024, _get_int(_FASTIO_BUFFER_POOL_BYTES))
 
 
 def restore_donation() -> str:
@@ -910,10 +818,6 @@ def override_per_rank_memory_budget_bytes(value: int):
 
 def override_allow_pickle_objects(value: bool):
     return _override(_ALLOW_PICKLE_OBJECTS, int(value))
-
-
-def override_serialize_transfers(value):
-    return _override(_SERIALIZE_TRANSFERS, value)
 
 
 def override_write_checksums(value: bool):
@@ -1000,18 +904,6 @@ def override_metrics_textfile(value):
     return _override(_METRICS_TEXTFILE, value or "")
 
 
-def override_tier_policy(value: str):
-    return _override(_TIER_POLICY, value)
-
-
-def override_tier_fast_keep_last_n(value: int):
-    return _override(_TIER_FAST_KEEP_LAST_N, value)
-
-
-def override_tier_verify_fast_reads(value: bool):
-    return _override(_TIER_VERIFY_FAST_READS, int(value))
-
-
 def override_mmap(value: bool):
     return _override(_MMAP, int(value))
 
@@ -1028,26 +920,8 @@ def override_topology(value):
     return _override(_TOPOLOGY, value or "auto")
 
 
-def override_topology_slice_id(value):
-    return _override(
-        _TOPOLOGY_SLICE_ID, "" if value is None else str(value)
-    )
-
-
-def override_topology_host_id(value):
-    return _override(_TOPOLOGY_HOST_ID, value or "")
-
-
 def override_fanout(value):
     return _override(_FANOUT, value)
-
-
-def override_fanout_part_bytes(value: int):
-    return _override(_FANOUT_PART_BYTES, value)
-
-
-def override_fanout_timeout_s(value: float):
-    return _override(_FANOUT_TIMEOUT_S, value)
 
 
 def override_transport(value):
@@ -1058,32 +932,12 @@ def override_transport_part_bytes(value: int):
     return _override(_TRANSPORT_PART_BYTES, value)
 
 
-def override_transport_timeout_s(value: float):
-    return _override(_TRANSPORT_TIMEOUT_S, value)
-
-
 def override_continuous(value: bool):
     return _override(_CONTINUOUS, int(value))
 
 
-def override_continuous_promote_every_n(value: int):
-    return _override(_CONTINUOUS_PROMOTE_EVERY_N, value)
-
-
-def override_continuous_grace_s(value: float):
-    return _override(_CONTINUOUS_GRACE_S, value)
-
-
-def override_publish_poll_s(value: float):
-    return _override(_PUBLISH_POLL_S, value)
-
-
 def override_publish_announce(value: bool):
     return _override(_PUBLISH_ANNOUNCE, value)
-
-
-def override_publish_retain(value: int):
-    return _override(_PUBLISH_RETAIN, value)
 
 
 def override_liveness_timeout_s(value: float):
@@ -1094,20 +948,12 @@ def override_liveness_interval_s(value: float):
     return _override(_LIVENESS_INTERVAL_S, value)
 
 
-def override_takeover(value: bool):
-    return _override(_TAKEOVER, int(value))
-
-
 def override_fastio(value: bool):
     return _override(_FASTIO, int(value))
 
 
 def override_fastio_direct(value: bool):
     return _override(_FASTIO_DIRECT, int(value))
-
-
-def override_fastio_buffer_pool_bytes(value: int):
-    return _override(_FASTIO_BUFFER_POOL_BYTES, value)
 
 
 def override_failpoint_seed(value: int):
@@ -1118,20 +964,12 @@ def override_retry_max_attempts(value: int):
     return _override(_RETRY_MAX_ATTEMPTS, value)
 
 
-def override_retry_progress_window_s(value: float):
-    return _override(_RETRY_PROGRESS_WINDOW_S, value)
-
-
 def override_retry_backoff_cap_s(value: float):
     return _override(_RETRY_BACKOFF_CAP_S, value)
 
 
 def override_breaker_threshold(value: int):
     return _override(_BREAKER_THRESHOLD, value)
-
-
-def override_breaker_cooldown_s(value: float):
-    return _override(_BREAKER_COOLDOWN_S, value)
 
 
 @contextlib.contextmanager

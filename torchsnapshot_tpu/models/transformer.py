@@ -1,8 +1,8 @@
 """Flagship benchmark model: a llama-style decoder-only transformer in flax.
 
 The reference validates its checkpointer against real workloads — a 1.9B
-FSDP transformer (benchmarks/fsdp/main.py:36-43) and DDP ResNet
-(benchmarks/ddp) — so this repo bundles an equivalent TPU-native workload:
+FSDP transformer (upstream torchsnapshot's benchmarks/fsdp/main.py:36-43)
+and DDP ResNet (its benchmarks/ddp) — so this repo bundles an equivalent TPU-native workload:
 float32 params (flax's default ``param_dtype``) computed in bf16, RMSNorm
 + rotary + SwiGLU blocks, `jax.checkpoint` remat on each block, and a
 pjit-able train step whose params/optimizer state carry real dp/tp

@@ -12,8 +12,8 @@ Three layers, one import surface:
 - **Export** (``write_trace``) — dump recorded spans as Chrome
   ``trace_event`` JSON for ui.perfetto.dev.  See ``perfetto.py``.
 
-Operator surface: ``python -m torchsnapshot_tpu stats|trace`` and the
-metrics block ``bench.py`` embeds in its BENCH records.
+Operator surface: ``python -m torchsnapshot_tpu stats|trace``; a
+program reads the same instruments through ``metrics_snapshot()``.
 """
 
 from __future__ import annotations

@@ -1,7 +1,6 @@
 """OpenMetrics (Prometheus text exposition) export of the registry.
 
-Scrape-based fleets don't read BENCH records — they run node_exporter
-with a textfile collector.  ``export_openmetrics()`` renders the live
+Scrape-based fleets run node_exporter with a textfile collector.  ``export_openmetrics()`` renders the live
 registry in the text exposition format (counters as ``_total``, gauges
 plus a ``_max`` high-water twin, histograms with cumulative
 ``_bucket{le=...}`` series), and ``write_metrics_textfile()`` dumps it

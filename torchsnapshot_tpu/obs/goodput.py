@@ -1,7 +1,7 @@
 """Goodput/SLO accounting: what checkpointing costs the training loop.
 
-Three numbers, tracked per process and exposed as always-on gauges,
-flight-record blocks (obs/aggregate.py) and BENCH blocks (bench.py):
+Three numbers, tracked per process and exposed as always-on gauges and
+flight-record blocks (obs/aggregate.py):
 
 - **time-to-unblock-train** (``goodput.time_to_unblock_s``) — how long
   the last take blocked its caller.  For ``async_take`` this is the
@@ -135,7 +135,7 @@ def durable_commit(path: str) -> Optional[float]:
 
 
 def block() -> Dict[str, Any]:
-    """JSON-safe goodput block for flight records and BENCH records."""
+    """JSON-safe goodput block for flight records."""
     with _lock:
         first = _first_begin_ts
         out: Dict[str, Any] = {

@@ -5,8 +5,8 @@ handful of times per storage op / pipeline transition, and each update
 is one lock-protected arithmetic op — cheap enough to leave running so
 benchmarks and the CLI can read real numbers without flipping any knob.
 
-Snapshot format (``snapshot()``) is plain JSON-safe dicts so ``bench.py``
-can embed it verbatim in BENCH records:
+Snapshot format (``snapshot()``) is plain JSON-safe dicts, so a program
+can embed it verbatim in its own records:
 
     {"counters": {name: int},
      "gauges": {name: {"value": float, "max": float}},
@@ -110,8 +110,8 @@ CACHE_EVICTIONS = "storage.cache.evictions"
 # leg vs the buffered (pwritev-batched) leg, part digests fused into
 # the same native pass that moved the bytes (each one is a full read
 # pass the old path paid separately), waits for an exhausted aligned
-# bounce-buffer pool (backpressure — size FASTIO_BUFFER_POOL_BYTES up
-# if this grows), and reads that applied the posix_fadvise(DONTNEED)
+# bounce-buffer pool (backpressure — storage/fastio.py POOL_BYTES is
+# too small if this grows), and reads that applied the posix_fadvise(DONTNEED)
 # fallback where O_DIRECT was unavailable.
 FASTIO_BYTES_WRITTEN = "storage.fastio.bytes_written"
 FASTIO_BYTES_READ = "storage.fastio.bytes_read"

@@ -153,8 +153,8 @@ def _jitted_unpack(dtype_str, shape, out_dtype_str):
     The monolithic form (every member sliced at a static offset inside a
     single jit) compiled superlinearly in member count on the TPU
     backend: 4 × 16MB members ≈ 14s, 16 members > 10min — measured on
-    hardware; it was the entire 151s restore gap vs orbax in the round-5
-    orbax_compare capture.  Per-signature kernels make compile cost
+    hardware; it was the entire 151s restore gap vs orbax in a round-5
+    head-to-head capture.  Per-signature kernels make compile cost
     O(distinct shapes) — a transformer's repeated layer shapes share one
     executable — and the runtime offset (``lax.dynamic_slice``) keeps
     byte positions out of the cache key, so evolving slab layouts reuse
@@ -247,12 +247,8 @@ def warm_tile_updates(acc_n, acc_dtype, tile_sigs, device) -> None:
 
 def tile_update_device(acc, tile_np: np.ndarray, off: int):
     """Write one host tile into a flat device accumulator, donating the
-    previous accumulator handle.  The tile H2D and the executable
-    dispatch ride the transfer gate like every other restore
-    transfer."""
+    previous accumulator handle."""
     import jax
-
-    from ..preparers.array import transfer_gate
 
     device = list(acc.sharding.device_set)[0]
     fn = _compiled_tile_update(
@@ -262,11 +258,9 @@ def tile_update_device(acc, tile_np: np.ndarray, off: int):
         str(np.dtype(tile_np.dtype)),
         device,
     )
-    with transfer_gate() as pending:
-        with obs.span("h2d/put", bytes=tile_np.nbytes):
-            tile = jax.device_put(tile_np, device)
-        pending.append(tile)
-        out = fn(acc, tile, np.int32(off))
+    with obs.span("h2d/put", bytes=tile_np.nbytes):
+        tile = jax.device_put(tile_np, device)
+    out = fn(acc, tile, np.int32(off))
     _count("tile_update")
     return out
 
@@ -321,8 +315,6 @@ def unpack_slab_to_device(buf, members, out_dtypes, device) -> List[Any]:
     """
     import jax
 
-    from ..preparers.array import transfer_gate
-
     u8 = np.frombuffer(buf, np.uint8)
     if u8.nbytes > np.iinfo(np.int32).max:
         # dynamic_slice offsets ride int32; slabs are budget/threshold
@@ -356,30 +348,14 @@ def unpack_slab_to_device(buf, members, out_dtypes, device) -> List[Any]:
         )
         for (_, dtype_str, shape), out_dt in zip(members, out_dtypes)
     ]
-    # the slab H2D rides the same gate as every other restore transfer.
-    # When the gate is active the per-member programs (compiled lazily
-    # on this executor thread at first use) run inside it too, after the
-    # slab DMA has drained, so "serialized" means exactly one transfer
-    # or compile in flight.
-    from .. import knobs
-
-    gated = knobs.serialize_transfers()
-
-    def dispatch(slab):
-        with obs.span("unpack/dispatch", members=len(members)):
-            return [
-                fn(slab, np.int32(off // word_bytes))
-                for fn, (off, _, _) in zip(fns, members)
-            ]
-
-    with transfer_gate(gated) as pending:
-        with obs.span("h2d/put", bytes=u8.nbytes):
-            slab = jax.device_put(u8.view(_word(word_bytes)), device)
-        if gated:
-            jax.block_until_ready([slab])
-            out = dispatch(slab)
-    if not gated:
-        # compile/dispatch overlap the DMA freely
-        out = dispatch(slab)
+    with obs.span("h2d/put", bytes=u8.nbytes):
+        slab = jax.device_put(u8.view(_word(word_bytes)), device)
+    # the per-member programs (compiled lazily on this executor thread
+    # at first use) compile and dispatch while the slab's DMA runs
+    with obs.span("unpack/dispatch", members=len(members)):
+        out = [
+            fn(slab, np.int32(off // word_bytes))
+            for fn, (off, _, _) in zip(fns, members)
+        ]
     _count("unpack")  # after dispatch succeeded — fallbacks must not count
     return out

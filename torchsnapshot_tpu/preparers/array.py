@@ -26,7 +26,6 @@ io_preparers/chunked_tensor.py:36-128.  TPU-native differences:
 
 from __future__ import annotations
 
-import contextlib
 import functools
 import threading
 from concurrent.futures import Executor
@@ -51,37 +50,6 @@ from ..serialization import (
 )
 
 logger = logging.getLogger(__name__)
-
-# gates restore-path H2D transfers when knobs.serialize_transfers() is on
-_TRANSFER_LOCK = threading.Lock()
-
-
-@contextlib.contextmanager
-def transfer_gate(gated: "bool | None" = None):
-    """Serialize H2D transfers across consumer threads when
-    ``knobs.serialize_transfers()`` resolves on (see knobs.py).
-
-    Yields a list the caller appends in-flight arrays to; when gating is
-    active the gate blocks on them BEFORE releasing the lock —
-    ``device_put`` returns before the DMA completes, so releasing at
-    dispatch would let other threads' transfers overlap anyway.
-
-    ``gated`` lets a caller that already read the knob pin the decision
-    (a caller branching on its own read while the gate re-reads would
-    race a concurrent override into compiling outside the lock)."""
-    pending: List[Any] = []
-    if gated is None:
-        gated = knobs.serialize_transfers()
-    if not gated:
-        yield pending
-        return
-    import jax
-
-    with _TRANSFER_LOCK:
-        yield pending
-        if pending:
-            jax.block_until_ready(pending)
-
 
 @functools.lru_cache(maxsize=256)
 def _root_module(tp: type) -> str:
@@ -144,8 +112,8 @@ def donate_template(arr: Any) -> None:
         obs.swallowed_exception("restore.donate_template", e)
 
 
-# observability for the bench's mechanisms block: how many restore
-# templates were actually freed (the 1x-restore evidence)
+# how many restore templates were actually freed (the benchmark's
+# donation.templates_share reads it)
 DONATION_STATS = {"donated_templates": 0}
 
 
@@ -382,18 +350,12 @@ def materialize_into_template(np_arr: np.ndarray, obj_out: Any) -> Any:
     if _is_jax_array(obj_out):
         import jax
 
-        from .. import knobs
-
         if np.dtype(np_arr.dtype) != np.dtype(obj_out.dtype):
             np_arr = np_arr.astype(obj_out.dtype)
         shaped = np_arr.reshape(obj_out.shape)
         sharding = obj_out.sharding
-        # consumers run on an executor, so H2D puts from several
-        # threads overlap unless knobs.serialize_transfers() gates them
-        with transfer_gate() as pending:
-            with obs.span("h2d/put", bytes=shaped.nbytes):
-                out = jax.device_put(shaped, sharding)
-            pending.append(out)
+        with obs.span("h2d/put", bytes=shaped.nbytes):
+            out = jax.device_put(shaped, sharding)
         # NOTE: the template is NOT donated here.  Callers donate only
         # after the replacement is visible through the leaf's Future
         # (fut.set then donate_template), so a donated template always
@@ -418,9 +380,9 @@ class ArrayBufferConsumer(BufferConsumer):
     # below this, the executor thread-hop costs more than the copy —
     # a 20k-tiny-leaf restore spends most of its wall time in loop
     # wakeups and submits without this short-circuit.  HOST templates
-    # only: a jax template's materialize enters transfer_gate(), whose
-    # blocking lock + block_until_ready must NEVER run on the event
-    # loop thread (a gated wedge would freeze all restore I/O).
+    # only: a jax template's materialize calls device_put (and may
+    # compile an unpack program), which must not run on the event loop
+    # thread, where it would hold up all restore I/O.
     _INLINE_CONSUME_MAX = 256 * 1024
 
     async def consume_buffer(
@@ -504,16 +466,16 @@ class _DeviceTileAcc:
     template: each tile chains a donated ``dynamic_update_slice``
     (``ops.device_pack.tile_update_device``), so device peak stays at
     ~1x the target plus one tile and host peak at O(budget) — the
-    reference's bounded-RSS random-access property
-    (benchmarks/load_tensor) extended to DEVICE targets, which is the
-    TPU-native case.  The user's template seeds the chain and is
+    reference's bounded-RSS random-access property (upstream
+    torchsnapshot's benchmarks/load_tensor) extended to DEVICE
+    targets, which is the TPU-native case.  The user's template seeds the chain and is
     consumed by the first update; on a mid-read failure the template is
     therefore already donated — accessing it raises jax's
     deleted-buffer error, a LOUDER outcome than the host tiled path's
     documented garbage-contents one (_TileCrcFold CONTRACT note).
 
-    Updates are dispatched onto the scheduler's executor (the gate's
-    lock + transfer block must NEVER run on the loop thread — see
+    Updates are dispatched onto the scheduler's executor (a put and a
+    program dispatch must not hold up the loop thread — see
     ArrayBufferConsumer), so concurrent tiles of the same read race on
     the chain: a per-accumulator lock serializes them.  Tiles cover
     disjoint ranges, so completion order is irrelevant.  Construction
@@ -604,9 +566,9 @@ class _DeviceTiledConsumer(BufferConsumer):
             self.crc_fold.record(start, buf)
         np_arr = array_from_buffer(buf, self.dtype, (end - start,))
         if executor is not None:
-            # the update runs transfer_gate (lock + block on the DMA),
-            # which must never block the scheduler loop thread — same
-            # rule as ArrayBufferConsumer's materialize dispatch
+            # the update puts the tile on the device and dispatches
+            # its program, which must not hold up the scheduler loop
+            # thread — same rule as ArrayBufferConsumer's materialize
             await obs.run_in_executor(
                 executor, self.acc.update, np_arr, start,
                 name="consume/materialize", nbytes=np_arr.nbytes,
@@ -982,8 +944,8 @@ class ChunkedArrayIOPreparer:
         # extended to chunks): a chunk is a dim-0 row range, so in flat
         # element space it is CONTIGUOUS — each over-budget chunk splits
         # into byte-range tiles written straight into the target, keeping
-        # host memory O(limit) instead of O(chunk) (the reference's
-        # load_tensor benchmark contract, benchmarks/load_tensor/main.py).
+        # host memory O(limit) instead of O(chunk) (the contract of
+        # upstream torchsnapshot's benchmarks/load_tensor/main.py:26-27).
         # One outer step per chunk; a tiled chunk steps the outer
         # countdown only after its tiles land AND the assembled region
         # passes the recorded crc32 (VERIFY_ON_RESTORE).

@@ -368,8 +368,6 @@ class ShardedArrayIOPreparer:
                 if sharded_template:
                     import jax
 
-                    from .array import transfer_gate
-
                     if target_dtype != dtype:
                         for box in list(buffers):
                             buffers[box] = buffers[box].astype(target_dtype)
@@ -378,12 +376,10 @@ class ShardedArrayIOPreparer:
                     )
                     if set(local_boxes) == {full_box}:
                         # fully-replicated template: one broadcasting device_put
-                        with transfer_gate() as pending:
-                            with obs.span(
-                                "h2d/put", bytes=buffers[full_box].nbytes
-                            ):
-                                out = jax.device_put(buffers[full_box], sharding)
-                            pending.append(out)
+                        with obs.span(
+                            "h2d/put", bytes=buffers[full_box].nbytes
+                        ):
+                            out = jax.device_put(buffers[full_box], sharding)
                         # fut.set BEFORE donation: a donated template must
                         # always imply a replacement reachable through the
                         # Future (1x-restore; see donate_template)
@@ -391,18 +387,16 @@ class ShardedArrayIOPreparer:
                         donate_template(obj_out)
                         return
                     arrays = []
-                    with transfer_gate() as pending:
-                        for box, devs in local_boxes.items():
-                            for dev in devs:
-                                with obs.span(
-                                    "h2d/put",
-                                    bytes=buffers[box].nbytes,
-                                    device=dev.id,
-                                ):
-                                    arrays.append(
-                                        jax.device_put(buffers[box], dev)
-                                    )
-                        pending.extend(arrays)
+                    for box, devs in local_boxes.items():
+                        for dev in devs:
+                            with obs.span(
+                                "h2d/put",
+                                bytes=buffers[box].nbytes,
+                                device=dev.id,
+                            ):
+                                arrays.append(
+                                    jax.device_put(buffers[box], dev)
+                                )
                     out = jax.make_array_from_single_device_arrays(
                         tuple(obj_out.shape), sharding, arrays
                     )
@@ -645,7 +639,6 @@ class _DirectLeaf:
         import jax
 
         from ..ops.device_pack import cut_box_on_device
-        from .array import transfer_gate
 
         # (bytes to send, where the box starts in them or None, its sizes,
         # the devices that hold it)
@@ -668,18 +661,16 @@ class _DirectLeaf:
         ):
             placed: Dict[Any, Any] = {}
             wide = []
-            with transfer_gate() as pending:
-                for view, start, sizes, devs in sends:
-                    for dev in devs:
-                        with obs.span(
-                            "h2d/put", bytes=view.nbytes, device=dev.id
-                        ):
-                            arr = jax.device_put(view, dev)
-                        if start is not None:
-                            wide.append(arr)
-                            arr = cut_box_on_device(arr, start, sizes)
-                        placed[dev] = arr
-                pending.extend(placed.values())
+            for view, start, sizes, devs in sends:
+                for dev in devs:
+                    with obs.span(
+                        "h2d/put", bytes=view.nbytes, device=dev.id
+                    ):
+                        arr = jax.device_put(view, dev)
+                    if start is not None:
+                        wide.append(arr)
+                        arr = cut_box_on_device(arr, start, sizes)
+                    placed[dev] = arr
             # the worker waits for its piece before it takes the next:
             # at most one piece's wide buffers a worker are on the devices
             jax.block_until_ready(list(placed.values()))

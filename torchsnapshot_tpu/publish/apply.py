@@ -29,8 +29,6 @@ import contextlib
 import threading
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
-import numpy as np
-
 from .. import obs
 from ..continuous.store import decode_leaf, encode_leaf
 from ..flatten import flatten, inflate
@@ -217,12 +215,3 @@ class LiveWeights:
                 stateful.load_state_dict(inflated[k])
             else:
                 self._app_state[k] = inflated[k]
-
-
-def expected_window_array(
-    leaf_doc: Dict[str, Any], data: bytes
-) -> np.ndarray:
-    """Test/bench helper: decode a leaf window exactly as the applier
-    would (dim-0-flexible shape)."""
-    lw = LiveWeights({})
-    return lw._decode_window(leaf_doc, data, "<window>")

@@ -21,7 +21,7 @@ One ``Publisher`` owns one publication root and serves three sources:
   only the chunks the previous record didn't already reference into
   the root's own ``objects/`` pool (budgeted, via the scheduler's
   buffer-write engine), then commit the record.  This is the
-  SnapshotManager-free path and the bench/acceptance workhorse.
+  SnapshotManager-free path and the acceptance tests' workhorse.
 
 Every publication is the same marker-last commit (record body → HEAD
 flip, publish/record.py) followed by a best-effort KV announce
@@ -67,12 +67,13 @@ class Publisher:
         self,
         root: str,
         coordinator: Optional[Coordinator] = None,
-        retain: Optional[int] = None,
+        retain: int = 4,
         chunk_size_bytes: Optional[int] = None,
     ) -> None:
         self.root = root.rstrip("/")
         self._coordinator = coordinator
-        self._retain = retain
+        # records this publisher keeps; the HEAD record always survives
+        self._retain = max(1, int(retain))
         self.chunk_size = int(
             chunk_size_bytes or knobs.get_cas_chunk_size_bytes()
         )
@@ -373,13 +374,8 @@ class Publisher:
         Best-effort throughout: a failed delete leaks bytes, never a
         publication."""
         try:
-            retain = (
-                self._retain
-                if self._retain is not None
-                else knobs.get_publish_retain()
-            )
             self._recent_steps.append(int(record["step"]))
-            while len(self._recent_steps) > retain:
+            while len(self._recent_steps) > self._retain:
                 self._store.delete_quiet(
                     record_path(self._recent_steps.pop(0))
                 )

@@ -77,7 +77,7 @@ class Subscriber:
         coordinator: Optional[Coordinator] = None,
         sub_id: Optional[str] = None,
         shard_spec: Optional[Dict[str, Tuple]] = None,
-        poll_s: Optional[float] = None,
+        poll_s: float = 2.0,
         priority: int = 0,
         strict: bool = True,
     ) -> None:
@@ -86,7 +86,7 @@ class Subscriber:
         self.sub_id = sub_id or f"sub-{uuid.uuid4().hex[:12]}"
         self._coordinator = coordinator
         self._shard_spec = shard_spec
-        self._poll_s = poll_s
+        self._poll_s = max(0.01, float(poll_s))
         self._priority = int(priority)
         self._strict = strict
         self._store = PublishStore(self.root)
@@ -127,11 +127,7 @@ class Subscriber:
         return self.live.generation
 
     def poll_interval_s(self) -> float:
-        return (
-            self._poll_s
-            if self._poll_s is not None
-            else knobs.get_publish_poll_s()
-        )
+        return self._poll_s
 
     # ---------------------------------------------------------- engine
 

@@ -12,10 +12,10 @@ fatal classification) — an op that recovered on retry is a success.
 - **half-open** — after the cooldown one probe op is allowed through;
   its success closes the breaker, its failure re-opens (fresh cooldown).
 
-Knobs: ``TORCHSNAPSHOT_TPU_BREAKER_THRESHOLD`` (consecutive failures),
-``BREAKER_COOLDOWN_S``.  State is exported as the gauge
-``resilience.breaker_state.<name>`` (0 closed, 1 half-open, 2 open) and
-trips count ``resilience.breaker_trips``.
+Knob: ``TORCHSNAPSHOT_TPU_BREAKER_THRESHOLD`` (consecutive failures);
+the cooldown is the constructor's ``cooldown_s``.  State is exported as
+the gauge ``resilience.breaker_state.<name>`` (0 closed, 1 half-open,
+2 open) and trips count ``resilience.breaker_trips``.
 """
 
 from __future__ import annotations
@@ -56,11 +56,12 @@ class CircuitBreaker:
         self,
         name: str,
         threshold: Optional[int] = None,
-        cooldown_s: Optional[float] = None,
+        cooldown_s: float = 30.0,
     ) -> None:
         self.name = name
         self._threshold = threshold
-        self._cooldown_s = cooldown_s
+        # open this long before one half-open probe is admitted
+        self.cooldown_s = cooldown_s
         self._lock = threading.Lock()
         self._consecutive_failures = 0
         self._state = CLOSED
@@ -75,13 +76,6 @@ class CircuitBreaker:
         return (
             knobs.get_breaker_threshold() if self._threshold is None
             else self._threshold
-        )
-
-    @property
-    def cooldown_s(self) -> float:
-        return (
-            knobs.get_breaker_cooldown_s() if self._cooldown_s is None
-            else self._cooldown_s
         )
 
     @property

@@ -28,7 +28,7 @@ Classification verdicts (returned by a backend's ``classify(e)``):
   delete of a missing object).
 
 Policy knobs: ``TORCHSNAPSHOT_TPU_RETRY_MAX_ATTEMPTS``,
-``RETRY_PROGRESS_WINDOW_S``, ``RETRY_BACKOFF_CAP_S``.  Hand-rolled
+``RETRY_BACKOFF_CAP_S``.  Hand-rolled
 sleep-backoff loops around storage/KV ops elsewhere in the package are
 rejected by the snaplint ``retry-discipline`` pass — this module is the
 one sanctioned home for them.
@@ -64,14 +64,13 @@ class SharedProgress:
 
     def __init__(
         self,
-        window_s: Optional[float] = None,
+        window_s: float = 120.0,
         max_attempts: Optional[int] = None,
         label: str = "",
     ) -> None:
-        self.window_s = (
-            knobs.get_retry_progress_window_s() if window_s is None
-            else window_s
-        )
+        # an op gives up only when the WHOLE pipeline has made no
+        # progress this long (the GCS plugin's historical constant)
+        self.window_s = window_s
         self.max_attempts = (
             knobs.get_retry_max_attempts() if max_attempts is None
             else max_attempts

@@ -41,7 +41,7 @@ def measure_rss_deltas(
         rss_deltas.append(proc.memory_info().rss - baseline)
         # benchmarks read memory and timing through one surface: the
         # observed peak lands in the metrics registry alongside the
-        # pipeline counters (obs.metrics_snapshot / BENCH records)
+        # pipeline counters (obs.metrics_snapshot)
         from .obs import metrics as _metrics
 
         _metrics.gauge(_metrics.RSS_PEAK_DELTA_BYTES).set(

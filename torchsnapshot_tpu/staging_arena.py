@@ -5,10 +5,12 @@ one ``malloc`` of the object's size, and one ``free`` when its write is
 done.  At a slab's or a leaf's size glibc maps and unmaps every such
 request (over its 32 MiB ceiling nothing is recycled), so each save
 faults all its staged bytes in anew and hands them all back.  Measured on
-a TPU v5 lite host (PERF.md §5, PR 29): a 7.97 GB save whose allocator
-keeps its blocks takes 1.3 s where one that maps them anew takes 3.5 s,
-and the pages given back, 7.97 GB a save, are what the sandbox of that
-host cannot take back as fast as back-to-back saves return them.
+a TPU v5 lite host (PERF_LEDGER.jsonl, PR 29, six pairs): a blocking
+7.97 GB save commits in 1.47 s with this arena and the scheduler's order
+rule, against 4.82 s at the parent, whose device→host copies waited for
+fresh pages (``d2h.copy_s`` 11.07 → 1.82 thread-s); the pages given back,
+7.97 GB a save, are what the sandbox of that host cannot take back as
+fast as back-to-back saves return them (PERF.md §5).
 
 numpy lets a caller choose the allocator of the arrays made in the
 current context (NEP 49, ``PyDataMem_SetHandler``).  ``allocating()``

@@ -34,8 +34,8 @@ Fallback ladder, probed ONCE at engine construction (never per-op):
    toolchain) → the fs plugin keeps its pre-engine paths unchanged.
 
 The aligned bounce-buffer pool is preallocated at engine construction
-whenever the direct leg is active (``FASTIO_BUFFER_POOL_BYTES`` total,
-fixed 4MB buffers; buffered-only engines allocate none — they move
+whenever the direct leg is active (:data:`POOL_BYTES` total, fixed 4MB
+buffers; buffered-only engines allocate none — they move
 bytes straight between caller memory and the kernel); an exhausted
 pool backpressures the requesting part (``storage.fastio.pool_waits``)
 instead of allocating — the engine can never amplify the scheduler's
@@ -69,6 +69,12 @@ BOUNCE_BYTES = 4 * 1024 * 1024
 # a sub-MB object is all head/tail anyway, and O_DIRECT's synchronous
 # media round-trip would dominate its latency.
 DIRECT_MIN_BYTES = 1 * 1024 * 1024
+
+# Total preallocated aligned bounce-buffer pool (split into fixed 4MB
+# buffers).  Direct-path parts each hold one buffer for the duration of
+# their copy+write; an exhausted pool backpressures (the part waits for
+# a buffer, and storage.fastio.pool_waits counts the waits).
+POOL_BYTES = 64 * 1024 * 1024
 
 
 class _AlignedPool:
@@ -183,7 +189,7 @@ def create_engine(lib: Any, root: str) -> Optional["FastIOEngine"]:
         lib,
         direct=direct_ok,
         dontneed=want_direct and not direct_ok,
-        pool_bytes=knobs.get_fastio_buffer_pool_bytes(),
+        pool_bytes=POOL_BYTES,
     )
 
 

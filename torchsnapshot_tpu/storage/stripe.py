@@ -3,8 +3,7 @@
 The staging pipeline already overlaps D2H with storage I/O *across*
 objects, but a single large tensor used to move as ONE stream — one
 ``put_object``, one file write, one ranged GET — so intra-object
-parallelism was zero and a transient mid-object re-sent everything
-(BENCH r05: ~10ms async blocked time but 0.022 GB/s save throughput).
+parallelism was zero and a transient mid-object re-sent everything.
 This engine splits any object at or above
 ``TORCHSNAPSHOT_TPU_STRIPE_MIN_OBJECT_SIZE_BYTES`` into
 ``TORCHSNAPSHOT_TPU_STRIPE_PART_SIZE_BYTES`` parts and drives the parts

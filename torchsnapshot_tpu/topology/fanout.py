@@ -57,6 +57,7 @@ import time
 from typing import Any, Dict, Iterable, Optional, Set
 
 from .. import knobs, obs
+from ..coordination import KV_BLOB_PART_BYTES
 from ..io_types import (
     ReadIO,
     StoragePlugin,
@@ -212,14 +213,13 @@ async def publish_object(
     with obs.span("fanout/publish", path=path):
         try:
             failpoint("topology.fanout.publish", path=path)
-            part = knobs.get_fanout_part_bytes()
             loop = asyncio.get_running_loop()
             n = await loop.run_in_executor(
-                None, coordinator.kv_publish_blob, prefix, buf, part
+                None, coordinator.kv_publish_blob, prefix, buf
             )
             obs.counter(obs.FANOUT_PUBLISHES).inc()
             obs.counter(obs.FANOUT_BYTES_REDISTRIBUTED).inc(n)
-            return max(1, (n + part - 1) // part)
+            return max(1, -(-n // KV_BLOB_PART_BYTES))
         except Exception as e:  # noqa: BLE001 — best-effort by contract
             obs.swallowed_exception("topology.fanout.publish", e)
             return 0
@@ -263,8 +263,8 @@ async def fetch_published(
                     )
                     if data is not None:
                         # KV-leg consumption, metered under the same
-                        # instrument family as the collective engine so
-                        # the bench compares engines directly
+                        # instrument family as the collective engine, so
+                        # the two engines compare directly
                         obs.counter(obs.TRANSPORT_KV_OPS).inc()
                         obs.counter(obs.TRANSPORT_KV_BYTES).inc(
                             len(data)

@@ -28,8 +28,8 @@ do (multi-process jax session whose process indices align with the
 coordinator's ranks, or an in-process device registry for
 single-process worlds), and every downgrade — at probe time or mid-op
 — advances ``transport.fallbacks`` and lands on KV.  Transport NEVER
-wedges an operation: every collective wait is bounded by
-``TRANSPORT_TIMEOUT_S``, and any anomaly degrades the op (and, for
+wedges an operation: every collective wait is bounded
+(``collective.GATE_TIMEOUT_S``), and any anomaly degrades the op (and, for
 session-ordered collectives, the rest of the session) to the KV path
 the fan-out timeout ladder already defines.
 

@@ -28,9 +28,8 @@ any backend's layout constraints are satisfied), chunked at
   ``device_put`` into an in-process registry keyed by prefix and
   announced over the KV (``{prefix}/xmeta``, digests included);
   consumers ``device_get`` and verify.  The bytes genuinely cross the
-  host↔device boundary, which is what makes the bench's KV-vs-
-  collective comparison measure transfer machinery rather than a
-  dict lookup.
+  host↔device boundary, so a KV-vs-collective comparison measures
+  transfer machinery rather than a dict lookup.
 
 Every payload is crc32 + adler32 verified against digests computed at
 publication before a consumer may trust it, in both modes.  The KV
@@ -47,6 +46,7 @@ import time
 from typing import Any, Dict, List, Optional, Set, Tuple
 
 from .. import knobs, obs
+from ..coordination import KV_BLOB_PART_BYTES
 from ..resilience.failpoints import failpoint
 from ..utils.checksums import adler32_fast, crc32_fast
 from . import Transport, TransportUnavailable, count_fallback
@@ -57,6 +57,11 @@ logger = logging.getLogger(__name__)
 # (TPU lane width) never force a reshape on the hot path
 _LANE = 128
 _WORD = 4  # uint32 lane words carry the bytes (gloo/psum-safe dtype)
+
+# How long a participant waits on the control-plane gate (go/no-go key)
+# for one transfer before treating the transfer as failed and degrading
+# to KV.  Bounds every wait in the engine — the never-wedge contract.
+GATE_TIMEOUT_S = 30.0
 
 # in-process publication registry for local mode: prefix → (device
 # part arrays, payload nbytes, crc32, adler32).  Module-global on
@@ -343,7 +348,7 @@ class CollectiveFanoutSession:
     order; the read path talks to it through ``offer`` /  ``decline``
     (source side, non-blocking) and ``consume`` (sibling side,
     blocking with session-guaranteed progress).  All waits are bounded
-    by ``TRANSPORT_TIMEOUT_S``; any anomaly flips ``broken`` and the
+    by ``GATE_TIMEOUT_S``; any anomaly flips ``broken`` and the
     session finishes in drain mode — accepted payloads are
     re-published over the KV blob path so consumers' fan-out ladders
     still find them.
@@ -361,7 +366,7 @@ class CollectiveFanoutSession:
         self.coordinator = coordinator
         self.topology = topology
         self.uid = uid
-        self.timeout_s = max(0.5, knobs.get_transport_timeout_s())
+        self.timeout_s = GATE_TIMEOUT_S
         # transfer order: path read order (caller-derived) major, slice
         # minor — identical on every process by construction
         self.plan: List[Tuple[int, str]] = [
@@ -661,11 +666,10 @@ class CollectiveFanoutSession:
         returns nparts (0 on failure — the ladder's re-election still
         covers the siblings)."""
         try:
-            part = knobs.get_fanout_part_bytes()
-            n = self.coordinator.kv_publish_blob(prefix, data, part)
+            n = self.coordinator.kv_publish_blob(prefix, data)
             obs.counter(obs.TRANSPORT_KV_OPS).inc()
             obs.counter(obs.TRANSPORT_KV_BYTES).inc(n)
-            return max(1, (n + part - 1) // part)
+            return max(1, -(-n // KV_BLOB_PART_BYTES))
         except Exception as e:  # noqa: BLE001 — best-effort degrade
             obs.swallowed_exception("transport.session.degrade", e)
             return 0

@@ -3,8 +3,8 @@
 The degraded-but-always-available payload path: exactly the
 ``kv_publish_blob``/``kv_try_fetch_blob`` primitives the fan-out
 restore has used since the multislice PR, wrapped in the Transport
-API and metered under ``transport.kv_*`` so the bench's KV-vs-
-collective comparison reads both engines off one instrument family.
+API and metered under ``transport.kv_*`` so a KV-vs-collective
+comparison reads both engines off one instrument family.
 Correctness properties are the KV blob contract's: parts written
 first, ``meta`` key LAST (presence implies completeness), crc32
 verified on fetch before any byte is trusted; delivered bytes then
@@ -18,7 +18,8 @@ from __future__ import annotations
 import time
 from typing import Any, Optional
 
-from .. import knobs, obs
+from .. import obs
+from ..coordination import KV_BLOB_PART_BYTES
 from . import Transport
 
 
@@ -37,12 +38,11 @@ class KVTransport(Transport):
         written (the caller's cleanup ledger)."""
         with obs.span("transport/kv_publish", prefix=prefix):
             t0 = time.monotonic()
-            part = knobs.get_fanout_part_bytes()
-            n = self.coordinator.kv_publish_blob(prefix, data, part)
+            n = self.coordinator.kv_publish_blob(prefix, data)
             self._m_ops.inc()
             self._m_bytes.inc(n)
             self._m_lat.observe(time.monotonic() - t0)
-            return max(1, (n + part - 1) // part)
+            return max(1, -(-n // KV_BLOB_PART_BYTES))
 
     def try_fetch(self, prefix: str) -> Optional[bytes]:
         """Non-blocking probe + crc-verified fetch; None = not (yet)

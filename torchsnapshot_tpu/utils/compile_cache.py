@@ -1,7 +1,7 @@
 """Where PROGRAMS keep XLA's persistent compile cache.
 
 Called by the entry points that touch the chip (``chip_smoke.py``,
-``bench.py``, ``benchmarks/``, ``__graft_entry__.py``) before their
+``benchmarks/link_probe.py``, ``__graft_entry__.py``) before their
 first compile.  Importing the library never calls it: a library does
 not set process-wide JAX configuration.
 

@@ -18,7 +18,19 @@ file a device; each pass walks every file once in views of ``--mb``:
   before its put (``mlock`` + ``munlock``: the program's own
   ``preparers.sharded._populate``; ``madvise(MADV_POPULATE_READ)``,
   a ``pwrite`` of the view to a memfd, or a read of a byte a page from the
-  putting thread), then put, or put to two devices and cut.
+  putting thread), then put, or put to two devices and cut;
+- ``handoff``: the two ways a column piece's halves reach the two devices
+  that share it, each after the program's populate, in turn (A B B A):
+  ``pair_cut`` as above (the piece crosses the host link twice), and
+  ``pair_handoff``: the piece put ONCE, to one device, both halves cut
+  there, the second moved with a device-to-device ``jax.device_put`` to
+  the next device, waited for, deleted.  ``unique_gb_s`` is the piece's
+  own bytes a second, whatever the link carried;
+- ``d2d``: the hand-off alone: a device array moved to the next device
+  with ``jax.device_put`` and waited for, from one thread and from a
+  thread a device (a ring), against the same bytes read back to the host
+  and put again, which is what a runtime without a chip-to-chip path
+  would do.
 
 Run through the chip tool:
     chiprun --chips 4 -- python benchmarks/mapped_put_probe.py
@@ -136,11 +148,12 @@ def main() -> int:
     parser = argparse.ArgumentParser()
     parser.add_argument("--mb", type=int, nargs="+", default=[22, 96])
     parser.add_argument("--file-mb", type=int, default=1536)
-    parser.add_argument("--out", default="chiprun_out/pr31/probe.jsonl")
+    parser.add_argument("--out", default="chiprun_out/probe.jsonl")
     parser.add_argument("--allow-cpu", action="store_true")
     parser.add_argument(
-        "--phases", nargs="+", default=["put", "copy", "populate"],
-        choices=["put", "copy", "populate"],
+        "--phases", nargs="+",
+        default=["put", "copy", "populate", "handoff", "d2d"],
+        choices=["put", "copy", "populate", "handoff", "d2d"],
     )
     parser.add_argument("--sinks", nargs="+", default=["ram", "tmp"])
     args = parser.parse_args()
@@ -174,20 +187,30 @@ def main() -> int:
         out.write(line + "\n")
         out.flush()
 
-    def put_pass(maps, view_bytes, pair_cut, populate=None):
+    def put_pass(maps, view_bytes, pair_cut, populate=None, handoff=False):
         sent = [0] * n
         populate_s = [0.0] * n
 
         def work(k):
-            targets = [devices[k]] + ([devices[(k + 1) % n]] if pair_cut else [])
+            sibling = devices[(k + 1) % n]
+            targets = [devices[k]] + ([sibling] if pair_cut else [])
             for view in _views(maps[k], view_bytes):
                 if populate is not None:
                     t0 = time.perf_counter()
                     populate(view)
                     populate_s[k] += time.perf_counter() - t0
                 wide = [jax.device_put(view, d) for d in targets]
-                if pair_cut:
-                    half = view.shape[1] // 2
+                half = view.shape[1] // 2
+                if handoff:
+                    cuts = [
+                        cut_box_on_device(wide[0], (0, i * half), (ROWS, half))
+                        for i in range(2)
+                    ]
+                    moved = jax.device_put(cuts[1], sibling)
+                    jax.block_until_ready([cuts[0], moved])
+                    for w in wide + cuts + [moved]:
+                        w.delete()
+                elif pair_cut:
                     cuts = [
                         cut_box_on_device(w, (0, i * half), (ROWS, half))
                         for i, w in enumerate(wide)
@@ -213,8 +236,35 @@ def main() -> int:
 
         return _threads(work, n)
 
+    def d2d_pass(mb, threads, through_host):
+        reps = 24
+        cols = (mb << 20) // 4 // ROWS
+
+        def work(k):
+            src, dst = devices[k], devices[(k + 1) % n]
+            box = jax.device_put(np.ones((ROWS, cols), np.float32), src)
+            box.block_until_ready()
+            for _ in range(reps):
+                moved = jax.device_put(np.asarray(box) if through_host else box, dst)
+                moved.block_until_ready()
+                moved.delete()
+
+        wall = _threads(work, threads)
+        moved_gb = threads * reps * ROWS * cols * 4 / 1e9
+        report(
+            phase="d2d_through_host" if through_host else "d2d", view_mb=mb,
+            threads=threads, gb=moved_gb, wall_s=wall, gb_s=moved_gb / wall,
+            ms_a_move=wall / reps * 1e3,
+        )
+
     try:
-        for root, kind in ((ram, ram_kind), (tmp, tmp_kind)):
+        for mb in args.mb if "d2d" in args.phases else ():
+            for threads in (1, n):
+                for through_host in (False, True):
+                    d2d_pass(mb, threads, through_host)
+        # the d2d phase moves device arrays: alone, it needs no file
+        sinks = ((ram, ram_kind), (tmp, tmp_kind)) if set(args.phases) != {"d2d"} else ()
+        for root, kind in sinks:
             if ("ram" if root is ram else "tmp") not in args.sinks:
                 continue
             t0 = time.perf_counter()
@@ -253,6 +303,21 @@ def main() -> int:
                             unique_gb_s=sent / 1e9 / wall / (2 if pair_cut else 1),
                         )
                         del maps
+            for mb in args.mb if "handoff" in args.phases else ():
+                for name in ("pair_cut", "pair_handoff", "pair_handoff", "pair_cut"):
+                    handoff = name == "pair_handoff"
+                    maps = fresh()
+                    sent, wall, pop_s = put_pass(
+                        maps, mb << 20, not handoff, _populators()["mlock"], handoff
+                    )
+                    unique = sent / (1 if handoff else 2)
+                    report(
+                        phase=name, sink=kind, view_mb=mb, link_gb=sent / 1e9,
+                        wall_s=wall, link_gb_s=sent / 1e9 / wall,
+                        handoff_gb=unique / 2e9 if handoff else 0.0,
+                        unique_gb_s=unique / 1e9 / wall, populate_thread_s=pop_s,
+                    )
+                    del maps
             for mb in args.mb if "put" in args.phases else ():
                 view_bytes = mb << 20
                 maps = fresh()

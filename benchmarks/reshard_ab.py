@@ -6,8 +6,13 @@ restored into fresh dp1 x tp4 templates), restored in turn as
 - ``parent``:   host assembly buffers, no populate (the code before PR 31);
 - ``populate``: host assembly buffers, each mapped piece's pages asked of
                 the kernel in one call before the copy;
-- ``direct``:   the populate, then the piece put on its devices as it lies
-                and cut there, no host buffer.
+- ``twice``:    the populate, then the piece put as it lies on EVERY
+                device that holds a box of it and cut on each, no host
+                buffer (the code of PR 31 to 33: a column piece crosses
+                the host link once a device that shares it);
+- ``direct``:   the populate, then the piece put ONCE, on one of those
+                devices, every box cut there and the siblings' boxes
+                moved device to device (the package as it is).
 
 Each restore is timed as the cell times it (fresh template, restore, wait
 for every leaf), with a 20 ms poll of the fullest device's ``bytes_in_use``
@@ -42,28 +47,74 @@ TINY = dict(
 )
 
 
+def _put_twice_and_cut(self, src, read_box, overlaps):
+    """``_DirectLeaf._put_and_cut`` as PR 31 to 33 had it: a box's bytes
+    (the piece whole, for a column box) go over the host link to each
+    device that holds the box, and are cut there."""
+    import jax
+
+    from torchsnapshot_tpu import obs
+    from torchsnapshot_tpu.ops.device_pack import cut_box_on_device
+    from torchsnapshot_tpu.preparers.overlap import is_dim0_slab, relative_slices
+
+    sends = []
+    for inter, lbox in overlaps:
+        devs = self.local_boxes[lbox]
+        if is_dim0_slab(inter, read_box):
+            rows = relative_slices(inter, read_box)[:1]
+            sends.append((src[rows] if rows else src, None, None, devs))
+        else:
+            start = tuple(i - r for i, r in zip(inter[0], read_box[0]))
+            sends.append((src, start, inter[1], devs))
+    link_bytes = sum(view.nbytes * len(devs) for view, _, _, devs in sends)
+    with obs.span(
+        "reshard/direct", bytes=link_bytes, handoff_bytes=0,
+        devices=sum(len(devs) for _, _, _, devs in sends),
+        cut=any(start is not None for _, start, _, _ in sends),
+    ):
+        placed, wide = {}, []
+        for view, start, sizes, devs in sends:
+            for dev in devs:
+                with obs.span("h2d/put", bytes=view.nbytes, device=dev.id):
+                    arr = jax.device_put(view, dev)
+                if start is not None:
+                    wide.append(arr)
+                    arr = cut_box_on_device(arr, start, sizes)
+                placed[dev] = arr
+        obs.counter(obs.RESHARD_LINK_BYTES).inc(link_bytes)
+        jax.block_until_ready(list(placed.values()))
+        for arr in wide:
+            arr.delete()
+    return placed
+
+
 @contextlib.contextmanager
 def _variant(name: str):
+    """The package patched, for the length of one restore, into the
+    mechanism ``name`` names: no knob of the package selects them."""
     from torchsnapshot_tpu import knobs
     from torchsnapshot_tpu.preparers import sharded
 
-    populate = sharded._populate
-    with knobs.override_device_unpack(name == "direct"):
+    populate, put_and_cut = sharded._populate, sharded._DirectLeaf._put_and_cut
+    with knobs.override_device_unpack(name in ("direct", "twice")):
         if name == "parent":
             sharded._populate = lambda src: None
+        if name == "twice":
+            sharded._DirectLeaf._put_and_cut = _put_twice_and_cut
         try:
             yield
         finally:
             sharded._populate = populate
+            sharded._DirectLeaf._put_and_cut = put_and_cut
 
 
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--seed", type=int, default=2147489201)
-    parser.add_argument("--order", default="direct,populate,populate,direct",
+    parser.add_argument("--order", default="direct,twice,twice,direct",
                         help="the timed restores, repeated --rounds times")
     parser.add_argument("--rounds", type=int, default=3)
-    parser.add_argument("--parent", type=int, default=1,
+    parser.add_argument("--parent", type=int, default=0,
                         help="timed restores of the parent's path, at the end")
     parser.add_argument("--spans", type=int, default=1,
                         help="after the timed restores, one more a variant "
@@ -104,6 +155,7 @@ def main(argv=None) -> int:
             got = obs.metrics_snapshot()["counters"]
             return {k: got.get(k, 0) for k in (
                 "reshard.host_alloc_bytes", "reshard.direct_bytes",
+                "reshard.link_bytes", "reshard.handoff_bytes",
                 "reshard.populate_refused", "exceptions.swallowed",
             )}
 

@@ -1,7 +1,9 @@
 """The resharding restore's direct path (``preparers/sharded.py``): a leaf
 whose every local box lies whole inside one read piece takes no host
 assembly buffer; its bytes go from the read piece to ``jax.device_put`` as
-they lie, and a column box is cut out on its device.  On the CPU mesh under
+they lie ONCE, to one of the devices that hold a box of it; a column box
+is cut out there, and a box that another device holds is moved device to
+device.  On the CPU mesh under
 ``knobs.override_device_unpack(True)`` (at auto a CPU "device" is host
 memory and the host path runs, as it always did)."""
 
@@ -20,7 +22,21 @@ COUNTERS = (
     obs.RESHARD_HOST_ALLOC_BYTES,
     obs.RESHARD_DIRECT_BYTES,
     obs.EXCEPTIONS_SWALLOWED,
+    obs.RESHARD_LINK_BYTES,
+    obs.RESHARD_HANDOFF_BYTES,
 )
+
+
+@pytest.fixture(autouse=True)
+def link_tally(monkeypatch):
+    """The process-wide tally of host-link bytes a device, fresh a test:
+    which device receives a shared piece is then the same in every order
+    of the tests."""
+    from torchsnapshot_tpu.preparers import sharded
+
+    tally = sharded._LinkTally()
+    monkeypatch.setattr(sharded, "_LINK_TALLY", tally)
+    return tally
 
 # a leaf of each kind of parallel/mesh.py::_RULES, and a 0-d count
 LEAVES = {
@@ -44,10 +60,14 @@ def _put(kind, mesh, value):
     return jax.device_put(value, NamedSharding(mesh, P(*LEAVES[kind][1])))
 
 
+def _unique_boxes(array):
+    return {str(s.index): s.data.nbytes for s in array.addressable_shards}
+
+
 def _box_bytes(template):
     """Bytes of the template's unique local boxes: what a restore of it
     either allocates on the host or puts direct."""
-    return sum({str(s.index): s.data.nbytes for s in template.addressable_shards}.values())
+    return sum(_unique_boxes(template).values())
 
 
 class _Gained:
@@ -59,7 +79,7 @@ class _Gained:
 
     def __exit__(self, *exc):
         after = self._read()
-        self.host, self.direct, self.swallowed, self.cuts = (
+        self.host, self.direct, self.swallowed, self.link, self.handoff, self.cuts = (
             a - b for a, b in zip(after, self.before)
         )
 
@@ -83,10 +103,12 @@ def _assert_restored(leaf, value, template):
 
 
 # (saved mesh, restore mesh) -> kinds whose local boxes each lie in one
-# saved shard, and the cuts a column leaf takes (one a device)
+# saved shard, and the cuts a column leaf takes (one a unique box)
 LAYOUTS = {
-    ((2, 2), (1, 4)): ({"rows", "cols", "norm", "count"}, 4),
-    ((2, 2), (2, 4)): ({"rows", "cols", "norm", "count"}, 8),  # replicas under dp
+    ((2, 2), (1, 4)): ({"rows", "cols", "norm", "count"}, 4),  # a piece feeds 2 devices
+    ((1, 2), (1, 8)): ({"rows", "cols", "norm", "count"}, 8),  # a piece feeds 4
+    ((2, 2), (2, 4)): ({"rows", "cols", "norm", "count"}, 4),  # ... 2 boxes, each on 2
+    ((1, 2), (2, 2)): ({"rows", "cols", "norm", "count"}, 0),  # a row box on 2 devices
     ((1, 4), (1, 4)): ({"rows", "cols", "norm", "count"}, 0),  # whole shards
     ((2, 2), (4, 1)): ({"norm", "count"}, 0),  # gathered boxes: host path
     ((1, 4), (2, 2)): ({"norm", "count"}, 0),
@@ -100,7 +122,8 @@ LAYOUTS = {
 def test_a_leaf_comes_back_bitwise_on_every_device(tmp_path, layout, kind):
     (save, restore), (direct_kinds, cuts) = layout, LAYOUTS[layout]
     value = _value(kind, seed=3)
-    Snapshot.take(str(tmp_path / "s"), {"app": StateDict(w=_put(kind, _mesh(*save), value))})
+    saved = _put(kind, _mesh(*save), value)
+    Snapshot.take(str(tmp_path / "s"), {"app": StateDict(w=saved)})
     template = _put(kind, _mesh(*restore), np.zeros_like(value))
     dest = StateDict(w=template)
     with knobs.override_device_unpack(True), _Gained() as g:
@@ -111,6 +134,14 @@ def test_a_leaf_comes_back_bitwise_on_every_device(tmp_path, layout, kind):
     assert g.host + g.direct == _box_bytes(template)
     assert g.cuts == (cuts if kind == "cols" and want_direct else 0)
     assert g.swallowed == 0
+    # every byte crosses the host link once; of a piece's boxes one lands
+    # where the piece was put, and the others get theirs device to device.
+    # A column piece is a saved shard put whole; any other put is one box
+    assert g.link == g.direct
+    boxes = _unique_boxes(template)
+    puts = len(_unique_boxes(saved)) if kind == "cols" else len(boxes)
+    delivered = sum(s.data.nbytes for s in template.addressable_shards)
+    assert g.handoff == (delivered - puts * max(boxes.values()) if want_direct else 0)
 
 
 def _host_path_cases():
@@ -208,25 +239,41 @@ def test_verify_on_restore_still_catches_a_corrupted_shard(tmp_path, kind):
     np.testing.assert_array_equal(np.asarray(template), np.zeros_like(value))
 
 
-@pytest.mark.parametrize("fails_from", [0, 2], ids=["first_cut", "after_a_piece_landed"])
-def test_a_cut_that_raises_falls_back_to_the_host_path_once(tmp_path, monkeypatch, fails_from):
+@pytest.mark.parametrize(
+    "raises, fails_from, cuts",
+    [("cut", 0, {0}), ("cut", 2, {2}), ("handoff", 0, {1, 2}), ("handoff", 1, {3, 4})],
+    ids=["first_cut", "cut_after_a_piece_landed", "first_handoff", "handoff_after_a_piece_landed"],
+)
+def test_a_cut_that_raises_falls_back_to_the_host_path_once(
+    tmp_path, monkeypatch, raises, fails_from, cuts
+):
     """Both read pieces of the leaf fail (or the second, with the first
-    piece's boxes already on their devices and read back): the leaf comes
-    out bitwise through assembly buffers made then, counted once, and its
+    piece's boxes already on their devices and read back), in a cut or in
+    the move of a cut box to the sibling's device: the leaf comes out
+    bitwise through assembly buffers made then, counted once, and its
     template is whole whenever the direct path is asked."""
     value = _value("cols", seed=9)
     Snapshot.take(str(tmp_path / "s"), {"app": StateDict(w=_put("cols", _mesh(2, 2), value))})
     template = _put("cols", _mesh(1, 4), np.zeros_like(value))
-    real_cut, calls, template_deleted = device_pack.cut_box_on_device, [], []
+    real_cut, real_put = device_pack.cut_box_on_device, jax.device_put
+    calls, handoffs, template_deleted = [], [], []
 
     def cut(wide, starts, sizes):
         template_deleted.append(template.is_deleted())
         calls.append(starts)
-        if len(calls) > fails_from:
+        if raises == "cut" and len(calls) > fails_from:
             raise RuntimeError("planted: no room on the device")
         return real_cut(wide, starts, sizes)
 
+    def put(x, *args, **kwargs):
+        if isinstance(x, jax.Array):  # a box on its way to a sibling
+            handoffs.append(x.shape)
+            if raises == "handoff" and len(handoffs) > fails_from:
+                raise RuntimeError("planted: the sibling has no room")
+        return real_put(x, *args, **kwargs)
+
     monkeypatch.setattr(device_pack, "cut_box_on_device", cut)
+    monkeypatch.setattr(jax, "device_put", put)
     dest = StateDict(w=template)
     # one worker: the pieces land one after the other, in either order
     with knobs.override_device_unpack(True), knobs.override_staging_threads(1):
@@ -239,7 +286,77 @@ def test_a_cut_that_raises_falls_back_to_the_host_path_once(tmp_path, monkeypatc
         np.testing.assert_array_equal(np.asarray(shard.data), value[shard.index])
     assert g.swallowed == 1
     assert (g.host, g.direct) == (value.nbytes, 0)
-    assert g.cuts == min(fails_from, len(calls))
+    # a hand-off follows the cut of the sibling's box, which is the piece's
+    # first or its second
+    assert g.cuts in cuts and (raises == "cut" or g.cuts == len(calls))
+    assert len(handoffs) == (fails_from + 1 if raises == "handoff" else fails_from // 2)
+
+
+def test_a_read_piece_crosses_the_host_link_once_and_the_links_stay_even(tmp_path, link_tally):
+    """Four column leaves saved under 2x2, restored under 1x4: eight read
+    pieces, each wanted by two devices.  One host put a piece; the host
+    link carries the state once and the siblings get their halves device
+    to device; no device's link has taken over a piece more than another's."""
+    from torchsnapshot_tpu.obs import tracer
+
+    values = {f"w{i}": _value("cols", seed=20 + i) for i in range(4)}
+    saved = {k: _put("cols", _mesh(2, 2), v) for k, v in values.items()}
+    Snapshot.take(str(tmp_path / "s"), {"app": StateDict(**saved)})
+    templates = {k: _put("cols", _mesh(1, 4), np.zeros_like(v)) for k, v in values.items()}
+    dest = StateDict(**templates)
+    with knobs.override_device_unpack(True), knobs.override_trace(True), _Gained() as g:
+        tracer.get_tracer().reset()
+        Snapshot(str(tmp_path / "s")).restore({"app": dest})
+        spans = tracer.get_tracer().spans()
+    for k, v in values.items():
+        _assert_restored(dest[k], v, templates[k])
+    state_bytes = sum(v.nbytes for v in values.values())
+    piece = values["w0"].nbytes // 2
+    puts = [s for s in spans if s.name == "h2d/put"]
+    moves = [s for s in spans if s.name == "d2d/put"]
+    assert len(puts) == 8 and {s.attrs["bytes"] for s in puts} == {piece}
+    assert len(moves) == 8 and {s.attrs["bytes"] for s in moves} == {piece // 2}
+    assert all(s.attrs["src"] != s.attrs["dst"] for s in moves)
+    assert (g.link, g.handoff, g.direct, g.host) == (state_bytes, state_bytes // 2, state_bytes, 0)
+    assert (g.cuts, g.swallowed) == (16, 0)
+    # the tally is what the puts' spans say, a device
+    taken = link_tally.snapshot()
+    assert sum(taken.values()) == state_bytes
+    for dev in jax.devices()[:4]:
+        assert taken.get(dev.id, 0) == sum(
+            s.attrs["bytes"] for s in puts if s.attrs["device"] == dev.id
+        )
+    assert max(taken.values()) - min(taken.get(d.id, 0) for d in jax.devices()[:4]) <= piece
+
+
+def test_the_link_tally_loses_no_charge_under_many_workers(link_tally):
+    """More workers than cores charge equal pieces to a pair of devices
+    each: every charge is counted, and no link is ever a piece ahead of
+    its pair's other by more than one."""
+    import sys
+    import threading
+
+    devs = jax.devices()[:4]
+    pairs, rounds, workers = [devs[:2], devs[2:]], 400, 16
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        def work(k):
+            for i in range(rounds):
+                assert link_tally.charge_least(pairs[(k + i) % 2], 8) in pairs[(k + i) % 2]
+
+        threads = [threading.Thread(target=work, args=(k,)) for k in range(workers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    taken = link_tally.snapshot()
+    assert sum(taken.values()) == workers * rounds * 8
+    for pair in pairs:
+        assert abs(taken[pair[0].id] - taken[pair[1].id]) <= 8
 
 
 def _mapped(tmp_path, nbytes):
@@ -351,19 +468,32 @@ def test_the_ab_script_restores_one_snapshot_by_each_mechanism(tmp_path, capsys)
     reshard_ab = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(reshard_ab)
     out = tmp_path / "ab.jsonl"
-    assert reshard_ab.main(["--tiny", "--rounds", "1", "--out", str(out)]) == 0
+    order = "direct,twice,populate,twice,direct"
+    argv = ["--tiny", "--rounds", "1", "--parent", "1", "--order", order, "--out", str(out)]
+    assert reshard_ab.main(argv) == 0
     summary = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert summary["answers_checked"] == 2 and not any(summary["wrong"].values())
+    assert summary["answers_checked"] == 3 and not any(summary["wrong"].values())
+    state_bytes = summary["state_bytes"]
     runs = [json.loads(line) for line in out.read_text().splitlines()]
     assert [r["variant"] for r in runs] == (
-        ["direct", "populate"] + ["direct", "populate", "populate", "direct"]
-        + ["parent"] + ["direct", "populate"]
+        ["direct", "twice", "populate"] + order.split(",") + ["parent"]
+        + ["direct", "twice", "populate"]
     )
     for r in runs:
-        took_direct = r["variant"] == "direct"
-        assert r["reshard.direct_bytes"] == (summary["state_bytes"] if took_direct else 0)
-        assert r["reshard.host_alloc_bytes"] == (0 if took_direct else summary["state_bytes"])
+        took_direct = r["variant"] in ("direct", "twice")
+        assert r["reshard.direct_bytes"] == (state_bytes if took_direct else 0)
+        assert r["reshard.host_alloc_bytes"] == (0 if took_direct else state_bytes)
         assert r["exceptions.swallowed"] == 0
+    # the package puts a piece once and hands the siblings' boxes on; the
+    # script's ``twice`` sends the piece to every device that shares it
+    link = {r["variant"]: r["reshard.link_bytes"] for r in runs}
+    handoff = {r["variant"]: r["reshard.handoff_bytes"] for r in runs}
+    assert link["direct"] == state_bytes < link["twice"] and link["populate"] == 0
+    assert 0 < handoff["direct"] <= link["twice"] - state_bytes  # a half for each piece sent twice
+    assert handoff["twice"] == handoff["populate"] == handoff["parent"] == 0
     traced = {r["variant"]: r["spans"] for r in runs if "spans" in r}
-    assert "reshard/direct" in traced["direct"] and "reshard/scatter" not in traced["direct"]
+    for name in ("direct", "twice"):
+        assert "reshard/direct" in traced[name] and "reshard/scatter" not in traced[name]
+    assert "d2d/put" in traced["direct"] and "d2d/put" not in traced["twice"]
+    assert traced["direct"]["h2d/put"][0] < traced["twice"]["h2d/put"][0]
     assert "reshard/scatter" in traced["populate"] and "reshard/direct" not in traced["populate"]

@@ -2,8 +2,10 @@
 and ``reshard/assemble`` spans, one ``h2d/put`` span a device of a sharded
 leaf, and the counter ``reshard.host_alloc_bytes`` (the local boxes' bytes)
 beside ``bytes_read`` (what the sink gave); and, for a leaf on the direct
-path, ``reshard/direct`` in place of ``reshard/scatter`` and the counter
-``reshard.direct_bytes`` in place of ``reshard.host_alloc_bytes``."""
+path, ``reshard/direct`` in place of ``reshard/scatter`` (an ``h2d/put`` a
+host put and a ``d2d/put`` a box handed to a sibling under it) and the
+counters ``reshard.direct_bytes`` in place of ``reshard.host_alloc_bytes``,
+``reshard.link_bytes`` and ``reshard.handoff_bytes``."""
 
 import jax
 import numpy as np
@@ -13,7 +15,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from torchsnapshot_tpu import PyTreeState, Snapshot, knobs, obs
 from torchsnapshot_tpu.obs import tracer
 
-COUNTERS = (obs.RESHARD_HOST_ALLOC_BYTES, obs.RESHARD_DIRECT_BYTES, obs.BYTES_READ)
+COUNTERS = (
+    obs.RESHARD_HOST_ALLOC_BYTES, obs.RESHARD_DIRECT_BYTES, obs.BYTES_READ,
+    obs.RESHARD_LINK_BYTES, obs.RESHARD_HANDOFF_BYTES,
+)
 
 
 def _mesh(dp, tp):
@@ -127,27 +132,41 @@ def test_a_direct_leafs_pieces_record_reshard_direct_spans(restored_direct):
     populates = by_name["reshard/populate"]
     assert sorted(p.parent_id for p in populates) == sorted(d.parent_id for d in directs)
     assert sum(p.attrs["bytes"] for p in populates) == sum(x.nbytes for x in tree.values())
-    # every put is a child of its piece's span, names its device, and the
-    # puts of a piece sum to the bytes it sent over the link
-    puts = by_name["h2d/put"]
+    # every put and every hand-off is a child of its piece's span and names
+    # its devices; the puts of a piece sum to the bytes it sent over the host
+    # link, its hand-offs to the bytes it moved device to device, and
+    # together they deliver one box a device
+    puts, moves = by_name["h2d/put"], by_name["d2d/put"]
     inside = {d.span_id: d for d in directs}
     assert all(p.parent_id in inside and "device" in p.attrs for p in puts)
+    assert all(m.parent_id in inside and m.attrs["src"] != m.attrs["dst"] for m in moves)
     for d in directs:
         mine = [p for p in puts if p.parent_id == d.span_id]
-        assert len(mine) == d.attrs["devices"]
+        handed = [m for m in moves if m.parent_id == d.span_id]
+        assert len(mine) + len(handed) == d.attrs["devices"]
         assert sum(p.attrs["bytes"] for p in mine) == d.attrs["bytes"]
-    # a column leaf's saved shard goes whole to both devices that share it
-    # (twice the leaf over the link) and is cut there; a row leaf's and the
-    # replicated leaf's boxes are sent as they lie (once a device)
+        assert sum(m.attrs["bytes"] for m in handed) == d.attrs["handoff_bytes"]
+        assert {m.attrs["src"] for m in handed} <= {p.attrs["device"] for p in mine}
+    # a column leaf's saved shard goes whole to ONE of the two devices that
+    # share it (the leaf once over the link), both halves are cut there and
+    # one is handed on; a row leaf's boxes are sent as they lie, each to its
+    # device; the replicated leaf is put once and copied to the other three
     cut = [d for d in directs if d.attrs["cut"]]
-    assert len(cut) == 2 and sum(d.attrs["bytes"] for d in cut) == 2 * saved["cols"].nbytes
+    assert len(cut) == 2 and sum(d.attrs["bytes"] for d in cut) == saved["cols"].nbytes
+    assert sum(d.attrs["handoff_bytes"] for d in cut) == saved["cols"].nbytes // 2
     plain = [d for d in directs if not d.attrs["cut"]]
-    assert sum(d.attrs["bytes"] for d in plain) == saved["rows"].nbytes + 4 * saved["norm"].nbytes
-    assert sorted(p.attrs["device"] for p in puts) == sorted(3 * [d.id for d in jax.devices()[:4]])
+    assert sum(d.attrs["bytes"] for d in plain) == saved["rows"].nbytes + saved["norm"].nbytes
+    assert sum(d.attrs["handoff_bytes"] for d in plain) == 3 * saved["norm"].nbytes
+    assert len(puts) == 7 and len(moves) == 5
+    assert sorted(
+        [p.attrs["device"] for p in puts] + [m.attrs["dst"] for m in moves]
+    ) == sorted(3 * [d.id for d in jax.devices()[:4]])
     state_bytes = sum(x.nbytes for x in tree.values())
     assert gained[obs.RESHARD_DIRECT_BYTES] == state_bytes
     assert gained[obs.RESHARD_HOST_ALLOC_BYTES] == 0
     assert gained[obs.BYTES_READ] == state_bytes
+    assert gained[obs.RESHARD_LINK_BYTES] == state_bytes
+    assert gained[obs.RESHARD_HANDOFF_BYTES] == sum(m.attrs["bytes"] for m in moves)
 
 
 def test_host_alloc_bytes_is_the_sum_of_the_local_boxes(restored):
@@ -159,6 +178,7 @@ def test_host_alloc_bytes_is_the_sum_of_the_local_boxes(restored):
     state_bytes = sum(x.nbytes for x in tree.values())
     assert gained[obs.RESHARD_HOST_ALLOC_BYTES] == local_boxes == state_bytes
     assert gained[obs.RESHARD_DIRECT_BYTES] == 0
+    assert gained[obs.RESHARD_LINK_BYTES] == gained[obs.RESHARD_HANDOFF_BYTES] == 0
     # every saved byte comes from the sink once: a saved shard whose halves
     # go to two devices is still one read
     assert gained[obs.BYTES_READ] == state_bytes

@@ -94,7 +94,9 @@ from .metrics import (  # noqa: F401
     PROMOTION_LAG_S,
     REGISTRY,
     RESHARD_DIRECT_BYTES,
+    RESHARD_HANDOFF_BYTES,
     RESHARD_HOST_ALLOC_BYTES,
+    RESHARD_LINK_BYTES,
     RESHARD_POPULATE_REFUSED,
     RESILIENCE_ABORTS,
     RESILIENCE_BACKOFF_DELAY_S,

@@ -302,6 +302,12 @@ RESHARD_HOST_ALLOC_BYTES = "reshard.host_alloc_bytes"
 # from the read piece as it lies, cut there where needed.  The two sum to
 # the local boxes' bytes of every sharded leaf restored.
 RESHARD_DIRECT_BYTES = "reshard.direct_bytes"
+# What the direct path moved for them: bytes ``device_put`` from host
+# memory (a read piece crosses the host link once, so this is the local
+# boxes' unique bytes), and bytes moved device to device (the boxes of a
+# shared piece that belong to another device than the one it was put on).
+RESHARD_LINK_BYTES = "reshard.link_bytes"
+RESHARD_HANDOFF_BYTES = "reshard.handoff_bytes"
 # Mapped read pieces whose populate (one mlock + munlock over the piece,
 # preparers/sharded.py::_populate) the kernel refused: their pages are
 # left to first touches, as before the populate existed.

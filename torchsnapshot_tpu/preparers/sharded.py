@@ -27,9 +27,10 @@ with a different template sharding.
 A leaf whose every local box lies whole inside ONE read piece takes no
 host assembly buffer at all (``_DirectLeaf``): the consume worker hands
 the piece's bytes, as they lie in the mapped file, to ``jax.device_put``
-for each device that holds the box, and where the box is not a contiguous
-range of the piece (a column range) cuts it out on that device
-(``ops.device_pack.cut_box_on_device``).  Same plan, same reads, same
+ONCE, for one of the devices that hold a box of it; where a box is not a
+contiguous range of the piece (a column range) it is cut out on that
+device (``ops.device_pack.cut_box_on_device``), and a box that another
+device holds goes there device to device.  Same plan, same reads, same
 countdown, same assemble step: only where a piece's bytes go differs.
 """
 
@@ -584,14 +585,60 @@ def _populate(src: np.ndarray) -> None:
                 sp.attrs["refused"] = True
 
 
+class _LinkTally:
+    """Bytes each device has taken over its host link on the direct path,
+    in this process: a read piece that several devices share goes to the
+    one with the fewest so far (ties to the lowest id), so the links stay
+    as even as they are when every device is sent its own copy.  Charged
+    when the receiver is chosen, so pieces in flight on other workers
+    count.  (Process-wide, not a restore's own: ``prepare_read`` plans a
+    leaf at a time and knows no restore; a restore starts from where the
+    last one left the links, which is even.)"""
+
+    def __init__(self) -> None:
+        self._bytes: Dict[int, int] = {}  # device id -> bytes
+        self._lock = threading.Lock()
+
+    def charge_least(self, devs: List[Any], nbytes: int) -> Any:
+        with self._lock:
+            dev = min(devs, key=lambda d: (self._bytes.get(d.id, 0), d.id))
+            self._bytes[dev.id] = self._bytes.get(dev.id, 0) + nbytes
+        return dev
+
+    def snapshot(self) -> Dict[int, int]:
+        with self._lock:
+            return dict(self._bytes)
+
+
+_LINK_TALLY = _LinkTally()
+
+
 class _DirectLeaf:
     """A leaf on the direct path: its boxes as they land on their devices,
     and the way back to the host path.
 
-    The first exception on any piece of the leaf sends the WHOLE leaf
-    down the host path, once: the assembly buffers are made then, boxes
-    already on a device are read back into them, and every later piece
-    scatters on the host.  Counted once in ``exceptions.swallowed``."""
+    A read piece crosses the host link ONCE.  The bytes of it that are
+    sent (a row range that is a box, as it lies; the piece whole for its
+    column boxes) go to one of the devices that want them: the one
+    ``_LINK_TALLY`` has charged the fewest host-link bytes, ties to the
+    lowest id.  Every box is cut out of that buffer there, and a box that
+    belongs to another device (the sibling that shares a column piece, a
+    replica of the box under the template's sharding) is moved device to
+    device (``jax.device_put`` of a device array: a ``d2d/put`` span).
+    At its peak a receiver holds, a worker that serves it: the wide
+    buffer, its own box, and its siblings' boxes until their copies have
+    landed; a sibling holds only its box.
+
+    Counted: ``reshard.link_bytes`` (bytes put from host memory) and
+    ``reshard.handoff_bytes`` (bytes moved device to device); the
+    ``reshard/direct`` span carries ``bytes`` (over the host link),
+    ``handoff_bytes``, ``devices`` (boxes delivered) and ``cut``.
+
+    The first exception on any piece of the leaf (a put, a cut, a
+    hand-off) sends the WHOLE leaf down the host path, once: the assembly
+    buffers are made then, boxes already on a device are read back into
+    them, and every later piece scatters on the host.  Counted once in
+    ``exceptions.swallowed``."""
 
     def __init__(
         self,
@@ -640,41 +687,70 @@ class _DirectLeaf:
 
         from ..ops.device_pack import cut_box_on_device
 
-        # (bytes to send, where the box starts in them or None, its sizes,
-        # the devices that hold it)
+        # (bytes to send, [(where a box starts in them or None, its sizes,
+        # the devices that hold it)]): a row range of the piece is
+        # contiguous bytes of the mapping, exactly one box; the column
+        # boxes all come out of the piece whole
         sends = []
+        columns = []
         for inter, lbox in overlaps:
             devs = self.local_boxes[lbox]
             if is_dim0_slab(inter, read_box):
-                # a row range of the piece (or all of it): contiguous
-                # bytes of the mapping, exactly the box
                 rows = relative_slices(inter, read_box)[:1]
-                sends.append((src[rows] if rows else src, None, None, devs))
+                sends.append((src[rows] if rows else src, [(None, None, devs)]))
             else:
                 start = tuple(i - r for i, r in zip(inter[0], read_box[0]))
-                sends.append((src, start, inter[1], devs))
+                columns.append((start, inter[1], devs))
+        if columns:
+            sends.append((src, columns))
         with obs.span(
             "reshard/direct",
-            bytes=sum(view.nbytes * len(devs) for view, _, _, devs in sends),
-            devices=sum(len(devs) for _, _, _, devs in sends),
-            cut=any(start is not None for _, start, _, _ in sends),
-        ):
+            bytes=sum(view.nbytes for view, _ in sends),
+            devices=sum(len(devs) for _, boxes in sends for _, _, devs in boxes),
+            cut=bool(columns),
+        ) as sp:
             placed: Dict[Any, Any] = {}
-            wide = []
-            for view, start, sizes, devs in sends:
-                for dev in devs:
-                    with obs.span(
-                        "h2d/put", bytes=view.nbytes, device=dev.id
-                    ):
-                        arr = jax.device_put(view, dev)
-                    if start is not None:
-                        wide.append(arr)
-                        arr = cut_box_on_device(arr, start, sizes)
-                    placed[dev] = arr
-            # the worker waits for its piece before it takes the next:
-            # at most one piece's wide buffers a worker are on the devices
+            spent = []  # the wide buffers, and boxes cut for a sibling
+            handoff_bytes = 0
+            for view, boxes in sends:
+                receiver = _LINK_TALLY.charge_least(
+                    [dev for _, _, devs in boxes for dev in devs], view.nbytes
+                )
+                with obs.span(
+                    "h2d/put", bytes=view.nbytes, device=receiver.id
+                ):
+                    arr = jax.device_put(view, receiver)
+                obs.counter(obs.RESHARD_LINK_BYTES).inc(view.nbytes)
+                if boxes is columns:  # the piece whole: a wide buffer
+                    spent.append(arr)
+                for start, sizes, devs in boxes:
+                    box = (
+                        arr
+                        if start is None
+                        else cut_box_on_device(arr, start, sizes)
+                    )
+                    if receiver not in devs:
+                        spent.append(box)
+                    for dev in devs:
+                        if dev == receiver:
+                            placed[dev] = box
+                            continue
+                        with obs.span(
+                            "d2d/put",
+                            bytes=box.nbytes,
+                            src=receiver.id,
+                            dst=dev.id,
+                        ):
+                            placed[dev] = jax.device_put(box, dev)
+                        handoff_bytes += box.nbytes
+            obs.counter(obs.RESHARD_HANDOFF_BYTES).inc(handoff_bytes)
+            if sp is not None:
+                sp.attrs["handoff_bytes"] = handoff_bytes
+            # the worker waits for its piece before it takes the next: at
+            # most one piece's wide buffer and hand-offs a worker are on
+            # the devices
             jax.block_until_ready(list(placed.values()))
-            for arr in wide:
+            for arr in spent:
                 arr.delete()
         return placed
 

@@ -2,15 +2,24 @@
 drives the checkpointer through its public entry points as the cell's
 traffic file says, and reduces what it saw to the result's last line.
 
-It holds no table of cells, configurations, mixes or per-layer metrics: a
-cell is an entry of ``workloads``; its configuration is the ``file`` of the
-entry of ``configs``; its mix is ``traffic/<traffic>.json`` and each
-per-layer metric ``metrics/<name>.py`` under one of ``paths``.
+It holds no table of cells, configurations, states, mixes or per-layer
+metrics: a cell is an entry of ``workloads``; its configuration is the
+``file`` of the entry of ``configs``; its mix is ``traffic/<traffic>.json``,
+each per-layer metric ``metrics/<name>.py`` and its state ``states/<name>.py``,
+``<name>`` being the configuration file's key ``"state"``, under one of
+``paths``.
+
+A state file gives ``factory(conf, mesh)``, whose object has ``mesh``,
+``shardings``, ``make(seed) -> tree`` (one jitted call, born with its
+shardings), ``step(tree, batch) -> (tree, loss)`` (jitted, argument 0
+donated, the loss a scalar), ``batch_pool(seed, batch, n) -> list`` (on the
+device; ``batch`` is the mix's, handed through as it is), and a dict
+``TINY``: the keys that cut such a configuration to a size a CPU test runs.
 
 The one general generator (``Driver``) knows five operations, and a traffic
 file is an arrangement of them with its parameters:
 
-    step     one donated train step on the next token batch, loss read back
+    step     one donated train step on the next batch, loss read back
     take     blocking ``Snapshot.take`` of the live state to a new directory
     cycle    ``Snapshot.async_take``, donated steps until ``done()``, ``wait()``
     drop     let go of the live state
@@ -58,6 +67,39 @@ class NoSink(RuntimeError):
 # ----------------------------------------------------------------- discovery
 
 
+def find_file(root: str, paths: List[str], sub: str, filename: str) -> str:
+    for base in paths:
+        path = os.path.join(root, base, sub, filename)
+        if os.path.isfile(path):
+            return path
+    raise FileNotFoundError(f"no {os.path.join(sub, filename)} under {paths}")
+
+
+def load_file(path: str, kind: str):
+    """The module of one metric's reader or one state, found by its name."""
+    name = os.path.basename(path)[:-3].replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(f"chipbench_{kind}_{name}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def state_file(root: str, paths: List[str], conf: Dict[str, Any]) -> str:
+    """The state file a configuration names.  There is no default: one that
+    names none, or one that is not there, is an error."""
+    if not conf.get("state"):
+        raise KeyError(
+            'the configuration names no state: its key "state" is the <name> '
+            f"of a file states/<name>.py under {paths}"
+        )
+    return find_file(root, paths, "states", conf["state"] + ".py")
+
+
+def load_state(root: str, paths: List[str], conf: Dict[str, Any]):
+    """The module of the state file a configuration names."""
+    return load_file(state_file(root, paths, conf), "state")
+
+
 class Cell:
     """One entry of ``workloads`` with the files its names lead to."""
 
@@ -78,13 +120,10 @@ class Cell:
             self._find("traffic", self.workload["traffic"] + ".json")
         )
         self.peaks = state.load_json(self._find("", "peaks.json"))
+        self.state = load_state(root, self.spec["paths"], self.config)
 
     def _find(self, sub: str, filename: str) -> str:
-        for base in self.spec["paths"]:
-            path = os.path.join(self.root, base, sub, filename)
-            if os.path.isfile(path):
-                return path
-        raise FileNotFoundError(f"no {os.path.join(sub, filename)} under {self.spec['paths']}")
+        return find_file(self.root, self.spec["paths"], sub, filename)
 
     def _listed(self, table: str) -> List[Dict[str, Any]]:
         return [
@@ -99,13 +138,11 @@ class Cell:
         return self._listed("per_layer")
 
     def reader(self, metric: str) -> Callable[[Any], Optional[float]]:
-        path = self._find("metrics", metric + ".py")
-        spec = importlib.util.spec_from_file_location(
-            "chipbench_metric_" + metric.replace(".", "_").replace("-", "_"), path
-        )
-        module = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(module)
-        return module.read
+        return load_file(self._find("metrics", metric + ".py"), "metric").read
+
+    def state_factory(self, mesh):
+        """Makes the configuration's states under ``mesh``."""
+        return self.state.factory(self.config, mesh)
 
     def peak_of(self, device_kind: str) -> Dict[str, Any]:
         if device_kind not in self.peaks:
@@ -253,25 +290,16 @@ class Driver:
         self, cell: Cell, seed: int, devices, snap_root: str,
         fault: Optional[str] = None,
     ) -> None:
-        import jax
-
-        from torchsnapshot_tpu.models.transformer import train_step
-
         mix = cell.traffic
         self.seed = seed
         self.snap_root, self.fault = snap_root, fault
         self.traced = self.in_window = False
-        self.save = state.StateFactory(
-            cell.config, state.build_mesh(devices, *mix["save_mesh"])
-        )
+        self.save = cell.state_factory(state.build_mesh(devices, *mix["save_mesh"]))
         self.rest = (
             self.save if mix["restore_mesh"] == mix["save_mesh"]
-            else state.StateFactory(
-                cell.config, state.build_mesh(devices, *mix["restore_mesh"])
-            )
+            else cell.state_factory(state.build_mesh(devices, *mix["restore_mesh"]))
         )
-        self.step_fn = jax.jit(train_step, donate_argnums=0)
-        self.tokens = self.save.token_pool(seed, tuple(mix["batch"]), 16)
+        self.batches = self.save.batch_pool(seed, mix["batch"], 16)
         self.digest = state.Digester()
         picks = mix.get("check", {"loops": 0, "below": 1})
         # which timed restores are held against the reference, drawn from
@@ -366,10 +394,10 @@ class Driver:
     def step(self, in_flight: bool = False) -> float:
         """A step while a save drains is a ``step`` record for the
         arithmetic, under the span ``drain`` in the trace."""
-        tokens = self.tokens[self.steps_done % len(self.tokens)]
+        batch = self.batches[self.steps_done % len(self.batches)]
         with self._op("step", span="drain" if in_flight else None, in_flight=in_flight):
             with self.save.mesh:
-                self.ts, loss = self.step_fn(self.ts, tokens)
+                self.ts, loss = self.save.step(self.ts, batch)
             loss = float(loss)
         self.steps_done += 1
         if not np.isfinite(loss):

@@ -1,6 +1,9 @@
 """A checkout in miniature for the harness's own tests: the benchmark's
-files as committed, with every configuration cut to tiny widths and every
-mix to a tiny batch, so that a whole run fits a CPU test."""
+files as committed, with every configuration cut to tiny widths by its own
+state file's ``TINY`` and every mix to a tiny batch, so that a whole run fits
+a CPU test.  A mix that checks timed restores counts its loops there
+(``window.max_loops``), never seconds, so that ``correct`` cannot depend on
+a loaded worker's clock."""
 
 import json
 import os
@@ -13,11 +16,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-TINY = dict(
-    hidden_size=64, num_attention_heads=4, num_key_value_heads=4, head_dim=16,
-    intermediate_size=128, vocab_size=256, num_hidden_layers=2,
-    max_position_embeddings=64,
-)
+LOOPS = 3  # timed restores of a tiny window: all that a tiny mix draws its checks from
 
 
 def _rewrite(path, change):
@@ -74,6 +73,40 @@ def full_spec(repo, benchmark_json):
     return spec
 
 
+@pytest.fixture(scope="session")
+def dense_lm(repo, benchmark_json):
+    from chipbench import bench
+
+    return bench.load_state(repo, benchmark_json["paths"], {"state": "dense_lm"})
+
+
+def cut_to_tiny(root, paths, file):
+    """Cuts one configuration file of a checkout by its own state file's ``TINY``."""
+    from chipbench import bench
+
+    _rewrite(
+        os.path.join(root, file),
+        lambda c: c.update(bench.load_state(root, paths, c).TINY),
+    )
+
+
+def shrink_mix(path):
+    """Cuts one mix of a checkout to a tiny window: LOOPS restores, whatever
+    the clock says, with its checks sampled among them.  ``batch`` is the
+    state's to read, so only the token shape of the committed mixes, two
+    whole numbers, is made small; a batch said another way stays as it is."""
+
+    def shrink(mix):
+        if isinstance(mix["batch"], list) and len(mix["batch"]) == 2:
+            mix["batch"] = [2, 16]
+        if "check" in mix:
+            mix["check"] = {"loops": 2, "below": LOOPS}
+            mix["answers_checked_least"] = 3
+            mix["window"]["max_loops"] = LOOPS
+
+    _rewrite(path, shrink)
+
+
 @pytest.fixture(scope="module")
 def tiny_root(tmp_path_factory, repo, full_spec):
     root = str(tmp_path_factory.mktemp("checkout"))
@@ -81,17 +114,10 @@ def tiny_root(tmp_path_factory, repo, full_spec):
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(full_spec, f)
     for config in full_spec["configs"]:
-        _rewrite(os.path.join(root, config["file"]), lambda c: c.update(TINY))
+        cut_to_tiny(root, full_spec["paths"], config["file"])
     traffic = os.path.join(root, "chipbench", "traffic")
-    def shrink(mix):
-        # a tiny window holds a handful of restores: sample among the first
-        mix["batch"] = [2, 16]
-        if "check" in mix:
-            mix["check"] = {"loops": 2, "below": 3}
-            mix["answers_checked_least"] = 3
-
     for name in os.listdir(traffic):
-        _rewrite(os.path.join(traffic, name), shrink)
+        shrink_mix(os.path.join(traffic, name))
     return root
 
 
@@ -99,7 +125,11 @@ def tiny_root(tmp_path_factory, repo, full_spec):
 def run_tiny(tiny_root):
     from chipbench import bench
 
-    def run(workload, trace=False, fault=None, seed=2**31 + 7, seconds=0.4):
+    def run(workload, trace=False, fault=None, seed=2**31 + 7, seconds=None):
+        if seconds is None:
+            # an hour where the loop's cap ends the window, not the clock
+            capped = "max_loops" in bench.Cell(tiny_root, workload).traffic["window"]
+            seconds = 3600.0 if capped else 0.4
         return bench.run_cell(
             tiny_root, workload, seed=seed, seconds=seconds, trace=trace,
             allow_cpu=True, fault=fault,

@@ -3,10 +3,9 @@ keeps Ouro-2.6B's published widths, its mix is the one PERF.md states, and
 whole runs of it at tiny widths on the CPU's virtual devices (saved under
 2x2, restored under 1x4) come out correct, report the cell's per-layer
 metrics and catch the control and a planted fault.  The tiny runs count
-loops (``window.max_loops``), never seconds, so that ``correct`` cannot
-depend on a loaded worker's clock."""
+loops (``window.max_loops``, set by ``conftest.tiny_root``), never seconds,
+so that ``correct`` cannot depend on a loaded worker's clock."""
 
-import json
 import os
 
 import pytest
@@ -33,7 +32,7 @@ LOOPS = 3
 # ------------------------------------------------- the files as committed
 
 
-def test_the_configuration_keeps_the_published_widths(repo, benchmark_json):
+def test_the_configuration_keeps_the_published_widths(repo, benchmark_json, dense_lm):
     entry = {c["name"]: c for c in benchmark_json["configs"]}[CONFIG]
     conf = state.load_json(os.path.join(repo, entry["file"]))
     for key, value in PUBLISHED.items():
@@ -45,7 +44,8 @@ def test_the_configuration_keeps_the_published_widths(repo, benchmark_json):
     for key in entry["reduced"]:
         assert not key.endswith(("_dim", "_rank", "_size")), key
     assert len(conf["layer_types"]) == conf["num_hidden_layers"]
-    cfg = state.model_config(conf)
+    assert conf["state"] == "dense_lm"
+    cfg = dense_lm.model_config(conf)
     assert (cfg.d_model, cfg.n_heads, cfg.d_ff, cfg.vocab) == (2048, 16, 5632, 49152)
     for stated in ("published", "assumed", "deployment", "storage", "guarantees"):
         assert conf[stated], stated
@@ -107,14 +107,9 @@ def test_the_reference_imports_nothing_of_the_program(repo):
 
 @pytest.fixture(scope="module")
 def elastic_root(tiny_root):
-    """The miniature checkout with its copy of the cell's mix (cut to a tiny
-    batch by ``tiny_root``) held to ``LOOPS`` restores whatever the clock says."""
-    path = os.path.join(tiny_root, MIX)
-    with open(path) as f:
-        mix = json.load(f)
-    mix["window"]["max_loops"] = LOOPS
-    with open(path, "w") as f:
-        json.dump(mix, f)
+    """The miniature checkout: its copy of the cell's mix is cut to a tiny
+    batch and held to ``LOOPS`` restores whatever the clock says."""
+    assert state.load_json(os.path.join(tiny_root, MIX))["window"]["max_loops"] == LOOPS
     return tiny_root
 
 

@@ -96,7 +96,7 @@ def test_an_unknown_workload_is_an_error(repo):
 
 
 @pytest.mark.parametrize("config", ["ouro-2.6b-d3", "ouro-2.6b-d4", "ouro-2.6b-d9", "ouro-2.6b-d32"])
-def test_configuration_keeps_the_published_widths(repo, full_spec, config):
+def test_configuration_keeps_the_published_widths(repo, full_spec, dense_lm, config):
     entry = {c["name"]: c for c in full_spec["configs"]}[config]
     conf = state.load_json(os.path.join(repo, entry["file"]))
     for key, value in PUBLISHED.items():
@@ -106,7 +106,8 @@ def test_configuration_keeps_the_published_widths(repo, full_spec, config):
     for key in entry["reduced"]:
         assert not key.endswith(("_dim", "_rank", "_size")), key
     assert len(conf["layer_types"]) == conf["num_hidden_layers"]
-    cfg = state.model_config(conf)
+    assert conf["state"] == "dense_lm"
+    cfg = dense_lm.model_config(conf)
     assert (cfg.d_model, cfg.n_heads, cfg.d_ff, cfg.vocab) == (2048, 16, 5632, 49152)
 
 

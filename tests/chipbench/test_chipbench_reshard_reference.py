@@ -14,25 +14,23 @@ import pytest
 from chipbench import state
 from chipbench.reference import reshard
 
-from conftest import TINY
-
 LAYOUTS = [((2, 2), (1, 4)), ((2, 2), (4, 1)), ((2, 2), (2, 2)), ((1, 4), (2, 2))]
 
 
 @pytest.fixture(scope="module")
-def conf(repo):
+def conf(repo, dense_lm):
     conf = state.load_json(os.path.join(repo, "chipbench/configs/ouro-2.6b-4chip.json"))
-    conf.update(TINY)
+    conf.update(dense_lm.TINY)
     return conf
 
 
 @pytest.fixture(scope="module")
-def factories(conf):
+def factories(conf, dense_lm):
     made = {}
 
     def factory(mesh):
         if mesh not in made:
-            made[mesh] = state.StateFactory(conf, state.build_mesh(jax.devices(), *mesh))
+            made[mesh] = dense_lm.factory(conf, state.build_mesh(jax.devices(), *mesh))
         return made[mesh]
 
     return factory

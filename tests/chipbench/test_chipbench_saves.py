@@ -201,17 +201,27 @@ def test_a_mix_without_the_key_keeps_its_snapshots_under_tmpdir(
     assert fs == f"{bench._fs_type(str(tmp_path))} {tmp_path}"
 
 
-def test_the_committed_mixes_that_name_a_sink(repo):
-    mixes = {
-        name[:-5]: json.load(open(os.path.join(repo, "chipbench", "traffic", name)))
-        for name in os.listdir(os.path.join(repo, "chipbench", "traffic"))
-    }
-    assert {name: mix.get("sink") for name, mix in mixes.items()} == {
-        "kill_resume": None, "reshard_resume": None,
-        "preempt_sync_save": "ram", "async_save_train": "ram",
-    }
+TRAFFIC = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    "chipbench", "traffic",
+)
+# the sinks of the mixes that were there when this was written; a mix that a
+# later PR commits is one more case, and needs no line here
+SINKS = {
+    "kill_resume": None, "reshard_resume": None, "preempt_sync_save": "ram",
+    "async_save_train": "ram", "elastic_resume": "ram",
+}
+
+
+@pytest.mark.parametrize("name", sorted(f[:-5] for f in os.listdir(TRAFFIC)))
+def test_the_committed_mixes_that_name_a_sink(name):
+    with open(os.path.join(TRAFFIC, name + ".json")) as f:
+        mix = json.load(f)
+    sink = mix.get("sink")
+    assert sink in (None, "ram")
+    assert sink == SINKS.get(name, sink)
     # a mix that names the RAM tier names the deployment that has one
-    assert all("source" in mix for mix in mixes.values() if mix.get("sink"))
+    assert sink is None or mix["source"]
 
 
 def test_a_ram_sink_that_may_not_be_mounted_is_nothing(tmp_path, monkeypatch):

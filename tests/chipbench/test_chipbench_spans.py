@@ -205,8 +205,10 @@ def traced_run(tmp_path_factory, repo, benchmark_json):
     for config in benchmark_json["configs"]:
         rewrite(os.path.join(root, config["file"]), lambda c: c.update(SMALL))
     mix = os.path.join(root, "chipbench", "traffic", "kill_resume.json")
+    # eight restores, whatever a loaded worker's clock says
     rewrite(mix, lambda m: m.update(
-        batch=[2, 16], check={"loops": 2, "below": 3}, answers_checked_least=3
+        batch=[2, 16], check={"loops": 2, "below": 3}, answers_checked_least=3,
+        window={"loop": ["restore"], "max_loops": 8},
     ))
     seen = {}
     context = bench.Context
@@ -217,7 +219,7 @@ def traced_run(tmp_path_factory, repo, benchmark_json):
 
     bench.Context = keep
     try:
-        result = bench.run_cell(root, CELL, seed=2**31 + 11, seconds=1.0, trace=True, allow_cpu=True)
+        result = bench.run_cell(root, CELL, seed=2**31 + 11, seconds=3600.0, trace=True, allow_cpu=True)
     finally:
         bench.Context = context
     return result, seen["ctx"]

@@ -2,7 +2,8 @@
 elements, so members join (and leave) a slab by a same-width bitcast that
 moves nothing — the byte-granular ``uint8[n, itemsize]`` form it replaced
 could not be loaded on a TPU beside a real train state (PERF.md, PR 21).
-There is no second form: anything else is left to the host path."""
+There is no second form: anything else is left to the host path.  (A slab
+of 2-byte members is handed to the host as pairs in 4-byte words.)"""
 
 import numpy as np
 import pytest
@@ -57,11 +58,41 @@ def test_pack_is_the_serialized_bytes_in_words_of_member_width(case):
     dtypes, word_bytes = _CASES[case]
     arrays = _arrays(dtypes)
     want = b"".join(np.asarray(a).tobytes() for a in arrays)
-    # on the device the slab is words of the members' width...
-    assert device_pack._pack(arrays).dtype == np.dtype(f"uint{8 * word_bytes}")
+    # on the device the slab is words of the members' width (2-byte
+    # members join as such and leave for the host as pairs in 4-byte words:
+    # a device→host copy of narrower words is slow on a TPU)...
+    leaves_as = 4 if word_bytes == 2 else word_bytes
+    assert device_pack._pack(arrays).dtype == np.dtype(f"uint{8 * leaves_as}")
     # ...and the host reads exactly the per-array serialization
+    before = _counters()
     slab = pack_arrays_to_host(arrays)
     assert slab.dtype == np.uint8 and slab.tobytes() == want
+    # counted under the members' width, by their bytes
+    assert _counters().get(f"device_pack.bytes_w{word_bytes}", 0) - before.get(
+        f"device_pack.bytes_w{word_bytes}", 0) == len(want)
+
+
+def _counters():
+    from torchsnapshot_tpu import obs
+
+    return dict(obs.metrics_snapshot()["counters"])
+
+
+@pytest.mark.parametrize("elements", [1, 2, 255, 256, 257, 511, 40_000, 131_073])
+def test_two_byte_members_travel_as_pairs_and_arrive_as_their_bytes(elements):
+    """Whatever the element count (odd, under one row of pairs, a row and
+    one): the words the device makes are the members' bytes in memory
+    order, zeros after them, and the host keeps the members' bytes alone."""
+    rng = np.random.default_rng(elements)
+    # every 16-bit pattern but the NaNs (a backend may quieten those)
+    bits = rng.integers(0, 0x7F80, size=elements, dtype=np.uint16)
+    arrays = [jnp.asarray(bits.view(ml_dtypes.bfloat16)), jnp.asarray(bits[::-1].view(np.int16))]
+    want = bits.tobytes() + bits[::-1].tobytes()
+    words = np.asarray(device_pack._pack(arrays))
+    assert words.dtype == np.uint32 and words.size % 128 == 0
+    raw = words.tobytes()
+    assert raw[: len(want)] == want and not any(raw[len(want):])
+    assert pack_arrays_to_host(arrays).tobytes() == want
 
 
 @pytest.mark.parametrize("case", sorted(_CASES))

@@ -47,6 +47,8 @@ DYNAMIC_FAMILIES = (
     "storage.*.*_latency_s",
     "storage.codec.encode_latency_s.*",
     "storage.codec.decode_latency_s.*",
+    "device_pack.bytes_w*",
+    "device_unpack.bytes_w*",
 )
 
 

@@ -66,15 +66,18 @@ class BatchedBufferStager(BufferStager):
         self._all_jax = all(
             isinstance(s, JaxArrayBufferStager) for s, _ in stagers
         )
-        self._device_packable = self._all_jax and (
-            len({_pack_group(s) for s, _ in stagers}) == 1
-        )
+        groups = {_pack_group(s) for s, _ in stagers} if self._all_jax else ()
+        self._device_packable = len(groups) == 1
+        # element width of a slab the device can pack; 0: host members,
+        # several widths or several devices
+        self._width = next(iter(groups))[1] if self._device_packable else 0
 
     async def stage_buffer(self, executor: Optional[Executor] = None) -> memoryview:
         with obs.span(
             "pipeline/slab_pack",
             members=len(self.stagers),
             bytes=self.total,
+            width=self._width,
         ):
             buf = await self._stage_buffer_impl(executor)
         obs.counter(obs.SLABS_PACKED).inc()
@@ -173,6 +176,7 @@ class BatchedBufferStager(BufferStager):
         if piece_digests:
             self.piece_digests = piece_digests
         self.stagers = []
+        obs.counter(obs.SLAB_HOST_PACK_BYTES).inc(self.total)
         return memoryview(slab)
 
     def _any_member_on_host(self) -> bool:
@@ -390,6 +394,7 @@ class _MergedRangeConsumer(BufferConsumer):
                 done = self._try_device_unpack(view)
             if done:
                 return
+        obs.counter(obs.SLAB_HOST_UNPACK_BYTES).inc(view.nbytes)
         for req, start, end in self.subs:
             piece = view[start - self.base : end - self.base]
             await req.buffer_consumer.consume_buffer(piece, executor)

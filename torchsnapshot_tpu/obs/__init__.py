@@ -105,6 +105,8 @@ from .metrics import (  # noqa: F401
     RESILIENCE_RETRIES,
     RSS_PEAK_DELTA_BYTES,
     SLABS_PACKED,
+    SLAB_HOST_PACK_BYTES,
+    SLAB_HOST_UNPACK_BYTES,
     STRIPE_ABORTS,
     STRIPE_BYTES_READ,
     STRIPE_BYTES_WRITTEN,

@@ -52,6 +52,15 @@ BUDGET_BYTES_IN_USE_READ = "budget_bytes_in_use_read"
 IO_QUEUE_DEPTH_READ = "io_queue_depth_read"
 RSS_PEAK_DELTA_BYTES = "rss_peak_delta_bytes"
 SLABS_PACKED = "slabs_packed"
+# Which path a slab's bytes took (batcher.py, ops/device_pack.py).  Bytes
+# a COMPLETED device pack / unpack program moved are counted by element
+# width under ``device_pack.bytes_w<width>`` / ``device_unpack.bytes_w<width>``
+# (``..._w2``, ``..._w4``; a family, named where it is raised); these two
+# count the bytes of the slabs that packed / unpacked on the host instead,
+# by choice (mixed widths, host members, a CPU backend's unpack) or by
+# fallback (a device program that failed).
+SLAB_HOST_PACK_BYTES = "slab.host_pack_bytes"
+SLAB_HOST_UNPACK_BYTES = "slab.host_unpack_bytes"
 # tiered storage (tier/): read-path residency + write-back promotion.
 # hits/misses count tier-plugin reads served by the fast tier vs fallen
 # back (peer or durable); repairs count fast-tier copies rewritten from

@@ -8,10 +8,12 @@ instead of many small ones, and the pack itself runs at HBM bandwidth.
 XLA caches the compiled pack per shape-tuple, so steady-state checkpoints
 (same model every time) pay compilation once.
 
-The slab travels as unsigned WORDS as wide as its members' elements, and
+The slab is made of unsigned WORDS as wide as its members' elements, and
 the host reinterprets the words as bytes for free.  Every member of a
 slab has the same element width (the batcher groups by ``packed_width``)
-and converts with a same-width bitcast, which moves nothing.  The
+and converts with a same-width bitcast, which moves nothing.  A slab of
+2-byte members then leaves for the host as pairs in 4-byte words
+(``_pairs_as_words``): the copy of narrower words is the slow one.  The
 byte-granular form this replaces (``bitcast_convert_type(x, uint8)``
 through a ``uint8[n, itemsize]`` temporary) is tiled with its 4-byte
 minor dimension padded to a full lane row on a TPU: one 64 MiB float32
@@ -92,7 +94,37 @@ def _pack(arrays: List[Any]):
             # complex bytes are interleaved (real, imag) component pairs
             flat = jnp.stack([flat.real, flat.imag], axis=-1).reshape(-1)
         parts.append(lax.bitcast_convert_type(flat, word))  # same width
-    return jnp.concatenate(parts) if len(parts) > 1 else parts[0]
+    packed = jnp.concatenate(parts) if len(parts) > 1 else parts[0]
+    return _pairs_as_words(packed) if word == np.uint16 else packed
+
+
+_PAIR_LANES = 256
+
+
+def _pairs_as_words(x):
+    """uint16[n] → uint32[ceil(n / 256) * 128], word i = x[2i] | x[2i+1] << 16:
+    the same bytes in a little-endian host's memory, zeros after the n-th
+    element.  A 2-byte slab travels so because the device→host copy of any
+    array of 1- or 2-byte elements ran at 0.68 GB/s on a TPU v5e, whatever
+    its shape, where 4-byte words ran at 3.2 (one 135 MB slab: 195–215 ms
+    against 59–71 with the 17 ms this costs the device; PERF.md, PR 36).
+    Two transposes put the even and the odd elements on rows of their own;
+    the plain form (``bitcast_convert_type(x.reshape(-1, 2), uint32)``) has
+    a minor dimension of 2, which a TPU pads to a lane row: 17 GB of
+    scratch for that slab."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    n = x.shape[0]
+    rows = -(-n // _PAIR_LANES)
+    if rows * _PAIR_LANES != n:
+        x = jnp.concatenate([x, jnp.zeros((rows * _PAIR_LANES - n,), x.dtype)])
+    by_lane = x.reshape(rows, _PAIR_LANES).T  # row l: lane l of every row
+    even, odd = (
+        lax.slice(by_lane, (first, 0), by_lane.shape, (2, 1)).astype(jnp.uint32)
+        for first in (0, 1)
+    )
+    return (even | (odd << 16)).T.reshape(-1)
 
 
 _pack_jit = None
@@ -126,6 +158,7 @@ def pack_arrays_to_host(arrays: List[Any]) -> np.ndarray:
         if _pack_jit is None:
             _pack_jit = jax.jit(_pack)
         pack_fn = _pack_jit
+    nbytes = sum(a.nbytes for a in arrays)
     packed = pack_fn(arrays)
     # the slab's host array is made by whichever of the two calls comes to
     # it first: both inside the arena
@@ -137,8 +170,10 @@ def pack_arrays_to_host(arrays: List[Any]) -> np.ndarray:
         # materializes (async failures surface here); the host reads the
         # words as the bytes they are
         with obs.span("d2h/copy", bytes=packed.nbytes):
-            out = np.asarray(packed).view(np.uint8)
+            # a 2-byte slab comes padded to whole rows of word pairs
+            out = np.asarray(packed).view(np.uint8)[:nbytes]
     _count("pack")
+    obs.counter(f"device_pack.bytes_w{packed_width(arrays[0].dtype)}").inc(nbytes)
     return out
 
 
@@ -352,10 +387,11 @@ def unpack_slab_to_device(buf, members, out_dtypes, device) -> List[Any]:
         slab = jax.device_put(u8.view(_word(word_bytes)), device)
     # the per-member programs (compiled lazily on this executor thread
     # at first use) compile and dispatch while the slab's DMA runs
-    with obs.span("unpack/dispatch", members=len(members)):
+    with obs.span("unpack/dispatch", members=len(members), width=word_bytes):
         out = [
             fn(slab, np.int32(off // word_bytes))
             for fn, (off, _, _) in zip(fns, members)
         ]
     _count("unpack")  # after dispatch succeeded — fallbacks must not count
+    obs.counter(f"device_unpack.bytes_w{word_bytes}").inc(u8.nbytes)
     return out

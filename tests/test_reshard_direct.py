@@ -455,6 +455,67 @@ def test_a_cut_moves_words_of_every_dtype(dtype):
     np.testing.assert_array_equal(np.asarray(out), raw[1:3, 4:12])
 
 
+# A cut is handed its starts as numpy scalars, which jit transfers inside the
+# call: ``device_unpack.arg_puts`` counts them where they are made (PR 37: the
+# one-vector-a-piece form the slabs take was measured on the four-chip cell,
+# read slower, and was taken out again).
+
+@pytest.mark.parametrize(
+    "dtype", ["float32", "int32", "bfloat16", "float16", "int8", "bool", "complex64"]
+)
+def test_a_pieces_cuts_share_a_program_and_count_the_scalars_they_are_handed(dtype):
+    import ml_dtypes  # noqa: F401 — registers bfloat16
+
+    raw = np.random.default_rng(12).integers(0, 2, size=(6, 16)).astype(np.dtype(dtype))
+    wide = jnp.asarray(raw)
+    boxes = [((0, 4 * i), (6, 4)) for i in range(4)] + [((2, 3), (3, 9))]
+    device_pack._jitted_cut.cache_clear()
+    before = _counter("device_unpack.arg_puts"), device_pack.CALL_COUNTS["unpack"]
+    outs = [device_pack.cut_box_on_device(wide, st, sz) for st, sz in boxes]
+    # two starts a cut of a matrix, one cut a box
+    assert _counter("device_unpack.arg_puts") - before[0] == 10
+    assert device_pack.CALL_COUNTS["unpack"] - before[1] == 5
+    for out, ((r, c), (nr, nc)) in zip(outs, boxes):
+        assert out.dtype == raw.dtype
+        assert np.asarray(out).tobytes() == raw[r : r + nr, c : c + nc].tobytes()
+    # the four quarters share a program: no executable an offset value
+    assert device_pack._jitted_cut.cache_info().currsize == 2
+    quarter = device_pack._jitted_cut((6, 16), str(raw.dtype), (6, 4))
+    assert quarter._cache_size() == 1
+
+
+@pytest.mark.parametrize(
+    "starts, sizes", [((0, 9), (8, 8)), ((-1, 0), (8, 8)), ((0,), (8,)), ((1, 0), (8, 4))]
+)
+def test_a_box_outside_the_piece_raises_with_nothing_handed_over(starts, sizes):
+    wide = jnp.arange(128, dtype=jnp.float32).reshape(8, 16)
+    before = _counter("device_unpack.arg_puts")
+    with pytest.raises(ValueError, match="outside"):
+        device_pack.cut_box_on_device(wide, starts, sizes)
+    assert _counter("device_unpack.arg_puts") == before
+
+
+def test_a_restore_counts_two_scalars_a_cut_and_none_for_a_row_piece(tmp_path):
+    """Column leaves and a row leaf saved under 2x2, restored under 1x4:
+    the counter gains the cuts' starts, a row piece (a box as it lies, no
+    program) nothing."""
+    values = {f"w{i}": _value("cols", seed=30 + i) for i in range(3)}
+    values["r"] = _value("rows", seed=33)
+    kinds = {k: ("rows" if k == "r" else "cols") for k in values}
+    saved = {k: _put(kinds[k], _mesh(2, 2), v) for k, v in values.items()}
+    Snapshot.take(str(tmp_path / "s"), {"app": StateDict(**saved)})
+    templates = {k: _put(kinds[k], _mesh(1, 4), np.zeros_like(v)) for k, v in values.items()}
+    dest = StateDict(**templates)
+    before = _counter("device_unpack.arg_puts")
+    with knobs.override_device_unpack(True), _Gained() as g:
+        Snapshot(str(tmp_path / "s")).restore({"app": dest})
+    for k, v in values.items():
+        _assert_restored(dest[k], v, templates[k])
+    # 3 column leaves x 2 pieces x 2 boxes; the row leaf's pieces are boxes as they lie
+    assert (g.cuts, g.swallowed, g.host) == (12, 0, 0)
+    assert _counter("device_unpack.arg_puts") - before == 24
+
+
 def test_the_ab_script_restores_one_snapshot_by_each_mechanism(tmp_path, capsys):
     """``benchmarks/reshard_ab.py`` at tiny widths: every restore of a
     variant takes that variant's path and none other, and comes out

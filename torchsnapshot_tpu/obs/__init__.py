@@ -77,6 +77,7 @@ from .metrics import (  # noqa: F401
     CONTINUOUS_RESTORES_FROM_PEER,
     CONTINUOUS_STEP_OVERHEAD_S,
     CONTINUOUS_STEPS,
+    DEVICE_UNPACK_ARG_PUTS,
     EVENT_HANDLER_ERRORS,
     EXCEPTIONS_SWALLOWED,
     FASTIO_BUFFERED_PARTS,

@@ -61,6 +61,12 @@ SLABS_PACKED = "slabs_packed"
 # fallback (a device program that failed).
 SLAB_HOST_PACK_BYTES = "slab.host_pack_bytes"
 SLAB_HOST_UNPACK_BYTES = "slab.host_unpack_bytes"
+# Host→device transfers of the ARGUMENTS of a restore's device programs,
+# other than the slab or the piece itself (ops/device_pack.py): a slab's
+# member offsets go up as one int32 vector, so one a slab; a cut is
+# handed its starts as numpy scalars, which jit transfers inside the
+# call, so one a dimension a cut.
+DEVICE_UNPACK_ARG_PUTS = "device_unpack.arg_puts"
 # tiered storage (tier/): read-path residency + write-back promotion.
 # hits/misses count tier-plugin reads served by the fast tier vs fallen
 # back (peer or durable); repairs count fast-tier copies rewritten from

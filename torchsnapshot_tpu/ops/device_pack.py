@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import functools
 import threading
+import time
 from typing import Any, List, Optional
 
 import numpy as np
@@ -143,6 +144,13 @@ def _count(kind: str) -> None:
         CALL_COUNTS[kind] += 1
 
 
+# host→device transfers of the device programs' ARGUMENTS (a slab's offset
+# vector; the numpy scalars a cut is handed), as against the slabs and
+# pieces themselves.  Made with the module, so a process that restored on
+# the host path reads 0, not an absence.
+_ARG_PUTS = obs.counter(obs.DEVICE_UNPACK_ARG_PUTS)
+
+
 def pack_arrays_to_host(arrays: List[Any]) -> np.ndarray:
     """Pack device arrays of ONE element width into one uint8 host buffer
     (C-order bytes of each array, concatenated).  Raises on mixed widths
@@ -179,6 +187,46 @@ def pack_arrays_to_host(arrays: List[Any]) -> np.ndarray:
 
 # ------------------------------------------------------------- unpack
 
+# a split program has at most this many outputs (a slab of more members,
+# thousands of tiny leaves, sends a vector a chunk); lengths are padded to
+# a power of two, so a process compiles at most seven of them
+_SPLIT_MAX = 64
+
+
+# members of one signature a call of their unpack program, at most
+_GROUP_MAX = 32
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_split(n):
+    """``int32[n]`` → n ``int32[]`` arrays on the vector's device: the
+    form ``_jitted_unpack``'s programs take their runtime offsets in."""
+    import jax
+
+    return jax.jit(lambda vec: tuple(vec[i] for i in range(n)))
+
+
+def _scalars_on_device(values, device):
+    """The host ints ``values`` as device-resident ``int32[]`` scalars, for
+    ONE host→device transfer (a vector, split on the device) where handing
+    them to the programs one by one costs a transfer each; with them, the
+    transfers made (counted in ``device_unpack.arg_puts``).  The caller has
+    validated the values: nothing on the device can."""
+    import jax
+
+    out: List[Any] = []
+    puts = 0
+    for lo in range(0, len(values), _SPLIT_MAX):
+        chunk = values[lo : lo + _SPLIT_MAX]
+        vec = np.zeros(1 << (len(chunk) - 1).bit_length(), np.int32)
+        vec[: len(chunk)] = chunk
+        on_device = jax.device_put(vec, device)
+        puts += 1
+        out.extend(_jitted_split(vec.size)(on_device)[: len(chunk)])
+    _ARG_PUTS.inc(puts)
+    return out, puts
+
+
 @functools.lru_cache(maxsize=256)
 def _jitted_unpack(dtype_str, shape, out_dtype_str):
     """One small program per distinct member SIGNATURE (dtype/shape/cast),
@@ -193,7 +241,25 @@ def _jitted_unpack(dtype_str, shape, out_dtype_str):
     O(distinct shapes) — a transformer's repeated layer shapes share one
     executable — and the runtime offset (``lax.dynamic_slice``) keeps
     byte positions out of the cache key, so evolving slab layouts reuse
-    the same executables instead of pinning one per layout."""
+    the same executables instead of pinning one per layout.
+
+    The program takes k offsets and returns k members of its signature:
+    a CALL is what costs a consume worker (0.6–1 ms each with four workers
+    in the runtime at once, whatever it carries: PERF.md §5, PR 37), so
+    all of a slab's members of one signature go in one call (the first
+    ``_GROUP_MAX``, then the next).  jit keeps an executable an arity and
+    a slab length, which the slab's layout decides: a state restored
+    again, or one whose layers repeat, compiles as many as a call a member
+    did (124 against 122 for 1,053 leaves), and compile time grows with k
+    by a tenth of a program a member.  The cache key here stays the
+    signature.
+
+    Each offset is an ``int32[]`` that already LIVES ON THE DEVICE
+    (``_scalars_on_device``): handed a numpy scalar, jit transfers it host
+    to device inside the call, one transfer a member.  Every caller passes
+    device scalars: jit keeps a second cache entry (a second compile) for
+    a host argument beside a committed device one, so no call site may mix
+    the two forms."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -221,7 +287,10 @@ def _jitted_unpack(dtype_str, shape, out_dtype_str):
             arr = arr.astype(jnp.dtype(out_dt))
         return arr
 
-    return jax.jit(unpack_one)
+    def unpack(slab, *offs):
+        return tuple(unpack_one(slab, off) for off in offs)
+
+    return jax.jit(unpack)
 
 
 @functools.lru_cache(maxsize=256)
@@ -305,7 +374,17 @@ def _jitted_cut(shape, dtype_str, sizes):
     """One program per (wide shape, dtype, box sizes); the box's start is
     a RUNTIME argument, so the halves (quarters, ...) of one saved shard
     share an executable: a resharding restore of a transformer compiles
-    one per distinct column-sharded shape."""
+    one per distinct column-sharded shape.
+
+    The starts are handed over as numpy scalars, which jit transfers host
+    to device inside the call (counted in ``device_unpack.arg_puts``, one
+    a scalar).  The form the slab's offsets take (one vector a piece,
+    split on the device) was measured here and taken out again: 2.97 →
+    3.20 s a restore of the four-chip cell, every restore of the window
+    slower (PERF.md §6, PR 37).  A worker waits for every piece, and the
+    vector's transfer is an ordinary one, queued behind what the other
+    workers have on the links, where a scalar inside a call is not:
+    that is the likely reason, and it is not measured."""
     import jax
     from jax import lax
 
@@ -333,15 +412,26 @@ def cut_box_on_device(wide, starts, sizes):
     fn = _jitted_cut(shape, str(np.dtype(wide.dtype)), sizes)
     out = fn(wide, *(np.int32(s) for s in starts))
     _count("unpack")  # after dispatch succeeded — fallbacks must not count
+    _ARG_PUTS.inc(len(starts))
     return out
 
 
 def unpack_slab_to_device(buf, members, out_dtypes, device) -> List[Any]:
-    """ONE H2D transfer + per-member compiled slice/bitcast programs turn
-    a host slab into all of its member device arrays — the restore-side
-    mirror of ``pack_arrays_to_host`` (amortizes per-transfer latency
-    exactly the way the write side amortizes DtoH launches; the handful
-    of extra dispatches are noise next to the transfer).
+    """ONE H2D transfer + compiled slice/bitcast programs, one call for
+    all of a slab's members of one signature, turn a host slab into all of
+    its member device arrays — the restore-side mirror of
+    ``pack_arrays_to_host`` (amortizes per-transfer latency exactly the
+    way the write side amortizes DtoH launches).
+
+    The members' word offsets follow the slab as ONE ``int32`` vector, are
+    split into scalars on the device, and every member program is called
+    with device-resident arguments only (``_scalars_on_device``): handed
+    over as numpy scalars they cost a host→device transfer a member.  The
+    calls are few because each costs the worker a share of a millisecond
+    while the other workers are in the runtime too: a call a member made a
+    thousand-leaf restore wait for its calls, not for the link (PERF.md
+    §5, PR 37).  The offsets are checked on the host, before anything is
+    put.
 
     ``members``: ((byte_offset, dtype_str, shape), ...) within ``buf``;
     ``out_dtypes``: per-member template dtype (cast on device) or None.
@@ -373,25 +463,49 @@ def unpack_slab_to_device(buf, members, out_dtypes, device) -> List[Any]:
             raise ValueError(
                 f"member [{off}, {off + nbytes}) outside slab of {u8.nbytes}"
             )
-    fns = [
-        _jitted_unpack(
+    by_signature: dict = {}  # signature -> its members' indices, in order
+    for i, ((_, dtype_str, shape), out_dt) in enumerate(zip(members, out_dtypes)):
+        signature = (
             # canonicalize unconditionally: alias spellings ('<f4' vs
             # 'float32') must share one cache entry, not two compiles
             str(np.dtype(dtype_str)),
             tuple(shape),
             None if out_dt is None else str(np.dtype(out_dt)),
         )
-        for (_, dtype_str, shape), out_dt in zip(members, out_dtypes)
-    ]
+        by_signature.setdefault(signature, []).append(i)
+    calls = []  # (program, the members it returns)
+    for signature, idx in by_signature.items():
+        fn = _jitted_unpack(*signature)
+        calls.extend(
+            (fn, idx[lo : lo + _GROUP_MAX])
+            for lo in range(0, len(idx), _GROUP_MAX)
+        )
     with obs.span("h2d/put", bytes=u8.nbytes):
         slab = jax.device_put(u8.view(_word(word_bytes)), device)
-    # the per-member programs (compiled lazily on this executor thread
-    # at first use) compile and dispatch while the slab's DMA runs
-    with obs.span("unpack/dispatch", members=len(members), width=word_bytes):
-        out = [
-            fn(slab, np.int32(off // word_bytes))
-            for fn, (off, _, _) in zip(fns, members)
-        ]
+    # the member programs (compiled lazily on this executor thread at
+    # first use) compile and dispatch while the slab's DMA runs
+    with obs.span(
+        "unpack/dispatch", members=len(members), width=word_bytes
+    ) as sp:
+        offs, arg_puts = _scalars_on_device(
+            [off // word_bytes for off, _, _ in members], device
+        )
+        out: List[Any] = [None] * len(members)
+        call_ns = []
+        for fn, idx in calls:
+            t0 = time.perf_counter_ns()
+            arrays = fn(slab, *(offs[i] for i in idx))
+            call_ns.append(time.perf_counter_ns() - t0)
+            for i, arr in zip(idx, arrays):
+                out[i] = arr
+        if sp is not None:
+            sp.attrs["arg_puts"] = arg_puts
+            sp.attrs["calls"] = len(calls)
+            # the worker's wait for the slab's transfer sits in ONE of the
+            # calls (not always the first: the runtime takes a few ahead);
+            # the span less the longest is what the calls themselves cost
+            sp.attrs["first_call_ns"] = call_ns[0]
+            sp.attrs["longest_call_ns"] = max(call_ns)
     _count("unpack")  # after dispatch succeeded — fallbacks must not count
     obs.counter(f"device_unpack.bytes_w{word_bytes}").inc(u8.nbytes)
     return out

@@ -30,7 +30,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SAVE = "take/pipeline"
 PLAN = "take/plan"  # a save's first span: its plan comes before its pipeline
 NAMES = (
-    PLAN, SAVE, "pipeline/staging", "stage/materialize", "d2h/copy",
+    PLAN, SAVE, "pipeline/staging", "stage/materialize", "chunk/slice", "d2h/copy",
     "stage/copy", "stage/digest", "pipeline/io", "storage/write", "take/commit",
 )
 _AFTER_NS = 3_000_000_000  # the last save's commit follows its pipeline

@@ -77,6 +77,10 @@ from .metrics import (  # noqa: F401
     CONTINUOUS_RESTORES_FROM_PEER,
     CONTINUOUS_STEP_OVERHEAD_S,
     CONTINUOUS_STEPS,
+    CHUNKED_HOST_ASSEMBLY_BYTES,
+    CHUNKED_READ_BYTES,
+    CHUNKED_WRITE_BYTES,
+    CHUNKED_WRITE_CHUNKS,
     DEVICE_UNPACK_ARG_PUTS,
     EVENT_HANDLER_ERRORS,
     EXCEPTIONS_SWALLOWED,

@@ -67,6 +67,17 @@ SLAB_HOST_UNPACK_BYTES = "slab.host_unpack_bytes"
 # handed its starts as numpy scalars, which jit transfers inside the
 # call, so one a dimension a cut.
 DEVICE_UNPACK_ARG_PUTS = "device_unpack.arg_puts"
+# The chunked path of a leaf over MAX_CHUNK_SIZE_BYTES (preparers/array.py
+# ``ChunkedArrayIOPreparer``), raised where the work is done: a chunk's
+# bytes, and one, when its staging completes; a chunk's bytes when its
+# consumer has placed them; and the bytes of the whole-array host buffers a
+# restore makes itself to assemble such leaves in (none where the caller's
+# template is a numpy array of the dtype): the twin of
+# ``reshard.host_alloc_bytes``.
+CHUNKED_WRITE_BYTES = "chunked.write_bytes"
+CHUNKED_WRITE_CHUNKS = "chunked.write_chunks"
+CHUNKED_READ_BYTES = "chunked.read_bytes"
+CHUNKED_HOST_ASSEMBLY_BYTES = "chunked.host_assembly_bytes"
 # tiered storage (tier/): read-path residency + write-back promotion.
 # hits/misses count tier-plugin reads served by the fast tier vs fallen
 # back (peer or durable); repairs count fast-tier copies rewritten from

@@ -150,14 +150,32 @@ def array_dtype_str(obj: Any) -> str:
     return dtype_to_string(obj.dtype)
 
 
+def _count_chunk_staged(nbytes: int) -> None:
+    """One chunk of a leaf over MAX_CHUNK_SIZE_BYTES has been staged whole
+    (a host chunk that a striped store streams by parts has no such moment
+    and is not counted)."""
+    obs.counter(obs.CHUNKED_WRITE_BYTES).inc(nbytes)
+    obs.counter(obs.CHUNKED_WRITE_CHUNKS).inc()
+
+
 class JaxArrayBufferStager(BufferStager):
     """Stage a (slice of a) single-device/replicated jax.Array: launch the
     async D2H transfer, then materialize to numpy in a worker thread."""
 
-    def __init__(self, arr: Any, index: Optional[Tuple] = None, nbytes: int = 0):
+    def __init__(
+        self,
+        arr: Any,
+        index: Optional[Tuple] = None,
+        nbytes: int = 0,
+        chunk_rows: Optional[int] = None,
+    ):
         self.arr = arr
         self.index = index
         self.nbytes = nbytes or array_nbytes(arr)
+        # rows of the dim-0 range this stager holds of a leaf over
+        # MAX_CHUNK_SIZE_BYTES (ChunkedArrayIOPreparer); None for any
+        # other write.  Read by the chunked path's span and counters only.
+        self.chunk_rows = chunk_rows
         # Set by eager_offload_write_reqs when it re-points ``arr`` at an
         # in-flight pinned-host copy: the original (immutable) device array,
         # kept so an asynchronous offload failure (e.g. pinned-host
@@ -196,7 +214,18 @@ class JaxArrayBufferStager(BufferStager):
                     "the train state on the step after async_take. "
                     "Offloaded leaves are immune; " + why
                 )
-            a = src if self.index is None else src[self.index]
+            if self.chunk_rows is None:
+                a = src if self.index is None else src[self.index]
+            else:
+                with obs.span(
+                    "chunk/slice", bytes=self.nbytes, rows=self.chunk_rows
+                ) as sliced:
+                    a = src[self.index]
+                    if sliced is not None:
+                        # a traced run waits here, so that the span holds
+                        # the slice and d2h/copy the copy alone; the copy
+                        # waits for the slice either way
+                        a.block_until_ready()
             # the host array is made by whichever of the two calls comes
             # to it first: both inside the arena
             with staging_arena.allocating():
@@ -235,6 +264,8 @@ class JaxArrayBufferStager(BufferStager):
             np_arr = await _run(fallback)
         self.arr = None  # drop refs as early as possible
         self.fallback_arr = None
+        if self.chunk_rows is not None:
+            _count_chunk_staged(self.nbytes)
         return array_as_memoryview(np_arr)
 
     def get_staging_cost_bytes(self) -> int:
@@ -246,9 +277,13 @@ class HostArrayBufferStager(BufferStager):
     defensive copy at staging time: the caller may mutate the source before
     storage I/O completes (reference io_preparers/tensor.py:283-307)."""
 
-    def __init__(self, arr: np.ndarray, defensive_copy: bool):
+    def __init__(
+        self, arr: np.ndarray, defensive_copy: bool, chunk: bool = False
+    ):
         self.arr = arr
         self.defensive_copy = defensive_copy
+        # a dim-0 range of a leaf over MAX_CHUNK_SIZE_BYTES: counted
+        self.chunk = chunk
         # Set when the stager holds a private copy (eager offload took the
         # defensive copy early); staging then drops the ref so the copy is
         # freed as soon as its storage write completes, matching the
@@ -268,6 +303,8 @@ class HostArrayBufferStager(BufferStager):
             self.arr = None
         elif self.owns_arr:
             self.arr = None
+        if self.chunk:
+            _count_chunk_staged(arr.nbytes)
         return array_as_memoryview(arr)
 
     # ------------------------------------------------- part streaming
@@ -886,11 +923,14 @@ class ChunkedArrayIOPreparer:
             nbytes = serialized_size_bytes(sizes, dtype)
             if _is_jax_array(obj):
                 stager: BufferStager = JaxArrayBufferStager(
-                    obj, index=(slice(r0, r1),), nbytes=nbytes
+                    obj, index=(slice(r0, r1),), nbytes=nbytes,
+                    chunk_rows=r1 - r0,
                 )
             else:
                 stager = HostArrayBufferStager(
-                    _to_host_view(obj)[r0:r1], defensive_copy=is_async_snapshot
+                    _to_host_view(obj)[r0:r1],
+                    defensive_copy=is_async_snapshot,
+                    chunk=True,
                 )
             from ..codec import filter_for_dtype
 
@@ -930,11 +970,17 @@ class ChunkedArrayIOPreparer:
             host_buf = obj_out
         else:
             host_buf = np.empty(tuple(entry.shape), dtype=dtype)
+            obs.counter(obs.CHUNKED_HOST_ASSEMBLY_BYTES).inc(host_buf.nbytes)
 
         def on_done() -> None:
             if host_buf is obj_out:
                 fut.set(obj_out)
-            else:
+                return
+            # the whole array goes up in one put, on the thread that
+            # stepped the countdown to zero
+            with obs.span(
+                "chunk/put", bytes=host_buf.nbytes, chunks=len(entry.chunks)
+            ):
                 result = materialize_into_template(host_buf, obj_out)
                 fut.set(result)
                 if result is not obj_out:
@@ -974,8 +1020,12 @@ class ChunkedArrayIOPreparer:
                     buffer_size_limit_bytes,
                     base_byte=chunk.byte_range[0] if chunk.byte_range else 0,
                 )
+                def tiled_chunk_placed(n: int = chunk_bytes) -> None:
+                    obs.counter(obs.CHUNKED_READ_BYTES).inc(n)
+                    outer.step()
+
                 fold = _TileCrcFold(
-                    chunk.crc32, f"{chunk.location} (tiled)", outer.step
+                    chunk.crc32, f"{chunk.location} (tiled)", tiled_chunk_placed
                 )
                 inner = _Countdown(n=len(tiles), on_zero=fold.finish)
                 for t0, t1, byte_range in tiles:
@@ -1028,7 +1078,8 @@ class _ChunkConsumer(BufferConsumer):
         np_arr = array_from_buffer(buf, self.dtype, tuple(self.sizes))
 
         def copy() -> None:
-            fast_copyto(self.host_buf[r0:r1], np_arr)
+            with obs.span("chunk/assemble", bytes=np_arr.nbytes):
+                fast_copyto(self.host_buf[r0:r1], np_arr)
 
         if executor is not None:
             await obs.run_in_executor(
@@ -1037,6 +1088,7 @@ class _ChunkConsumer(BufferConsumer):
             )
         else:
             copy()
+        obs.counter(obs.CHUNKED_READ_BYTES).inc(np_arr.nbytes)
         self.countdown.step()
 
     def get_consuming_cost_bytes(self) -> int:

@@ -193,12 +193,38 @@ def test_the_counters_and_spans_of_the_device_path(tmp_path, small_chunks, devic
         (v.nbytes, len(chunks[k])) for k, v in leaves.items()
     )
     assert not [s for s in spans if s.name in ("chunk/assemble", "h2d/put")]
-    for s in puts + joins:
+    lands = [s for s in spans if s.name == "chunk/land"]
+    for s in puts + joins + lands:
         assert s.thread_name.startswith("tsnp-consume"), s.thread_name
     # a put and the join that follows it both lie inside the worker's
     # consume/materialize: the loop runs neither
     assert {by_id[s.parent_id].name for s in puts} == {"consume/materialize"}
     assert {by_id[s.parent_id].name for s in joins} == {"consume/materialize"}
+    # one chunk/land a group of chunks a worker places: after the group's
+    # puts, before any join, holding the group's bytes
+    assert {by_id[s.parent_id].name for s in lands} == {"consume/materialize"}
+    assert {s.attrs["link"] for s in puts + lands} == {"h2d"}
+    placed = []
+    for land in lands:
+        group = [
+            s for s in puts
+            if s.parent_id == land.parent_id and s.end_ns <= land.start_ns
+            and s.span_id not in placed
+        ]
+        placed += [s.span_id for s in group]
+        assert land.attrs["chunks"] == len(group) >= 1
+        assert land.attrs["bytes"] == sum(s.attrs["bytes"] for s in group)
+    assert sorted(placed) == sorted(s.span_id for s in puts)
+    assert sum(s.attrs["bytes"] for s in lands) == total
+
+
+def test_with_tracing_off_the_device_path_records_no_land(tmp_path, small_chunks, device_path):
+    leaves = {"a": _value((40, 8), np.float32, 1)}
+    path = _take(tmp_path, leaves)
+    tracer.get_tracer().reset()
+    got = _restore(path, _zeros_like(leaves))
+    assert np.asarray(got["a"]).tobytes() == leaves["a"].tobytes()
+    assert len(tracer.get_tracer()) == 0
 
 
 def test_the_join_compiles_once_for_leaves_cut_alike(tmp_path, small_chunks, device_path):

@@ -597,3 +597,147 @@ def test_spans_sit_on_the_profilers_clock(tmp_path, tracing):
             assert stats["queue_ns"] >= 0
         if name == "tsnp:restore/pipeline":
             assert stats["thread"] == "MainThread" and stats["workers"] >= 1
+
+
+def _profiled(tmp_path, run, tracing=1):
+    """Run ``run()`` inside a ``jax.profiler`` session with tracing as
+    given: the recorded spans, and the profile's ``tsnp:`` events as
+    [(name, stats)]."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    trace_dir = str(tmp_path / "trace")
+    tr = obs.get_tracer()
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    try:
+        with knobs.override_trace(tracing):
+            tr.reset()
+            run()
+            spans = tr.spans()
+    finally:
+        jax.profiler.stop_trace()
+        tr.reset()
+    (found,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    events = [
+        (ev.name, dict(ev.stats)) for plane in ProfileData.from_file(found).planes
+        for line in plane.lines for ev in line.events if ev.name.startswith("tsnp:")
+    ]
+    return spans, events
+
+
+@pytest.mark.parametrize("op", ["take", "restore"])
+def test_every_lexical_span_has_one_event_with_its_id(tmp_path, op):
+    """Each recorded lexical span of a traced take or restore is exactly one
+    ``tsnp:`` event of the profile, named as the span and carrying its
+    ``span_id`` and ``parent_id``; a ``begin``/``end`` span is none."""
+    path = str(tmp_path / "snap")
+    if op == "restore":
+        Snapshot.take(path, {"ts": _jax_state(0)})
+        spans, events = _profiled(tmp_path, lambda: Snapshot(path).restore({"ts": _jax_state(1)}))
+    else:
+        spans, events = _profiled(tmp_path, lambda: Snapshot.take(path, {"ts": _jax_state(0)}))
+    by_id = {}
+    for name, stats in events:
+        by_id.setdefault(stats["span_id"], []).append((name, stats))
+    lexical = [s for s in spans if s.lexical]
+    assert {op, f"{op}/pipeline"} <= {s.name for s in lexical}
+    assert "pipeline/budget_admission" in {s.name for s in spans if not s.lexical}
+    for s in lexical:
+        ((name, stats),) = by_id[s.span_id]
+        assert name == "tsnp:" + s.name and stats.get("parent_id") == s.parent_id
+        assert stats["thread"] == s.thread_name
+    assert set(by_id) == {s.span_id for s in lexical}
+
+
+def _reshard_restore(tmp_path):
+    """A column leaf saved under 2x2 and restored under 1x4 on the direct
+    path: a piece put once, cut, and half handed to a sibling device."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    def put(value, dp, tp):
+        mesh = Mesh(np.array(jax.devices()[: dp * tp]).reshape(dp, tp), ("dp", "tp"))
+        return jax.device_put(value, NamedSharding(mesh, P(None, "tp")))
+
+    value = np.arange(8 * 16, dtype=np.float32).reshape(8, 16)
+    path = str(tmp_path / "resharded")
+    Snapshot.take(path, {"app": StateDict(w=put(value, 2, 2))})
+    dest = StateDict(w=put(np.zeros_like(value), 1, 4))
+    return lambda: Snapshot(path).restore({"app": dest})
+
+
+# the spans that hold a host-side wait for a transfer, or the call that
+# issues one, by the path that opens them (chunk/put and chunk/land:
+# tests/test_chunked_device_restore.py)
+LINKS = {
+    "restore": {"h2d/put": "h2d"},
+    "take": {"d2h/copy": "d2h"},
+    "device_unpack": {"h2d/put": "h2d", "unpack/dispatch": "h2d"},
+    "reshard": {"reshard/direct": "h2d", "h2d/put": "h2d", "d2d/put": "d2d"},
+}
+
+
+@pytest.mark.parametrize("case", sorted(LINKS))
+def test_the_spans_that_wait_on_the_link_say_which_way(tmp_path, case):
+    path = str(tmp_path / "snap")
+    tr = obs.get_tracer()
+    if case != "take":
+        Snapshot.take(path, {"ts": _jax_state(0)})
+    run = {
+        "take": lambda: Snapshot.take(path, {"ts": _jax_state(0)}),
+        "restore": lambda: Snapshot(path).restore({"ts": _jax_state(1)}),
+        "device_unpack": lambda: Snapshot(path).restore({"ts": _jax_state(1, n=12)}),
+    }.get(case) or _reshard_restore(tmp_path)
+    if case == "device_unpack":
+        # small leaves, so that the take writes them as one slab
+        Snapshot.take(path, {"ts": _jax_state(0, n=12)})
+    with knobs.override_device_unpack(case in ("device_unpack", "reshard")), \
+            knobs.override_trace(1):
+        tr.reset()
+        run()
+        spans = tr.spans()
+    tr.reset()
+    for name, link in LINKS[case].items():
+        found = [s for s in spans if s.name == name]
+        assert found, (name, {s.name for s in spans})
+        assert {s.attrs.get("link") for s in found} == {link}, name
+    tagged = {s.name for s in spans if "link" in s.attrs}
+    assert tagged <= {"h2d/put", "d2h/copy", "unpack/dispatch", "reshard/direct",
+                      "d2d/put", "chunk/put", "chunk/land"}
+
+
+def test_take_batch_and_commit_drain_lie_where_the_take_does_that_work(tmp_path, traced):
+    Snapshot.take(str(tmp_path / "snap"), {"ts": _jax_state(0, n=12), "meta": StateDict(step=3)})
+    spans = traced.spans()
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s.name, []).append(s)
+    (root,) = by_name["take"]
+    (plan,), (batch,), (pipeline,) = by_name["take/plan"], by_name["take/batch"], by_name["take/pipeline"]
+    (commit,), (drain,) = by_name["take/commit"], by_name["commit/drain"]
+    assert batch.parent_id == root.span_id and drain.parent_id == commit.span_id
+    assert plan.end_ns <= batch.start_ns and batch.end_ns <= pipeline.start_ns
+    assert commit.start_ns <= drain.start_ns and drain.end_ns <= commit.end_ns
+    # the twelve small leaves go as one slab; the primitive rides the metadata
+    assert batch.attrs == {"objects": 1, "slabs": 1}
+    assert drain.attrs == {}
+
+
+def test_with_tracing_off_the_new_spans_leave_no_trace(tmp_path):
+    """With tracing off a take (take/batch, commit/drain) and a restore
+    record nothing and add no event to a profile (chunk/land:
+    tests/test_chunked_device_restore.py)."""
+    path = str(tmp_path / "snap")
+    Snapshot.take(path, {"ts": _jax_state(0)})
+
+    def both():
+        Snapshot.take(str(tmp_path / "again"), {"ts": _jax_state(0)})
+        Snapshot(path).restore({"ts": _jax_state(1)})
+
+    spans, events = _profiled(tmp_path, both, tracing=0)
+    assert spans == [] and events == []

@@ -23,9 +23,12 @@ imported (this module never imports it), so inside a ``jax.profiler``
 session the same interval sits on its host-thread line of the
 ``.xplane.pb`` that holds the TPU plane — worker and loop threads give
 their OS thread the Python thread's name for that (``tsnp-consume_N``).
-``begin``/``end`` spans (``pipeline/budget_admission``) open and close on
-different turns of the loop or different threads, which an annotation
-cannot, and stay monotonic-only.
+The event carries the span's ``span_id`` and ``parent_id`` among its stats,
+so each event joins exactly one recorded span.  ``begin``/``end`` spans
+(``pipeline/budget_admission``) open and close on different turns of the
+loop or different threads, which an annotation cannot: they have no event
+(``lexical`` False), and a reader places them by the offset between the
+two clocks that the lexical spans give.
 
 Cost discipline: tracing is OFF by default and the disabled path is
 allocation-free — ``span()`` checks the module-level ``ENABLED`` flag
@@ -88,6 +91,7 @@ class Span:
         "task_name",
         "flow_in",
         "flow_out",
+        "lexical",
     )
 
     def __init__(
@@ -112,6 +116,9 @@ class Span:
         # storage-I/O start this way.
         self.flow_in: Optional[int] = None
         self.flow_out: Optional[int] = None
+        # opened and closed by one ``with`` (an event on the profiler's
+        # clock where jax is imported); ``begin`` spans are not
+        self.lexical = True
 
     @property
     def duration_ns(self) -> int:
@@ -164,6 +171,7 @@ class Tracer:
         if parent is None:
             parent = _current.get()
         s = Span(name, parent.span_id if parent else None, attrs)
+        s.lexical = False
         s.start_ns = time.monotonic_ns()
         return s
 
@@ -261,9 +269,13 @@ def _annotation(s: "Span") -> Any:
         return None
     # ``thread``: the profiler keys a line by pthread id, which a later
     # thread reuses (each call makes new pools), so one line can hold several
-    # threads' events under the name of one; the event says whose it is
+    # threads' events under the name of one; the event says whose it is.
+    # ``span_id`` / ``parent_id``: which recorded span the event is
     stats = {k: v for k, v in s.attrs.items() if isinstance(v, _SCALARS)}
     stats["thread"] = s.thread_name
+    stats["span_id"] = s.span_id
+    if s.parent_id is not None:
+        stats["parent_id"] = s.parent_id
     return cls(XPLANE_PREFIX + s.name, **stats)
 
 

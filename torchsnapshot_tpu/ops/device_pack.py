@@ -177,7 +177,7 @@ def pack_arrays_to_host(arrays: List[Any]) -> np.ndarray:
             obs.swallowed_exception("device_pack.copy_to_host_async", e)
         # materializes (async failures surface here); the host reads the
         # words as the bytes they are
-        with obs.span("d2h/copy", bytes=packed.nbytes):
+        with obs.span("d2h/copy", bytes=packed.nbytes, link="d2h"):
             # a 2-byte slab comes padded to whole rows of word pairs
             out = np.asarray(packed).view(np.uint8)[:nbytes]
     _count("pack")
@@ -362,7 +362,7 @@ def tile_update_device(acc, tile_np: np.ndarray, off: int):
         str(np.dtype(tile_np.dtype)),
         device,
     )
-    with obs.span("h2d/put", bytes=tile_np.nbytes):
+    with obs.span("h2d/put", bytes=tile_np.nbytes, link="h2d"):
         tile = jax.device_put(tile_np, device)
     out = fn(acc, tile, np.int32(off))
     _count("tile_update")
@@ -480,12 +480,12 @@ def unpack_slab_to_device(buf, members, out_dtypes, device) -> List[Any]:
             (fn, idx[lo : lo + _GROUP_MAX])
             for lo in range(0, len(idx), _GROUP_MAX)
         )
-    with obs.span("h2d/put", bytes=u8.nbytes):
+    with obs.span("h2d/put", bytes=u8.nbytes, link="h2d"):
         slab = jax.device_put(u8.view(_word(word_bytes)), device)
     # the member programs (compiled lazily on this executor thread at
     # first use) compile and dispatch while the slab's DMA runs
     with obs.span(
-        "unpack/dispatch", members=len(members), width=word_bytes
+        "unpack/dispatch", members=len(members), width=word_bytes, link="h2d"
     ) as sp:
         offs, arg_puts = _scalars_on_device(
             [off // word_bytes for off, _, _ in members], device

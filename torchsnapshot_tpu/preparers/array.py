@@ -244,7 +244,7 @@ class JaxArrayBufferStager(BufferStager):
                     obs.swallowed_exception(
                         "array_stager.copy_to_host_async", e
                     )
-                with obs.span("d2h/copy", bytes=self.nbytes):
+                with obs.span("d2h/copy", bytes=self.nbytes, link="d2h"):
                     return np.asarray(a)
 
         async def _run(src: Any) -> np.ndarray:
@@ -397,7 +397,7 @@ def materialize_into_template(np_arr: np.ndarray, obj_out: Any) -> Any:
             np_arr = np_arr.astype(obj_out.dtype)
         shaped = np_arr.reshape(obj_out.shape)
         sharding = obj_out.sharding
-        with obs.span("h2d/put", bytes=shaped.nbytes):
+        with obs.span("h2d/put", bytes=shaped.nbytes, link="h2d"):
             out = jax.device_put(shaped, sharding)
         # NOTE: the template is NOT donated here.  Callers donate only
         # after the replacement is visible through the leaf's Future
@@ -1234,14 +1234,20 @@ class _DeviceChunkJoin:
         placed = []
         for index, chunk in chunks:
             with obs.span(
-                "chunk/put", bytes=chunk.nbytes, rows=chunk.shape[0]
+                "chunk/put", bytes=chunk.nbytes, rows=chunk.shape[0],
+                link="h2d",
             ):
                 part = jax.device_put(chunk, self.obj_out.sharding)
             placed.append((index, chunk.nbytes, part))
-        for _, nbytes, part in placed:
-            part.block_until_ready()
-            obs.counter(obs.CHUNKED_DEVICE_PUT_BYTES).inc(nbytes)
-            obs.counter(obs.CHUNKED_READ_BYTES).inc(nbytes)
+        # the worker's wait for its transfers: the link's, not the calls'
+        with obs.span("chunk/land", link="h2d") as landed:
+            if landed is not None:
+                landed.attrs["bytes"] = sum(n for _, n, _ in placed)
+                landed.attrs["chunks"] = len(placed)
+            for _, nbytes, part in placed:
+                part.block_until_ready()
+                obs.counter(obs.CHUNKED_DEVICE_PUT_BYTES).inc(nbytes)
+                obs.counter(obs.CHUNKED_READ_BYTES).inc(nbytes)
         with self.lock:
             for index, _, part in placed:
                 self.parts[index] = part

@@ -378,7 +378,8 @@ class ShardedArrayIOPreparer:
                     if set(local_boxes) == {full_box}:
                         # fully-replicated template: one broadcasting device_put
                         with obs.span(
-                            "h2d/put", bytes=buffers[full_box].nbytes
+                            "h2d/put", bytes=buffers[full_box].nbytes,
+                            link="h2d",
                         ):
                             out = jax.device_put(buffers[full_box], sharding)
                         # fut.set BEFORE donation: a donated template must
@@ -394,6 +395,7 @@ class ShardedArrayIOPreparer:
                                 "h2d/put",
                                 bytes=buffers[box].nbytes,
                                 device=dev.id,
+                                link="h2d",
                             ):
                                 arrays.append(
                                     jax.device_put(buffers[box], dev)
@@ -708,6 +710,7 @@ class _DirectLeaf:
             bytes=sum(view.nbytes for view, _ in sends),
             devices=sum(len(devs) for _, boxes in sends for _, _, devs in boxes),
             cut=bool(columns),
+            link="h2d",
         ) as sp:
             placed: Dict[Any, Any] = {}
             spent = []  # the wide buffers, and boxes cut for a sibling
@@ -717,7 +720,8 @@ class _DirectLeaf:
                     [dev for _, _, devs in boxes for dev in devs], view.nbytes
                 )
                 with obs.span(
-                    "h2d/put", bytes=view.nbytes, device=receiver.id
+                    "h2d/put", bytes=view.nbytes, device=receiver.id,
+                    link="h2d",
                 ):
                     arr = jax.device_put(view, receiver)
                 obs.counter(obs.RESHARD_LINK_BYTES).inc(view.nbytes)
@@ -740,6 +744,7 @@ class _DirectLeaf:
                             bytes=box.nbytes,
                             src=receiver.id,
                             dst=dev.id,
+                            link="d2d",
                         ):
                             placed[dev] = jax.device_put(box, dev)
                         handoff_bytes += box.nbytes

@@ -33,7 +33,9 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from . import knobs, obs
-from .batcher import batch_read_requests, batch_write_requests
+from .batcher import (
+    BatchedBufferStager, batch_read_requests, batch_write_requests,
+)
 from .coordination import Coordinator, get_default_coordinator
 from .event import Event
 from .event_handlers import log_event
@@ -974,7 +976,8 @@ class Snapshot:
                 with coordinator.abort_scope(commit_uid), \
                         coordinator.liveness_scope(session.monitor), \
                         obs.span("take/commit", rank=coordinator.rank):
-                    pending_io.sync_complete()
+                    with obs.span("commit/drain"):
+                        pending_io.sync_complete()
                     # tiered storage: replicate fast-tier payloads to
                     # peers and enqueue write-back promotion, strictly
                     # after this rank's writes landed and strictly
@@ -1561,186 +1564,206 @@ class Snapshot:
                 else:
                     del entries[lp]
 
-        # coalesce small writes into slabs (reference batcher.py:204-355)
-        if not knobs.is_batching_disabled():
-            # shield split replicated entries: slab-packing a chunk would
-            # re-point it to a rank-LOCAL location, silently diverging the
-            # per-rank copies of the shared entry
-            shielded = {
-                lp: entries.pop(lp) for lp in split_repl_paths if lp in entries
-            }
-            entries, write_reqs = batch_write_requests(entries, write_reqs, rank)
-            entries.update(shielded)
+        # take/batch: slabs, then the manifest and every request's digest,
+        # codec and content-address sinks, up to take/pipeline
+        with obs.span("take/batch") as batched:
+            # coalesce small writes into slabs (reference batcher.py:204-355)
+            if not knobs.is_batching_disabled():
+                # shield split replicated entries: slab-packing a chunk would
+                # re-point it to a rank-LOCAL location, silently diverging the
+                # per-rank copies of the shared entry
+                shielded = {
+                    lp: entries.pop(lp)
+                    for lp in split_repl_paths
+                    if lp in entries
+                }
+                entries, write_reqs = batch_write_requests(
+                    entries, write_reqs, rank
+                )
+                entries.update(shielded)
 
-        # whole-object digests feed the metadata objects table and the
-        # incremental-dedup decision; attached AFTER batching so slab
-        # objects are covered at their final paths
-        object_crcs: Dict[str, List[int]] = {}
-        # codec frame tables (codec.py): filled by the scheduler for
-        # every object it stores compressed; rides the crc gather into
-        # SnapshotMetadata.codecs.  Sinks are attached unconditionally
-        # (one closure per request) — whether anything encodes is the
-        # scheduler's per-run CODEC-knob decision.
-        object_codecs: Dict[str, Any] = {}
-        for wr in write_reqs:
-            def _codec_sink(table: dict, wr=wr) -> None:
-                object_codecs[wr.path] = table
+            # whole-object digests feed the metadata objects table and the
+            # incremental-dedup decision; attached AFTER batching so slab
+            # objects are covered at their final paths
+            object_crcs: Dict[str, List[int]] = {}
+            # codec frame tables (codec.py): filled by the scheduler for
+            # every object it stores compressed; rides the crc gather into
+            # SnapshotMetadata.codecs.  Sinks are attached unconditionally
+            # (one closure per request) — whether anything encodes is the
+            # scheduler's per-run CODEC-knob decision.
+            object_codecs: Dict[str, Any] = {}
+            for wr in write_reqs:
+                def _codec_sink(table: dict, wr=wr) -> None:
+                    object_codecs[wr.path] = table
 
-            wr.codec_sink = _codec_sink
-        if cas_cfg is not None and base is not None:
-            # chunk-level addressing dedups against EVERY committed step
-            # sharing the pool — the whole-object base link is strictly
-            # weaker, and mixing the two storage models in one take
-            # would split ownership semantics
-            logger.info(
-                "rank %d: take(cas=...) supersedes base=%r; using "
-                "chunk-level content addressing", rank, base,
-            )
-            base = None
-        if base is not None and base.rstrip("/") == path.rstrip("/"):
-            # self-dedup would link an object onto itself (and the fs
-            # fallback's unlink-before-link would destroy the only copy)
-            logger.warning(
-                "rank %d: incremental base equals the target path %r; "
-                "performing a full save", rank, path,
-            )
-            base = None
-        if knobs.write_checksums_enabled():
-            base_objects: Dict[str, Any] = {}
-            base_codecs: Dict[str, Any] = {}
-            if base is not None:
-                # rank 0 reads the base's object table once and shares it
-                # (every rank GETting a multi-MB metadata object from
-                # cloud storage at the start of each take is a
-                # thundering herd); branch participation is rank-agreed
-                # by the gather above
+                wr.codec_sink = _codec_sink
+            if cas_cfg is not None and base is not None:
+                # chunk-level addressing dedups against EVERY committed step
+                # sharing the pool — the whole-object base link is strictly
+                # weaker, and mixing the two storage models in one take
+                # would split ownership semantics
+                logger.info(
+                    "rank %d: take(cas=...) supersedes base=%r; using "
+                    "chunk-level content addressing", rank, base,
+                )
+                base = None
+            if base is not None and base.rstrip("/") == path.rstrip("/"):
+                # self-dedup would link an object onto itself (and the fs
+                # fallback's unlink-before-link would destroy the only copy)
+                logger.warning(
+                    "rank %d: incremental base equals the target path %r; "
+                    "performing a full save", rank, path,
+                )
+                base = None
+            if knobs.write_checksums_enabled():
+                base_objects: Dict[str, Any] = {}
+                base_codecs: Dict[str, Any] = {}
+                if base is not None:
+                    # rank 0 reads the base's object table once and shares it
+                    # (every rank GETting a multi-MB metadata object from
+                    # cloud storage at the start of each take is a
+                    # thundering herd); branch participation is rank-agreed
+                    # by the gather above
+                    if rank == 0:
+                        try:
+                            base_meta = Snapshot(base).metadata
+                            base_objects = base_meta.objects or {}
+                            # a dedup link copies the base's STORED bytes —
+                            # if those were codec frames, the frame table
+                            # must carry into this snapshot's manifest
+                            base_codecs = base_meta.codecs or {}
+                        except Exception as e:  # noqa: BLE001
+                            logger.warning(
+                                "rank 0: incremental base %r unusable (%r); "
+                                "performing a full save", base, e,
+                            )
+                    if world > 1:
+                        base_objects, base_codecs = (
+                            coordinator.broadcast_object(
+                                (base_objects, base_codecs), src=0
+                            )
+                        )
+                for wr in write_reqs:
+                    def _object_sink(digest: List[int], wr=wr) -> None:
+                        wr.object_digest = tuple(digest)
+                        object_crcs[wr.path] = list(digest)
+
+                    wr.digest_sink = _object_sink
+                    base_digest = base_objects.get(wr.path)
+                    # dedup compares (crc32, adler32, size) — two independent
+                    # checksums + exact length, so a lone crc32 collision
+                    # can't silently link stale content
+                    if (
+                        base is not None
+                        and isinstance(base_digest, (list, tuple))
+                        and len(base_digest) == 3
+                    ):
+                        wr.dedup = (base, tuple(int(x) for x in base_digest))
+                        wr.dedup_codec = base_codecs.get(wr.path)
+            elif base is not None:
+                logger.warning(
+                    "rank %d: take(base=...) needs WRITE_CHECKSUMS=1; "
+                    "performing a full save", rank,
+                )
+
+            # content-addressed chunk store (cas/): rank 0 reads the
+            # committed index's LIVE key set once and shares it (same
+            # thundering-herd economics as the base objects table above);
+            # every write request gets a context routing it through the
+            # pool, with one shared written-this-take set so intra-take
+            # repeats (tied weights, identical slabs on two reqs) dedup too
+            object_cas: Dict[str, Any] = {}
+            cas_store = None
+            if cas_cfg is not None:
+                from . import cas as cas_mod
+
+                cas_store = cas_mod.ChunkStore(cas_cfg["root"])
+                known_keys: set = set()
                 if rank == 0:
                     try:
-                        base_meta = Snapshot(base).metadata
-                        base_objects = base_meta.objects or {}
-                        # a dedup link copies the base's STORED bytes —
-                        # if those were codec frames, the frame table
-                        # must carry into this snapshot's manifest
-                        base_codecs = base_meta.codecs or {}
-                    except Exception as e:  # noqa: BLE001
-                        logger.warning(
-                            "rank 0: incremental base %r unusable (%r); "
-                            "performing a full save", base, e,
-                        )
-                if world > 1:
-                    base_objects, base_codecs = coordinator.broadcast_object(
-                        (base_objects, base_codecs), src=0
-                    )
-            for wr in write_reqs:
-                def _object_sink(digest: List[int], wr=wr) -> None:
-                    wr.object_digest = tuple(digest)
-                    object_crcs[wr.path] = list(digest)
-
-                wr.digest_sink = _object_sink
-                base_digest = base_objects.get(wr.path)
-                # dedup compares (crc32, adler32, size) — two independent
-                # checksums + exact length, so a lone crc32 collision
-                # can't silently link stale content
-                if (
-                    base is not None
-                    and isinstance(base_digest, (list, tuple))
-                    and len(base_digest) == 3
-                ):
-                    wr.dedup = (base, tuple(int(x) for x in base_digest))
-                    wr.dedup_codec = base_codecs.get(wr.path)
-        elif base is not None:
-            logger.warning(
-                "rank %d: take(base=...) needs WRITE_CHECKSUMS=1; "
-                "performing a full save", rank,
-            )
-
-        # content-addressed chunk store (cas/): rank 0 reads the
-        # committed index's LIVE key set once and shares it (same
-        # thundering-herd economics as the base objects table above);
-        # every write request gets a context routing it through the
-        # pool, with one shared written-this-take set so intra-take
-        # repeats (tied weights, identical slabs on two reqs) dedup too
-        object_cas: Dict[str, Any] = {}
-        cas_store = None
-        if cas_cfg is not None:
-            from . import cas as cas_mod
-
-            cas_store = cas_mod.ChunkStore(cas_cfg["root"])
-            known_keys: set = set()
-            if rank == 0:
-                try:
-                    known_keys = cas_mod.ChunkIndex.load(
-                        cas_store
-                    ).live_keys()
-                except cas_mod.ChunkIndexCorruptError as e:
-                    logger.warning(
-                        "corrupt chunk index under %r (%r); rebuilding "
-                        "via fsck before this take", cas_cfg["root"], e,
-                    )
-                    try:
-                        cas_mod.fsck(cas_cfg["root"])
                         known_keys = cas_mod.ChunkIndex.load(
                             cas_store
                         ).live_keys()
-                    except Exception as e2:  # noqa: BLE001
+                    except cas_mod.ChunkIndexCorruptError as e:
                         logger.warning(
-                            "chunk-index fsck under %r failed (%r); "
-                            "this take writes every chunk (correct, "
-                            "just not deduplicated)", cas_cfg["root"], e2,
+                            "corrupt chunk index under %r (%r); rebuilding "
+                            "via fsck before this take", cas_cfg["root"], e,
                         )
-                        known_keys = set()
-            if world > 1:
-                known_keys = coordinator.broadcast_object(
-                    known_keys, src=0
-                )
-            written_this_take: set = set()
-            for wr in write_reqs:
-                def _cas_sink(table: dict, wr=wr) -> None:
-                    object_cas[wr.path] = table
+                        try:
+                            cas_mod.fsck(cas_cfg["root"])
+                            known_keys = cas_mod.ChunkIndex.load(
+                                cas_store
+                            ).live_keys()
+                        except Exception as e2:  # noqa: BLE001
+                            logger.warning(
+                                "chunk-index fsck under %r failed (%r); "
+                                "this take writes every chunk (correct, "
+                                "just not deduplicated)", cas_cfg["root"], e2,
+                            )
+                            known_keys = set()
+                if world > 1:
+                    known_keys = coordinator.broadcast_object(
+                        known_keys, src=0
+                    )
+                written_this_take: set = set()
+                for wr in write_reqs:
+                    def _cas_sink(table: dict, wr=wr) -> None:
+                        object_cas[wr.path] = table
 
-                wr.cas = cas_mod.CasWriteContext(
-                    store=cas_store,
-                    known_keys=known_keys,
-                    chunk_size=cas_cfg["chunk_size"],
-                    sink=_cas_sink,
-                    written_this_take=written_this_take,
-                )
+                    wr.cas = cas_mod.CasWriteContext(
+                        store=cas_store,
+                        known_keys=known_keys,
+                        chunk_size=cas_cfg["chunk_size"],
+                        sink=_cas_sink,
+                        written_this_take=written_this_take,
+                    )
 
-        # gather per-rank manifests; every rank can build the global view
-        # deterministically (reference _gather_manifest, snapshot.py:948-961)
-        # NOTE: this serializes entry objects BEFORE staging runs, so
-        # checksum sinks (which fire during staging) mutate only the
-        # LOCAL objects below — the commit paths re-gather crc maps
-        # post-staging and merge them into the metadata (_merge_crcs).
-        local_entry_objs = {**manifest, **entries}
-        local_manifest_d = {
-            lpath: e.to_dict() for lpath, e in local_entry_objs.items()
-        }
-        if world > 1:
-            gathered_manifests = coordinator.all_gather_object(local_manifest_d)
-        else:
-            gathered_manifests = [local_manifest_d]
-        global_manifest = consolidate_manifests(
-            [
-                {k: entry_from_dict(v) for k, v in md.items()}
-                for md in gathered_manifests
-            ]
-        )
-        metadata = SnapshotMetadata(
-            version=MANIFEST_VERSION, world_size=world, manifest=global_manifest
-        )
-        if cas_cfg is not None:
-            # the rank-agreed envelope; per-rank chunk tables merge in
-            # at commit (_merge_crc_payloads).  The root is recorded
-            # relative ("../cas") under the manager layout so a rehomed
-            # checkpoint tree keeps restoring.
-            from . import cas as cas_mod
-
-            metadata.cas = {
-                "root": cas_mod.record_root(path, cas_cfg["root"]),
-                "chunk_size": cas_cfg["chunk_size"],
-                "chunks": {},
+            # gather per-rank manifests; every rank can build the global view
+            # deterministically (reference _gather_manifest,
+            # snapshot.py:948-961)
+            # NOTE: this serializes entry objects BEFORE staging runs, so
+            # checksum sinks (which fire during staging) mutate only the
+            # LOCAL objects below — the commit paths re-gather crc maps
+            # post-staging and merge them into the metadata (_merge_crcs).
+            local_entry_objs = {**manifest, **entries}
+            local_manifest_d = {
+                lpath: e.to_dict() for lpath, e in local_entry_objs.items()
             }
+            if world > 1:
+                gathered_manifests = coordinator.all_gather_object(
+                    local_manifest_d
+                )
+            else:
+                gathered_manifests = [local_manifest_d]
+            global_manifest = consolidate_manifests(
+                [
+                    {k: entry_from_dict(v) for k, v in md.items()}
+                    for md in gathered_manifests
+                ]
+            )
+            metadata = SnapshotMetadata(
+                version=MANIFEST_VERSION,
+                world_size=world,
+                manifest=global_manifest,
+            )
+            if cas_cfg is not None:
+                # the rank-agreed envelope; per-rank chunk tables merge in
+                # at commit (_merge_crc_payloads).  The root is recorded
+                # relative ("../cas") under the manager layout so a rehomed
+                # checkpoint tree keeps restoring.
+                from . import cas as cas_mod
+
+                metadata.cas = {
+                    "root": cas_mod.record_root(path, cas_cfg["root"]),
+                    "chunk_size": cas_cfg["chunk_size"],
+                    "chunks": {},
+                }
+            if batched is not None:
+                batched.attrs["objects"] = len(write_reqs)
+                batched.attrs["slabs"] = sum(
+                    isinstance(wr.buffer_stager, BatchedBufferStager)
+                    for wr in write_reqs
+                )
 
         budget = get_process_memory_budget_bytes()
 

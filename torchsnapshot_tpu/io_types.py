@@ -288,7 +288,8 @@ class StripedWriteHandle(abc.ABC):
     """One in-flight striped (multipart) write of a single object.
 
     Obtained from ``StoragePlugin.begin_striped_write``; parts may be
-    written concurrently and in any order, then EXACTLY ONE of
+    written concurrently (up to ``max_concurrent_parts``) and in any
+    order, then EXACTLY ONE of
     ``complete``/``abort`` finishes the handle.  The object must never
     be observable half-written: ``complete`` is the atomic publish (S3
     CompleteMultipartUpload, GCS compose, fs temp→rename) and ``abort``
@@ -312,6 +313,18 @@ class StripedWriteHandle(abc.ABC):
     # that part raw instead (raw parts are sized by the stripe knob,
     # which backends size above their floor)
     min_part_bytes: int = 0
+
+    @property
+    def max_concurrent_parts(self) -> int:
+        """How many of this object's parts the stripe engine may have in
+        flight at once.  Defaults to the engine's per-object part
+        concurrency; a handle whose backend runs one object's parts one
+        after another anyway (a buffered file write holds the inode
+        lock for its whole copy) says 1, so the caller's threads go to
+        other objects instead of queueing on that lock."""
+        from .storage.stripe import part_concurrency
+
+        return part_concurrency()
 
     @abc.abstractmethod
     async def write_part(

@@ -205,11 +205,13 @@ _DEFAULTS = {
     # Striped storage I/O (storage/stripe.py): objects at or above
     # STRIPE_MIN_OBJECT_SIZE_BYTES are split into STRIPE_PART_SIZE_BYTES
     # parts driven concurrently — S3 true multipart uploads, GCS
-    # parallel compose-part uploads, fs offset-parallel pwrite into the
-    # preallocated temp file, memory ranged writes — and restore reads
-    # fan out as parallel ranged GETs.  Retry/failpoint/breaker/metrics
-    # granularity moves to the part: a transient mid-object re-sends one
-    # part, not the object.  Set MIN to 0 to disable striping entirely.
+    # parallel compose-part uploads, fs pwrite into the preallocated
+    # temp file (a file's parts one at a time on the buffered leg:
+    # StripedWriteHandle.max_concurrent_parts), memory ranged writes —
+    # and restore reads fan out as parallel ranged GETs.
+    # Retry/failpoint/breaker/metrics granularity moves to the part: a
+    # transient mid-object re-sends one part, not the object.  Set MIN
+    # to 0 to disable striping entirely.
     _STRIPE_PART_SIZE_BYTES: 64 * 1024 * 1024,
     _STRIPE_MIN_OBJECT_SIZE_BYTES: 128 * 1024 * 1024,
     # Per-part compression (codec.py): "raw" (off — the default; the

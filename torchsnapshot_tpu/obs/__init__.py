@@ -121,6 +121,7 @@ from .metrics import (  # noqa: F401
     STRIPE_PARTS_READ,
     STRIPE_PARTS_WRITTEN,
     STRIPE_READS,
+    STRIPE_SERIAL_PARTS,
     STRIPE_STREAMED_WRITES,
     STRIPE_WRITES,
     TIER_FAST_CORRUPT,

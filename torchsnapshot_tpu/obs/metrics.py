@@ -110,6 +110,11 @@ STRIPE_BYTES_WRITTEN = "storage.stripe.bytes_written"
 STRIPE_BYTES_READ = "storage.stripe.bytes_read"
 STRIPE_ABORTS = "storage.stripe.aborts"
 STRIPE_STREAMED_WRITES = "storage.stripe.streamed_writes"
+# parts written under a handle that takes one part at a time
+# (StripedWriteHandle.max_concurrent_parts == 1: an fs file on the
+# buffered leg); ÷ parts_written is the share of striped parts written
+# file by file instead of offset-parallel
+STRIPE_SERIAL_PARTS = "storage.stripe.serial_parts"
 STRIPE_PART_WRITE_LATENCY_S = "storage.stripe.part_write_latency_s"
 STRIPE_PART_READ_LATENCY_S = "storage.stripe.part_read_latency_s"
 # Per-part compression (codec.py): raw bytes entering the encode stage,

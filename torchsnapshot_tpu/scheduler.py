@@ -667,8 +667,9 @@ async def _execute_write_pipelines(
                 )
         if stripe.write_eligible(p.buf_size, storage):
             # whole-staged striped write: the buffer exists, so split it
-            # into concurrent parts (true multipart on s3, compose parts
-            # on gcs, engine/offset-parallel pwrite on fs).  When the
+            # into parts (true multipart on s3, compose parts on gcs,
+            # engine pwrite on fs: one part at a time on its buffered
+            # leg, so the I/O slots write files side by side).  When the
             # digest was deferred (_stage_one_inner: the plugin's part
             # handles fuse), each part's (crc32, adler32) rides its
             # write and the folded result replaces the staging-phase

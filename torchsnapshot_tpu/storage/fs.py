@@ -633,7 +633,9 @@ class FSStoragePlugin(StoragePlugin):
 
 
 class _FSStripedWriteHandle(StripedWriteHandle):
-    """Offset-parallel part writes into a preallocated sibling temp file.
+    """Part writes into a preallocated sibling temp file: one at a time
+    on the buffered leg (``max_concurrent_parts``), offset-parallel on
+    the O_DIRECT leg.
 
     With the fast-I/O engine each part is ONE GIL-free native call
     (pwritev-batched, optionally O_DIRECT for the aligned body, the
@@ -642,7 +644,7 @@ class _FSStripedWriteHandle(StripedWriteHandle):
     per-part digest read); without it, the pre-engine ``os.pwrite``
     loop.  Either way the plugin's temp+rename commit discipline holds:
     parts land in the ``.tsnp-tmp-*`` file (preallocated with ftruncate
-    so concurrent pwrites never race an append), ``complete``
+    so no pwrite races an append), ``complete``
     optionally fdatasyncs and ``os.replace``s onto the final name — a
     mid-stripe failure or abort leaves NO partial file where a reader
     (or a recovery sweep) would trust it.  Each part retries
@@ -667,6 +669,18 @@ class _FSStripedWriteHandle(StripedWriteHandle):
         # complete() truncates to this high-water mark, so raw-sized
         # preallocation never publishes trailing zeros
         self._hwm = 0
+
+    @property
+    def max_concurrent_parts(self) -> int:
+        # a buffered write holds the file's inode lock for its whole
+        # copy (shmem's and ext4's write_iter, XFS's exclusive iolock,
+        # gVisor's tmpfs inode.mu), so the parts of one file run one
+        # after another whatever the threads: one at a time, in offset
+        # order, leaves the I/O pool's other threads to other files.
+        # O_DIRECT overwrites inside i_size do not take it exclusively
+        if self._plugin._fastio is not None and self._fd_direct >= 0:
+            return super().max_concurrent_parts
+        return 1
 
     async def write_part(
         self, index: int, offset: int, buf, want_digest: bool = False

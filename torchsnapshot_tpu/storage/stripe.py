@@ -10,11 +10,15 @@ This engine splits any object at or above
 concurrently:
 
 - **writes** go through ``StoragePlugin.begin_striped_write`` — S3 true
-  multipart uploads, GCS parallel compose-part uploads, fs
-  offset-parallel ``pwrite`` into the preallocated temp file, memory
-  ranged writes — with retry/failpoint/breaker discipline INSIDE each
-  part (``storage.<backend>.part.write`` failpoints), so one flaky
-  connection re-sends one part;
+  multipart uploads, GCS parallel compose-part uploads, fs ``pwrite``
+  into the preallocated temp file, memory ranged writes — with
+  retry/failpoint/breaker discipline INSIDE each part
+  (``storage.<backend>.part.write`` failpoints), so one flaky connection
+  re-sends one part.  A handle says how many of its parts may be in
+  flight at once (``StripedWriteHandle.max_concurrent_parts``): an fs
+  file on the buffered leg takes one at a time, in offset order, since
+  the inode lock serializes its writes anyway — parallelism there comes
+  from the files the scheduler writes at once;
 - **reads** fan out as parallel ranged ``StoragePlugin.read`` calls
   assembled into one buffer (honoring the ``into`` destination hint),
   which needs no new plugin capability — every backend already honors
@@ -38,10 +42,11 @@ byte/latency instruments keep recording per part via
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import time
 from collections import deque
 from concurrent.futures import Executor
-from typing import Any, Callable, List, Optional, Tuple
+from typing import Any, AsyncIterator, Callable, List, Optional, Tuple
 
 from .. import knobs, obs
 from ..io_types import ReadIO, StoragePlugin, resolve_read_destination
@@ -101,6 +106,43 @@ def part_concurrency() -> int:
     return max(2, min(knobs.get_max_per_rank_io_concurrency(), 8))
 
 
+class _PartTurns:
+    """Admission of one object's part writes to its handle, at most
+    ``handle.max_concurrent_parts`` in flight.  Where that is 1 the parts
+    also go strictly in index (offset) order: part i writes once part
+    i-1 is over, written or failed, so a file whose writes the backend
+    serializes is written front to back by one thread at a time."""
+
+    def __init__(self, limit: int, nparts: int) -> None:
+        self.limit = limit
+        self._sem = asyncio.Semaphore(limit)
+        loop = asyncio.get_running_loop()
+        self._over: List[asyncio.Future] = (
+            [loop.create_future() for _ in range(nparts)]
+            if limit == 1
+            else []
+        )
+
+    @contextlib.asynccontextmanager
+    async def turn(self, idx: int) -> AsyncIterator[None]:
+        """Part ``idx``'s write: waits for its admission, and hands the
+        turn on when the write is over, however it ended."""
+        if self._over and idx:
+            await self._over[idx - 1]
+        async with self._sem:
+            try:
+                yield
+            finally:
+                self.pass_on(idx)
+
+    def pass_on(self, idx: int) -> None:
+        """Part ``idx`` is over without (or after) its write: a part
+        that failed before writing calls this, or its successors would
+        wait for a turn that never comes.  Idempotent."""
+        if self._over and not self._over[idx].done():
+            self._over[idx].set_result(None)
+
+
 async def striped_write(
     storage: StoragePlugin,
     path: str,
@@ -109,7 +151,8 @@ async def striped_write(
     on_part_done: Optional[Callable[[int], None]] = None,
     want_digests: bool = False,
 ) -> Optional[Tuple[int, int, int]]:
-    """Write an already-staged buffer as concurrent parts.
+    """Write an already-staged buffer as parts, as many at once as the
+    handle's ``max_concurrent_parts`` allows.
 
     ``on_part_done(nbytes)`` fires on the event loop as each part
     completes — the scheduler points it at budget/stat accounting so
@@ -127,22 +170,24 @@ async def striped_write(
     spans = plan_parts(total)
     backend = _backend_name(storage)
     m_part_lat = obs.histogram(obs.STRIPE_PART_WRITE_LATENCY_S)
-    sem = asyncio.Semaphore(part_concurrency())
     digests: List[Optional[Tuple[int, int, int]]] = [None] * len(spans)
 
     with obs.span(
         "stripe/write", backend=backend, path=path, bytes=total,
         parts=len(spans),
-    ):
+    ) as sp:
         handle = await storage.begin_striped_write(path, total)
-        # direct attribute access (the ABC defaults it False), NOT
-        # getattr: passing the handle to a call here would read as an
-        # ownership handoff to the resource-pairing lint pass and
-        # silence its complete/abort check on this function
+        # direct attribute access (the ABC defaults them), NOT getattr:
+        # passing the handle to a call here would read as an ownership
+        # handoff to the resource-pairing lint pass and silence its
+        # complete/abort check on this function
         fuse = want_digests and handle.supports_fused_digest
+        turns = _PartTurns(handle.max_concurrent_parts, len(spans))
+        if sp is not None:
+            sp.attrs["part_concurrency"] = turns.limit
 
         async def one(idx: int, lo: int, hi: int) -> None:
-            async with sem:
+            async with turns.turn(idx):
                 t0 = time.perf_counter()
                 with obs.span(
                     "stripe/write_part", path=path, part=idx, bytes=hi - lo
@@ -153,12 +198,14 @@ async def striped_write(
                     if fuse and d is not None:
                         digests[idx] = (d[0], d[1], hi - lo)
                 dt = time.perf_counter() - t0
-                m_part_lat.observe(dt)
-                obs.record_storage_io(backend, "write", hi - lo, dt)
-                obs.counter(obs.STRIPE_PARTS_WRITTEN).inc()
-                obs.counter(obs.STRIPE_BYTES_WRITTEN).inc(hi - lo)
-                if on_part_done is not None:
-                    on_part_done(hi - lo)
+            m_part_lat.observe(dt)
+            obs.record_storage_io(backend, "write", hi - lo, dt)
+            obs.counter(obs.STRIPE_PARTS_WRITTEN).inc()
+            if turns.limit == 1:
+                obs.counter(obs.STRIPE_SERIAL_PARTS).inc()
+            obs.counter(obs.STRIPE_BYTES_WRITTEN).inc(hi - lo)
+            if on_part_done is not None:
+                on_part_done(hi - lo)
 
         try:
             # settle every part before deciding the handle's fate: plain
@@ -332,7 +379,7 @@ async def streamed_part_write(
     with obs.span(
         "stripe/stream_write", backend=backend, path=path, bytes=total,
         parts=len(spans), codec=getattr(codec_spec, "codec", None),
-    ):
+    ) as sp:
         handle = await storage.begin_striped_write(path, ub_total)
 
         # fused copy+digest would hash the STORED bytes; under a codec
@@ -343,6 +390,11 @@ async def streamed_part_write(
             and codec_spec is None
             and getattr(handle, "supports_fused_digest", False)
         )
+        # parts still stage inside the window as they are admitted;
+        # only their writes take turns on the handle
+        turns = _PartTurns(handle.max_concurrent_parts, len(spans))
+        if sp is not None:
+            sp.attrs["part_concurrency"] = turns.limit
 
         async def one(idx: int, span: Tuple[int, int]) -> None:
             lo, hi = span
@@ -420,16 +472,18 @@ async def streamed_part_write(
                         starts[idx + 1].set_result(offset + len(frame))
                     piece = frame
                 nbytes = memoryview(piece).cast("B").nbytes
-                t0 = time.perf_counter()
-                with obs.span(
-                    "stripe/write_part", path=path, part=idx, bytes=nbytes
-                ) as write_sp:
-                    if write_sp is not None and flow_id is not None:
-                        write_sp.flow_in = flow_id
-                    d = await handle.write_part(
-                        idx, offset, piece, want_digest=fuse
-                    )
-                dt = time.perf_counter() - t0
+                async with turns.turn(idx):
+                    t0 = time.perf_counter()
+                    with obs.span(
+                        "stripe/write_part", path=path, part=idx,
+                        bytes=nbytes,
+                    ) as write_sp:
+                        if write_sp is not None and flow_id is not None:
+                            write_sp.flow_in = flow_id
+                        d = await handle.write_part(
+                            idx, offset, piece, want_digest=fuse
+                        )
+                    dt = time.perf_counter() - t0
                 m_phase_write.observe(dt)
                 if fuse:
                     if d is not None:
@@ -445,6 +499,8 @@ async def streamed_part_write(
                 m_part_lat.observe(dt)
                 obs.record_storage_io(backend, "write", nbytes, dt)
                 obs.counter(obs.STRIPE_PARTS_WRITTEN).inc()
+                if turns.limit == 1:
+                    obs.counter(obs.STRIPE_SERIAL_PARTS).inc()
                 obs.counter(obs.STRIPE_BYTES_WRITTEN).inc(nbytes)
                 del piece  # the part's bytes die with its write
                 if on_part_done is not None:
@@ -469,6 +525,7 @@ async def streamed_part_write(
                 raise
             finally:
                 gate.release(held)
+                turns.pass_on(idx)
 
         try:
             try:

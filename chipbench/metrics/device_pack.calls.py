@@ -3,4 +3,4 @@
 
 def read(ctx):
     saves = ctx.count("take")
-    return ctx.pack_calls / saves if saves else None
+    return ctx.pack_calls / saves if saves and ctx.pack_calls is not None else None

@@ -3,4 +3,4 @@
 
 def read(ctx):
     n = ctx.count("restore")
-    return ctx.unpack_calls / n if n else None
+    return ctx.unpack_calls / n if n and ctx.unpack_calls is not None else None

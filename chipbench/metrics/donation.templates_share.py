@@ -3,4 +3,6 @@
 
 def read(ctx):
     n = ctx.count("restore")
-    return ctx.donated_templates / (n * ctx.notes["array_leaves"]) if n else None
+    if not n or ctx.donated_templates is None:
+        return None
+    return ctx.donated_templates / (n * ctx.notes["array_leaves"])

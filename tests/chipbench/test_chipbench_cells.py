@@ -10,7 +10,7 @@ import os
 import shutil
 
 import pytest
-from conftest import LOOPS, cut_to_tiny, shrink_mix
+from conftest import DUMMY_LEAVES, DUMMY_METRIC, DUMMY_READER, DUMMY_STATE, LOOPS, cut_to_tiny, shrink_mix
 
 from chipbench import bench
 
@@ -89,55 +89,6 @@ def test_snapshots_never_land_in_the_checkout(run_tiny, tiny_root, benchmark_jso
     assert set(os.listdir(tiny_root)) == before
 
 
-# a state that is no transformer: a flat tree of many small leaves, bfloat16
-# and float32 side by side, with a step, batches and a TINY of its own
-DUMMY_STATE = '''
-import numpy as np
-
-from chipbench.state import prng_key
-
-TINY = {"rows": 8, "width": 16}
-
-
-class FlatMixed:
-    def __init__(self, conf, mesh):
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        self.mesh = mesh
-        names = [f"leaf{i:04d}" for i in range(conf["leaves"])]
-        shape = (conf["rows"], conf["width"])
-        dtypes = {n: (jnp.bfloat16, jnp.float32)[i % 2] for i, n in enumerate(names)}
-        self._whole = NamedSharding(mesh, P())
-        self.shardings = {n: self._whole for n in names}
-
-        def init(key):
-            rows = jax.random.normal(key, (len(names), *shape))
-            return {n: rows[i].astype(dtypes[n]) for i, n in enumerate(names)}
-
-        def step(tree, batch):
-            loss = jnp.mean(batch)
-            return {n: x + (1 + loss).astype(x.dtype) for n, x in tree.items()}, loss
-
-        self._init = jax.jit(init, out_shardings=self.shardings)
-        self.step = jax.jit(step, donate_argnums=0)
-
-    def make(self, seed):
-        return self._init(prng_key(seed))
-
-    def batch_pool(self, seed, batch, n):
-        import jax
-
-        pool = np.random.default_rng(seed).random((n, *batch["uniform"]), dtype=np.float32)
-        return [jax.device_put(b, self._whole) for b in pool]
-
-
-factory = FlatMixed
-'''
-DUMMY_LEAVES = 320
-
-
 def test_a_later_pr_adds_a_cell_by_adding_files_and_entries_only(tiny_root, run_tiny):
     """A dummy of each: state (no transformer), configuration, traffic mix,
     per-layer metric, cell.  No file that was there is edited but
@@ -157,7 +108,7 @@ def test_a_later_pr_adds_a_cell_by_adding_files_and_entries_only(tiny_root, run_
         f.write(DUMMY_STATE)
     # at the size its source would state, and cut as every configuration of
     # the miniature is: by its own state file's TINY
-    config = {"state": "flat_mixed", "leaves": DUMMY_LEAVES, "rows": 64, "width": 1024}
+    config = {"state": "flat_mixed", "leaves": DUMMY_LEAVES, "rows": 64, "hidden_size": 1024}
     with open(os.path.join(base, "configs", "dummy-flat.json"), "w") as f:
         json.dump(config, f)
     cut_to_tiny(tiny_root, spec["paths"], "chipbench/configs/dummy-flat.json")
@@ -179,10 +130,10 @@ def test_a_later_pr_adds_a_cell_by_adding_files_and_entries_only(tiny_root, run_
         shrunk = json.load(f)
     assert shrunk["batch"] == mix["batch"] and shrunk["window"]["max_loops"] == LOOPS
     with open(os.path.join(base, "metrics", "dummy.templates.py"), "w") as f:
-        f.write("def read(ctx):\n    return float(ctx.count('template')) or None\n")
+        f.write(DUMMY_READER)
     spec["configs"].append({
         "name": "dummy-flat", "source": "https://example.org/dummy",
-        "file": "chipbench/configs/dummy-flat.json", "reduced": ["rows", "width"],
+        "file": "chipbench/configs/dummy-flat.json", "reduced": ["rows", "hidden_size"],
         "why": "a dummy"})
     spec["workloads"].append({
         "name": "dummy-flat.dummy_mix", "config": "dummy-flat", "traffic": "dummy_mix",
@@ -190,10 +141,7 @@ def test_a_later_pr_adds_a_cell_by_adding_files_and_entries_only(tiny_root, run_
     for m in spec["end_to_end"]:
         if m["name"] == "resume_s":
             m["workloads"].append("dummy-flat.dummy_mix")
-    spec["per_layer"].append({
-        "name": "dummy.templates", "unit": "count", "better": "lower",
-        "source": "host_clock", "layer": "a dummy", "moves": "resume_s",
-        "workloads": ["dummy-flat.dummy_mix"]})
+    spec["per_layer"].append(dict(DUMMY_METRIC, workloads=["dummy-flat.dummy_mix"]))
     with open(path, "w") as f:
         json.dump(spec, f)
 

@@ -159,10 +159,10 @@ def test_the_committed_cut_is_the_issues_arithmetic(conf):
     assert max(rest) < 128 * 1024 * 1024
 
 
-def test_the_configuration_keeps_the_published_widths(benchmark_json, conf, module):
+def test_the_configuration_keeps_the_published_widths(checkout_spec, conf, module):
     for key, value in PUBLISHED.items():
         assert conf[key] == value, key
-    entry, = [c for c in benchmark_json["configs"] if c["name"] == NAME]
+    entry, = [c for c in checkout_spec["configs"] if c["name"] == NAME]
     assert entry["file"] == CONFIG and entry["source"] == conf["source"]
     assert len(conf["source"]) <= 200
     assert entry["reduced"] == conf["reduced"] == ["num_embeddings_per_feature"]
@@ -179,12 +179,14 @@ def test_the_configuration_keeps_the_published_widths(benchmark_json, conf, modu
     assert 1 in module.TINY["num_embeddings_per_feature"]  # a one-row table stays
 
 
-def test_the_cells_and_the_mix_are_the_ones_the_issue_names(repo, benchmark_json):
-    cells = {w["name"]: w for w in benchmark_json["workloads"]}
+def test_the_cells_and_the_mix_are_the_ones_the_issue_names(checkout, checkout_spec):
+    cells = {w["name"]: w for w in checkout_spec["workloads"]}
     assert [n for n in cells if n.startswith(NAME)] == [RESUME, SAVE]
     assert cells[RESUME]["chips"] == cells[SAVE]["chips"] == 1
-    assert benchmark_json["workloads"][-2:] == [cells[RESUME], cells[SAVE]]
-    traffic = os.path.join(repo, "chipbench", "traffic")
+    # side by side, in that order, wherever later cells put them
+    at = [w["name"] for w in checkout_spec["workloads"]].index(RESUME)
+    assert checkout_spec["workloads"][at + 1] is cells[SAVE]
+    traffic = os.path.join(checkout, "chipbench", "traffic")
     mix = state.load_json(os.path.join(traffic, "kill_resume_first3.json"))
     old = state.load_json(os.path.join(traffic, "kill_resume.json"))
     differs = {k for k in set(mix) | set(old) if mix.get(k) != old.get(k)}
@@ -193,18 +195,24 @@ def test_the_cells_and_the_mix_are_the_ones_the_issue_names(repo, benchmark_json
     assert mix["check"] == elastic["check"] == {"loops": 2, "below": 3}
     assert mix["answers_checked_least"] == elastic["answers_checked_least"] == 3
     assert "sink" not in mix
+    # the chunked path's readers: the six the cells came with, less chunk.assemble_s, which
+    # the device path left with nothing to read, and its two of its own
     new = {
         "chunked.bytes_share.restore": RESUME, "chunked.host_alloc_x": RESUME,
-        "chunk.assemble_s": RESUME, "chunk.put_s": RESUME,
-        "chunked.bytes_share.save": SAVE, "chunk.slice_s": SAVE,
+        "chunk.put_s": RESUME, "chunked.bytes_share.save": SAVE, "chunk.slice_s": SAVE,
     }
-    listed = {m["name"]: m for m in benchmark_json["per_layer"]}
-    assert {m["name"] for m in benchmark_json["per_layer"][-6:]} == set(new)
-    for name, cell in new.items():
-        assert listed[name]["workloads"] == [cell]
+    device_path = {"chunk.join_s": RESUME, "chunked.device_put_x": RESUME}
+    names = [m["name"] for m in checkout_spec["per_layer"]]
+    listed = {m["name"]: m for m in checkout_spec["per_layer"]}
+    assert "chunk.assemble_s" not in listed
+    for group in (new, device_path):  # each group's entries one after another
+        at = sorted(names.index(name) for name in group)
+        assert at == list(range(at[0], at[0] + len(group)))
+    for name, cell in {**new, **device_path}.items():
+        assert cell in listed[name]["workloads"]
         assert listed[name]["moves"] == ("resume_s" if cell == RESUME else "save_commit_s")
     for cell in (RESUME, SAVE):
-        found = bench.Cell(repo, cell)
+        found = bench.Cell(checkout, cell)
         reported = {m["name"] for m in found.end_to_end_metrics()}
         assert reported == {"setup_s", "resume_s" if cell == RESUME else "save_commit_s"}
         assert all(callable(found.reader(m["name"])) for m in found.per_layer_metrics())
@@ -458,6 +466,9 @@ def traced():
 def test_the_counters_and_spans_rise_by_what_the_manifest_names(
     tiny_chunked, factory, tmp_path, small_chunks, traced
 ):
+    # the restore half holds the host path, which the CPU's templates take:
+    # chunks assembled in a whole-leaf buffer, one put a leaf.  The chip's
+    # device path (a put a chunk, a join a leaf) is tests/test_chunked_device_restore.py's
     tree = stepped(factory, 5)
     path = str(tmp_path / "snap")
     before = counters()
@@ -538,12 +549,16 @@ def test_a_state_under_the_limit_raises_none_of_them(factory, tmp_path, traced):
 READERS = {
     "chunked.bytes_share.restore": ("chunked.read_bytes", "restore"),
     "chunked.host_alloc_x": ("chunked.host_assembly_bytes", "restore"),
+    "chunked.device_put_x": ("chunked.device_put_bytes", "restore"),
     "chunked.bytes_share.save": ("chunked.write_bytes", "take"),
 }
 SPAN_READERS = {
-    "chunk.assemble_s": ("chunk/assemble", "restore"), "chunk.put_s": ("chunk/put", "restore"),
+    "chunk.join_s": ("chunk/join", "restore"), "chunk.put_s": ("chunk/put", "restore"),
     "chunk.slice_s": ("chunk/slice", "take"),
 }
+# what no CPU run reports: it has no memory_stats, and its templates keep a
+# chunked leaf on the host path, so no chunk is put alone and none joined
+CHIP_ONLY = {"restore.hbm_peak_x", "chunk.join_s", "chunked.device_put_x"}
 
 
 class _Span:
@@ -639,21 +654,33 @@ def test_a_traced_run_of_the_resume_cell_reports_every_listed_metric(
     result = run_tiny(RESUME, trace=True)
     assert result["correct"] is True
     metrics = {k: v["value"] for k, v in result["metrics"].items()}
-    # no CPU run has memory_stats
-    assert set(metrics) == _listed(benchmark_json, RESUME) - {"restore.hbm_peak_x"}
+    assert set(metrics) - CHIP_ONLY == _listed(benchmark_json, RESUME) - CHIP_ONLY
+    # the host path, as on the CPU: every chunked byte through a whole-leaf
+    # host buffer, and one whole-leaf put a leaf (chunk.put_s).  On the chip
+    # the device path puts them chunk by chunk, which chunk.join_s and
+    # chunked.device_put_x read: their arithmetic is the two reader tests'
+    # hand-built windows above
     assert metrics["chunked.bytes_share.restore"] == pytest.approx(tiny_share["restore"])
     assert metrics["chunked.host_alloc_x"] == metrics["chunked.bytes_share.restore"]
-    assert metrics["chunk.assemble_s"] > 0 and metrics["chunk.put_s"] > 0
+    assert metrics["chunk.put_s"] > 0
+    assert "chunk.join_s" not in metrics
+    # a counter another test of this process raised on the device path reads 0
+    assert metrics.get("chunked.device_put_x", 0.0) == 0.0
     assert metrics["restore.host_us_per_leaf"] > 0
+    # the caller-thread and consume span readers read the chunked restore too
+    assert metrics["restore.pipeline_s"] > 0 and metrics["consume.work_s"] > 0
 
 
 def test_a_traced_run_under_the_limit_leaves_the_new_metrics_out(benchmark_json, run_tiny):
     # the chunk size as committed: no tiny leaf is over it, as no leaf of the accepted cells is
     result = run_tiny(RESUME, trace=True)
     assert result["correct"] is True
-    new = {"chunked.bytes_share.restore", "chunked.host_alloc_x", "chunk.assemble_s", "chunk.put_s"}
+    new = {
+        "chunked.bytes_share.restore", "chunked.host_alloc_x", "chunked.device_put_x",
+        "chunk.join_s", "chunk.put_s",
+    }
     left = _listed(benchmark_json, RESUME) - {"restore.hbm_peak_x"} - set(result["metrics"])
-    assert left <= new and {"chunk.assemble_s", "chunk.put_s"} <= left
+    assert left <= new and {"chunk.join_s", "chunk.put_s"} <= left
     for name in new - left:  # a counter another test of this process raised reads 0
         assert result["metrics"][name]["value"] == 0.0
 

@@ -1,6 +1,8 @@
 """The harness without a run: discovery by name, the contract's shape of
-BENCHMARK.json, the arithmetic on timelines that hold a stall, the table
-of peaks, and the refusal to run without a TPU."""
+BENCHMARK.json, a reader for every listed metric, the arithmetic on
+timelines that hold a stall, the table of peaks, and the refusal to run
+without a TPU.  What BENCHMARK.json promises is checked on the committed
+benchmark and on the same with a cell appended (``conftest.checkout``)."""
 
 import json
 import os
@@ -8,6 +10,7 @@ import re
 import statistics
 
 import pytest
+from conftest import APPENDED_CELL, DUMMY_METRIC, with_left_out
 
 from chipbench import arith, bench, state
 
@@ -15,7 +18,14 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
-    WORKLOADS = [w["name"] for w in json.load(_f)["workloads"]]
+    _SPEC = json.load(_f)
+WORKLOADS = [w["name"] for w in _SPEC["workloads"]]
+PER_LAYER = [m["name"] for m in _SPEC["per_layer"]]
+# each cell as committed, and each again in the checkout with one appended
+CELLS = [("committed", w) for w in WORKLOADS] + [("appended", w) for w in WORKLOADS + [APPENDED_CELL]]
+METRICS = [("committed", m) for m in PER_LAYER] + [
+    ("appended", m) for m in PER_LAYER + [DUMMY_METRIC["name"]]
+]
 # Ouro-2.6B's published config.json, the numbers a cut may not touch
 PUBLISHED = dict(
     head_dim=128, hidden_size=2048, intermediate_size=5632,
@@ -29,35 +39,39 @@ def _cells(benchmark_json):
     return {w["name"]: w for w in benchmark_json["workloads"]}
 
 
-def test_benchmark_json_has_exactly_the_contracts_keys(benchmark_json):
-    assert set(benchmark_json) == {
+def test_benchmark_json_has_exactly_the_contracts_keys(checkout_spec):
+    assert set(checkout_spec) == {
         "command", "paths", "run_seconds", "configs", "workloads",
         "end_to_end", "per_layer",
     }
-    assert 1 <= benchmark_json["run_seconds"] <= 51
-    assert "restore_peak_hbm_x" not in {m["name"] for m in benchmark_json["end_to_end"]}
+    assert 1 <= checkout_spec["run_seconds"] <= 51
+    assert "restore_peak_hbm_x" not in {m["name"] for m in checkout_spec["end_to_end"]}
 
 
-def test_names_units_and_bounds_keep_to_the_contract(benchmark_json):
+def test_names_units_and_bounds_keep_to_the_contract(checkout_spec):
     for table in ("configs", "workloads", "end_to_end", "per_layer"):
-        names = [e["name"] for e in benchmark_json[table]]
+        names = [e["name"] for e in checkout_spec[table]]
         assert len(names) == len(set(names))
         assert all(NAME.match(n) for n in names), names
-    for m in benchmark_json["end_to_end"] + benchmark_json["per_layer"]:
+    for m in checkout_spec["end_to_end"] + checkout_spec["per_layer"]:
         assert UNIT.match(m["unit"]), m
         assert m["better"] in ("lower", "higher")
-    for m in benchmark_json["end_to_end"]:
+    for m in checkout_spec["end_to_end"]:
         assert 0.01 <= m["bound"] <= 0.1
         assert m["source"] in ("host_clock", "device_trace")
-    assert "setup_s" in {m["name"] for m in benchmark_json["end_to_end"]}
-    four = sum(w["chips"] == 4 for w in benchmark_json["workloads"])
-    assert four <= max(1, len(benchmark_json["workloads"]) // 4)
+    assert "setup_s" in {m["name"] for m in checkout_spec["end_to_end"]}
+    four = sum(w["chips"] == 4 for w in checkout_spec["workloads"])
+    assert four <= max(1, len(checkout_spec["workloads"]) // 4)
 
 
-@pytest.mark.parametrize("workload", WORKLOADS)
-def test_a_cell_is_found_by_its_name_alone(repo, benchmark_json, workload):
-    cell = bench.Cell(repo, workload)
-    assert cell.config["hidden_size"] == 2048
+@pytest.mark.parametrize("checkout,workload", CELLS, indirect=["checkout"])
+def test_a_cell_is_found_by_its_name_alone(checkout, workload):
+    cell = bench.Cell(checkout, workload)
+    # the state file the configuration names, with what the harness and the
+    # miniature take from it: every key TINY cuts is the configuration's
+    module = bench.load_state(checkout, cell.spec["paths"], cell.config)
+    assert callable(module.factory) and isinstance(module.TINY, dict)
+    assert set(module.TINY) <= set(cell.config)
     assert cell.traffic["counts_as_attempt"] in ("restore", "take", "cycle")
     reported = {m["name"] for m in cell.end_to_end_metrics()}
     assert "setup_s" in reported and len(reported) >= 2
@@ -69,36 +83,64 @@ def test_a_cell_is_found_by_its_name_alone(repo, benchmark_json, workload):
         assert callable(cell.reader(m["name"]))
 
 
-def test_every_per_layer_metric_has_a_reader_and_lists_real_cells(repo, benchmark_json, full_spec):
-    cells = _cells(benchmark_json)
-    for m in benchmark_json["per_layer"]:
-        assert os.path.isfile(os.path.join(repo, "chipbench", "metrics", m["name"] + ".py"))
+def test_every_per_layer_metric_has_a_reader_and_lists_real_cells(repo, checkout, checkout_spec):
+    cells = _cells(checkout_spec)
+    for m in checkout_spec["per_layer"]:
+        assert os.path.isfile(os.path.join(checkout, "chipbench", "metrics", m["name"] + ".py"))
         assert m["workloads"] and set(m["workloads"]) <= set(cells)
     # a reader with no entry serves a cell that PERF.md leaves out for now
-    readers = {f[:-3] for f in os.listdir(os.path.join(repo, "chipbench", "metrics")) if f.endswith(".py")}
-    assert readers == {m["name"] for m in full_spec["per_layer"]}
+    metrics = os.path.join(checkout, "chipbench", "metrics")
+    readers = {f[:-3] for f in os.listdir(metrics) if f.endswith(".py")}
+    assert readers == {m["name"] for m in with_left_out(repo, checkout_spec)["per_layer"]}
 
 
-def test_left_out_cells_are_whole_entries_a_later_pr_can_add(repo, benchmark_json, full_spec):
+def _empty_window(op):
+    """A traced window's context that holds two operations and nothing to
+    read: no span, no counter, none of the harness's own counts."""
+    timeline = [{"op": op, "t0": 0.0, "t1": 1.0}, {"op": op, "t0": 1.0, "t1": 2.0}]
+    nothing = {"counters": {}, "histograms": {}}
+    return bench.Context(
+        timeline=timeline, setup_timeline=[], notes={"state_bytes": 1000, "array_leaves": 10},
+        obs_before=nothing, obs_after=nothing, spans=[], pack_calls=None, unpack_calls=None,
+        donated_templates=None, hbm_poll_max=None, rss_peak_delta=None, disk_bytes=None,
+    )
+
+
+@pytest.mark.parametrize("checkout,metric", METRICS, indirect=["checkout"])
+def test_a_listed_metric_has_a_reader_that_reads_none_where_there_is_nothing(
+    checkout, checkout_spec, metric
+):
+    # what a CPU run cannot reach (the chunks' device path, the chip's
+    # memory) is covered here: a reader that found nothing returns None,
+    # and the harness leaves the metric out of the line, never reads 0
+    (entry,) = [m for m in checkout_spec["per_layer"] if m["name"] == metric]
+    assert os.path.isfile(os.path.join(checkout, "chipbench", "metrics", metric + ".py"))
+    for workload in entry.get("workloads", list(_cells(checkout_spec))):
+        cell = bench.Cell(checkout, workload)
+        assert cell.reader(metric)(_empty_window(cell.traffic["counts_as_attempt"])) is None, workload
+
+
+def test_left_out_cells_are_whole_entries_a_later_pr_can_add(repo, checkout, checkout_spec):
+    full_spec = with_left_out(repo, checkout_spec)
     cells = _cells(full_spec)
     assert len(cells) == len(full_spec["workloads"])
     configs = {c["name"]: c for c in full_spec["configs"]}
     for w in full_spec["workloads"]:
-        assert os.path.isfile(os.path.join(repo, configs[w["config"]]["file"]))
-        assert os.path.isfile(os.path.join(repo, "chipbench", "traffic", w["traffic"] + ".json"))
+        assert os.path.isfile(os.path.join(checkout, configs[w["config"]]["file"]))
+        assert os.path.isfile(os.path.join(checkout, "chipbench", "traffic", w["traffic"] + ".json"))
         reported = {m["name"] for m in full_spec["end_to_end"] if w["name"] in m.get("workloads", [w["name"]])}
         assert len(reported) >= 2
 
 
-def test_an_unknown_workload_is_an_error(repo):
+def test_an_unknown_workload_is_an_error(checkout):
     with pytest.raises(KeyError):
-        bench.Cell(repo, "no-such.cell")
+        bench.Cell(checkout, "no-such.cell")
 
 
 @pytest.mark.parametrize("config", ["ouro-2.6b-d3", "ouro-2.6b-d4", "ouro-2.6b-d9", "ouro-2.6b-d32"])
-def test_configuration_keeps_the_published_widths(repo, full_spec, dense_lm, config):
-    entry = {c["name"]: c for c in full_spec["configs"]}[config]
-    conf = state.load_json(os.path.join(repo, entry["file"]))
+def test_configuration_keeps_the_published_widths(repo, checkout, checkout_spec, dense_lm, config):
+    entry = {c["name"]: c for c in with_left_out(repo, checkout_spec)["configs"]}[config]
+    conf = state.load_json(os.path.join(checkout, entry["file"]))
     for key, value in PUBLISHED.items():
         assert conf[key] == value, key
     assert conf["source"] == entry["source"]

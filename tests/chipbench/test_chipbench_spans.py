@@ -1,7 +1,9 @@
 """The nine readers of the program's spans (``chipbench/metrics``): on a
 hand-built context, where a stall planted in one phase of a restore moves
 that phase's metric and no other, and on a whole traced run at small widths
-on the CPU, where the caller-thread metrics have to tile a restore."""
+on the CPU, where the caller-thread metrics have to tile a restore.  All but
+``h2d.put_s`` are listed for every one-chip cell of a restore mix, and the
+four save readers for every one-chip save cell."""
 
 import json
 import os
@@ -13,6 +15,9 @@ from chipbench import bench
 from torchsnapshot_tpu.obs.tracer import Span
 
 CELL = "ouro-2.6b-d9.kill_resume"
+# the one-chip cells of the restore mixes; the four-chip cell's profile drops
+# events, and h2d.put_s, which times the call and not the link, stays on CELL
+RESTORE_CELLS = [CELL, "joyai-flash-ep16-d5.kill_resume", "dlrm-criteo-rw16.kill_resume_first3"]
 READERS = [
     "restore.plan_s", "restore.pipeline_s", "restore.finalize_s",
     "restore.tail_wait_s", "consume.queue_s", "consume.work_s",
@@ -26,11 +31,13 @@ def cell(repo):
     return bench.Cell(repo, CELL)
 
 
-def test_benchmark_json_lists_the_nine_readers_for_the_cell(cell):
-    listed = {m["name"]: m for m in cell.per_layer_metrics()}
-    for name in READERS:
-        assert listed[name]["moves"] == "resume_s" and listed[name]["workloads"] == [CELL]
-        assert callable(cell.reader(name))
+def test_benchmark_json_lists_the_nine_readers_for_the_cell(checkout):
+    for workload in RESTORE_CELLS:
+        cell = bench.Cell(checkout, workload)
+        listed = {m["name"]: m for m in cell.per_layer_metrics()}
+        for name in READERS if workload == CELL else set(READERS) - {"h2d.put_s"}:
+            assert listed[name]["moves"] == "resume_s" and workload in listed[name]["workloads"]
+            assert callable(cell.reader(name))
 
 
 # ------------------------------------------------------ a hand-built window
@@ -266,6 +273,7 @@ def test_spans_of_a_window_stay_under_the_recorder_cap(traced_run):
 # ------------------------------------------------- the save side: per take
 
 SAVE_CELL = "ouro-2.6b-d9.preempt_sync_save"
+SAVE_CELLS = [SAVE_CELL, "joyai-flash-ep16-d5.preempt_sync_save", "dlrm-criteo-rw16.preempt_sync_save"]
 SAVE_READERS = ["plan.take_s", "stage.queue_s", "d2h.copy_s", "stage.digest_s"]
 
 
@@ -274,12 +282,14 @@ def save_cell(repo):
     return bench.Cell(repo, SAVE_CELL)
 
 
-def test_benchmark_json_lists_the_save_readers_for_the_sync_cell(save_cell):
-    listed = {m["name"]: m for m in save_cell.per_layer_metrics()}
-    for name in SAVE_READERS:
-        assert listed[name]["moves"] == "save_commit_s"
-        assert listed[name]["workloads"] == [SAVE_CELL]
-        assert callable(save_cell.reader(name))
+def test_benchmark_json_lists_the_save_readers_for_the_sync_cell(checkout):
+    for workload in SAVE_CELLS:
+        cell = bench.Cell(checkout, workload)
+        listed = {m["name"]: m for m in cell.per_layer_metrics()}
+        for name in SAVE_READERS:
+            assert listed[name]["moves"] == "save_commit_s"
+            assert workload in listed[name]["workloads"]
+            assert callable(cell.reader(name))
 
 
 def _take(spans, timeline, at_ms, stall=None, piped=True, asynchronous=False):
